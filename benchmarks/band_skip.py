@@ -55,11 +55,11 @@ def run(*, full: bool = False, ci: bool = False, csv: list | None = None):
 
         def skip_fn():
             return jax.block_until_ready(
-                wavefront_call(plan, qp, rl, interpret=True))
+                wavefront_call(plan, qp, rl))
 
         def mask_fn():
             return jax.block_until_ready(
-                wavefront_call(full_plan, qp, rl, interpret=True))
+                wavefront_call(full_plan, qp, rl))
 
         t_skip = time_fn(skip_fn, warmup=1, runs=reps)
         t_mask = time_fn(mask_fn, warmup=1, runs=reps)

@@ -100,9 +100,7 @@ def run(full: bool = False, ci: bool = False,
             for backend in ("engine", "kernel"):
                 def call(backend=backend):
                     res = sdtw(q, r, backend=backend, spec=spec,
-                               normalize=False, segment_width=4,
-                               interpret=True if backend == "kernel"
-                               else None)
+                               normalize=False, segment_width=4)
                     return res.cost, res.end
                 cost, end = call()
                 dt = (float("nan") if runs == 0

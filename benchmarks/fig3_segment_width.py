@@ -16,7 +16,8 @@ tuning-cache file, then reports
     second run against the same cache file performs ZERO timing trials
     (``warm_trials == 0``, ``warm_cache_hits >= 1``).
 
-The kernel runs in interpret mode for structural truth on CPU; the XLA
+The kernel runs compiled on a TPU and interpreted elsewhere (off the
+chip its timings are structural only, not speed); the XLA
 engine baseline (which has no width knob) is measured by the tuner as
 the backend alternative.
 """
@@ -58,7 +59,7 @@ def run(full: bool = False, ci: bool = False, csv=None,
     # --- cold run: the tuner measures and persists a verdict
     cold_metrics = MetricsRegistry()
     res = tune.autotune(r, m=wl.query_len, batch=wl.batch,
-                        outputs=("cost", "end"), interpret=True,
+                        outputs=("cost", "end"),
                         budget=budget,
                         cache=tune.TuningCache(cache_path),
                         metrics=cold_metrics)
@@ -66,7 +67,8 @@ def run(full: bool = False, ci: bool = False, csv=None,
     floats = bucket * wl.query_len
 
     print(f"# Fig 3 via repro.tune (workload: batch={wl.batch} "
-          f"M={wl.query_len} N={wl.ref_len}) — interpret mode")
+          f"M={wl.query_len} N={wl.ref_len}) — "
+          f"{'interpret mode' if kops.default_interpret() else 'compiled'}")
     print(f"{'plan':>14s} {'ms':>12s} {'Gsps':>12s} {'source':>8s}")
     measured = dict(res.measured)                    # label -> ms
     rows = {lb: (ms, "tuner") for lb, ms in measured.items()}
@@ -79,7 +81,7 @@ def run(full: bool = False, ci: bool = False, csv=None,
             lb = f"kernel:w{w}"
             if lb not in rows:
                 t = time_fn(functools.partial(
-                    kops.sdtw_wavefront, segment_width=w, interpret=True),
+                    kops.sdtw_wavefront, segment_width=w),
                     jnp.asarray(q), r, warmup=budget.warmup,
                     runs=budget.runs)
                 rows[lb] = (t * 1e3, "sweep")
@@ -108,7 +110,7 @@ def run(full: bool = False, ci: bool = False, csv=None,
     # with zero timing trials
     warm_metrics = MetricsRegistry()
     warm = tune.autotune(r, m=wl.query_len, batch=wl.batch,
-                         outputs=("cost", "end"), interpret=True,
+                         outputs=("cost", "end"),
                          budget=budget,
                          cache=tune.TuningCache(cache_path),
                          metrics=warm_metrics)

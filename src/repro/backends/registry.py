@@ -160,11 +160,8 @@ _PRIORITY = ("engine", "kernel", "ref", "quantized", "distributed")
 
 def _device_default() -> str:
     """The platform auto-selection keys off (overridable in tests)."""
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:       # jax missing/misconfigured: stay generic
-        return "cpu"
+    import jax
+    return jax.default_backend()
 
 
 def _priority() -> tuple:

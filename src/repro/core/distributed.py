@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.spec import DEFAULT_SPEC, DPSpec
 from repro.core.spec import INF as _SPEC_INF
@@ -222,10 +221,10 @@ def make_sdtw_distributed(mesh: Mesh, *,
         best, end = local(q.astype(jnp.float32), r.astype(jnp.float32))
         return best, end
 
-    fn = shard_map(
+    fn = jax.shard_map(
         wrapped, mesh=mesh,
         in_specs=(P(batch_axes, None), P(ref_axis)),
         out_specs=(P(batch_axes), P(batch_axes)),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)

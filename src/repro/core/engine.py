@@ -43,7 +43,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.spec import (DEFAULT_SPEC, DPSpec, INF,  # noqa: F401
-                             NO_WINDOW, SOFT_BIG)
+                             NO_WINDOW, SOFT_BIG, soft_exp, soft_log)
 # INF re-exported for backward compatibility (engine.INF predates spec.py)
 
 
@@ -161,7 +161,7 @@ def sdtw_engine(queries: jnp.ndarray,
             # logsumexp of x = -D[M-1, j] / gamma (underflow-safe)
             x = jnp.where(bottom_valid, -bottom / spec.gamma, -SOFT_BIG)
             m_new = jnp.maximum(m_run, x)
-            s_run = s_run * jnp.exp(m_run - m_new) + jnp.exp(x - m_new)
+            s_run = s_run * soft_exp(m_run - m_new) + soft_exp(x - m_new)
             return (d0, d1, m_new, s_run, best, best_j), None
         if return_window:
             best_s = jnp.where(take, s0_[..., M - 1], best_s)
@@ -177,7 +177,7 @@ def sdtw_engine(queries: jnp.ndarray,
         carry, _ = lax.scan(step, (d_init, d_init, m0, s0, best0, bj0),
                             jnp.arange(M + N - 1))
         _, _, m_run, s_run, best, best_j = carry
-        cost_out = -spec.gamma * (m_run + jnp.log(s_run))
+        cost_out = -spec.gamma * (m_run + soft_log(s_run))
         # no reachable bottom cell (e.g. the band blocks the whole
         # bottom row): the logsumexp of SOFT_BIG-masked cells is a
         # finite ~SOFT_BIG value — report +inf like the hard path and
@@ -305,8 +305,8 @@ def _dp_engine(queries, reference, *, spec: DPSpec, return_end: bool,
             if soft:
                 x = -d0 / spec.gamma    # masked cells underflow to 0
                 m_new = jnp.maximum(m_run, jnp.max(x, axis=-1))
-                s_run = s_run * jnp.exp(m_run - m_new) \
-                    + jnp.sum(jnp.exp(x - m_new[..., None]), axis=-1)
+                s_run = s_run * soft_exp(m_run - m_new) \
+                    + jnp.sum(soft_exp(x - m_new[..., None]), axis=-1)
                 return (d0, d1, best, best_j, m_new, s_run), None
         else:
             # corner fold: the single cell (M-1, N-1) lives on the last
@@ -328,7 +328,7 @@ def _dp_engine(queries, reference, *, spec: DPSpec, return_end: bool,
         s0 = jnp.zeros((B,), dt)
         carry, _ = lax.scan(step, (d_init, d_init, best0, bj0, m0, s0), ts)
         _, _, best, best_j, m_run, s_run = carry
-        cost_out = -spec.gamma * (m_run + jnp.log(s_run))
+        cost_out = -spec.gamma * (m_run + soft_log(s_run))
         end = best_j
     else:
         carry, _ = lax.scan(step, (d_init, d_init, best0, bj0), ts)

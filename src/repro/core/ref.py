@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.spec import (DEFAULT_SPEC, DPSpec, INF,  # noqa: F401
-                             NO_WINDOW)
+                             NO_WINDOW, soft_exp, soft_log)
 # INF re-exported for backward compatibility (ref.INF predates spec.py)
 
 
@@ -91,6 +91,36 @@ def sdtw_numpy(q: np.ndarray, r: np.ndarray,
     if spec.soft:
         return -spec.gamma * float(_np_logsumexp(-last / spec.gamma)), end
     return float(last[end]), end
+
+
+def sdtw_bottom_row(queries: np.ndarray, r: np.ndarray,
+                    spec: DPSpec | None = None) -> np.ndarray:
+    """The last DP row ``D[M-1, :]`` of hard-min, unbanded sDTW in
+    float64, one vectorized pass per query row — the host oracle for
+    paper-size checks, where :func:`sdtw_numpy`'s cell loop is far too
+    slow.  Its min is the cost, its first argmin the end; the whole row
+    says how far from optimal any other end is.
+
+    The horizontal dependency ``D[i, j] = min(A[j], D[i, j-1] + c[j])``
+    with ``A[j] = c[j] + min(D[i-1, j], D[i-1, j-1])`` is a min-plus
+    prefix scan: ``D[i] = S + minimum.accumulate(A - S)``, ``S`` the
+    running sum of the row's costs.  Row 0 is the free start
+    ``D[0] = c``.  queries (B, M), r (N,) -> (B, N).
+    """
+    spec = DEFAULT_SPEC if spec is None else spec
+    if spec.soft or spec.band is not None:
+        raise ValueError("sdtw_bottom_row is the hard-min unbanded "
+                         f"oracle; got {spec.describe()}")
+    q = np.asarray(queries, dtype=np.float64)
+    r = np.asarray(r, dtype=np.float64)[None, :]
+    d = _np_cost(spec, q[:, :1], r)
+    for i in range(1, q.shape[1]):
+        c = _np_cost(spec, q[:, i:i + 1], r)
+        diag = np.concatenate(
+            [np.full((q.shape[0], 1), np.inf), d[:, :-1]], axis=1)
+        s = np.cumsum(c, axis=1)
+        d = s + np.minimum.accumulate(c + np.minimum(d, diag) - s, axis=1)
+    return d
 
 
 def _np_logsumexp(a: np.ndarray) -> float:
@@ -270,7 +300,7 @@ def _dp_rowscan_single(q: jnp.ndarray, r: jnp.ndarray, spec: DPSpec,
                 x = -row / spec.gamma       # masked cells underflow to 0
                 row_mx = jnp.max(x)
                 m_new = jnp.maximum(mx, row_mx)
-                s = s * jnp.exp(mx - m_new) + jnp.sum(jnp.exp(x - m_new))
+                s = s * soft_exp(mx - m_new) + jnp.sum(soft_exp(x - m_new))
                 mx = m_new
         return (row, best, best_j, mx, s), None
 
@@ -282,7 +312,7 @@ def _dp_rowscan_single(q: jnp.ndarray, r: jnp.ndarray, spec: DPSpec,
     if local:
         end = best_j
         if spec.soft:
-            return -spec.gamma * (mx + jnp.log(s)), end
+            return -spec.gamma * (mx + soft_log(s)), end
         return best, end
     # corner fold (global families)
     corner = last_row[n - 1]

@@ -479,6 +479,17 @@ class Aligner:
         with self._fns_lock:
             return sum(1 for _, jitted in self._fns.values() if jitted)
 
+    def hlo_texts(self) -> list[str]:
+        """Compiled HLO of every jitted executable this session holds:
+        what the device runs.  On a TPU a kernel session's text holds
+        the Pallas kernel as a ``tpu_custom_call``.  Lowering traces
+        each executable once more (``stats.traces`` counts it)."""
+        with self._fns_lock:
+            held = [(key, fn) for key, (fn, jitted) in self._fns.items()
+                    if jitted]
+        return [fn.lower(jax.ShapeDtypeStruct(shape, jnp.dtype(dtype)))
+                .compile().as_text() for (shape, dtype, _), fn in held]
+
     def __repr__(self):
         return (f"Aligner(n={self.length}, backend={self.backend.name!r}, "
                 f"spec={self.spec.describe()}, "

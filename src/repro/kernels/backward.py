@@ -65,7 +65,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.spec import PAD_VALUE, DPSpec
+from repro.core.spec import PAD_VALUE, DPSpec, soft_exp
 from repro.kernels import ops
 from repro.kernels.wavefront import (LANES, KernelPlan, band_grid_blocks,
                                      wavefront_call)
@@ -191,9 +191,10 @@ def _tile(C, left_col, *, spec: DPSpec, j0: int, shift: int,
                                                     (B, m, W)), axis=2)
 
 
-def _e_tile(qn, rp, cost, f_left, b_left_flipped, r: int, *,
+def _e_tile(qn, rp, cost, f_left, b_left_flipped, r, *,
             spec: DPSpec, W: int, n_pad: int, R: int):
-    """E and C tiles of original reference block ``r``.
+    """E and C tiles of original reference block ``r`` (traced: the
+    callers scan over blocks, so one tile set is live at a time).
 
     qn (B, m) queries, rp (n_pad,) padded reference, cost (B,) total
     soft costs, f_left/b_left_flipped (B, m) the forward/reverse
@@ -202,7 +203,7 @@ def _e_tile(qn, rp, cost, f_left, b_left_flipped, r: int, *,
     """
     m = qn.shape[1]
     j0 = r * W
-    rc = lax.slice(rp, (j0,), (j0 + W,))
+    rc = lax.dynamic_slice(rp, (j0,), (W,))
     C = spec.cell_cost(qn[:, :, None], rc[None, None, :]) \
         .astype(jnp.float32)
     F = _tile(C, f_left, spec=spec, j0=j0, shift=0, reverse=False)
@@ -214,7 +215,7 @@ def _e_tile(qn, rp, cost, f_left, b_left_flipped, r: int, *,
     # valid cells satisfy F + B - C >= cost (the through-(i,j) partition
     # of the path Gibbs measure), so the exponent is <= 0 up to float
     # error; masked/pad cells sit at ~ -1e30/gamma and underflow to 0
-    E = jnp.exp((cost[:, None, None] - F - Bo + C) / spec.gamma)
+    E = soft_exp((cost[:, None, None] - F - Bo + C) / spec.gamma)
     return jnp.where(jnp.isfinite(cost)[:, None, None], E, 0.0), C
 
 
@@ -223,23 +224,21 @@ def _e_tile(qn, rp, cost, f_left, b_left_flipped, r: int, *,
 def _fold_grads(queries, reference, cost, fck, rck, ct, *,
                 spec: DPSpec, segment_width: int):
     """Fold ct-weighted E tiles into (d cost / d queries,
-    d cost / d reference) block by block — peak extra memory is one
-    (B, m, W) tile set, never O(M * N)."""
+    d cost / d reference) block by block — a ``lax.scan`` over blocks,
+    so peak extra memory is one block's tile set, never O(M * N)."""
     B, m = queries.shape
     n = reference.shape[0]
     W, n_pad, R, Gf = _geometry(spec, m, n, segment_width)
     qn = queries.astype(jnp.float32)
     rp = jnp.pad(reference.astype(jnp.float32), (0, n_pad - n),
                  constant_values=PAD_VALUE)
-    fl = _unpack_ckpt(fck, B, Gf, m)
-    bl = _unpack_ckpt(rck, B, Gf, m)
     ctw = ct.astype(jnp.float32)[:, None, None]
-    gq = jnp.zeros((B, m), jnp.float32)
-    gr_segs = []
-    for r in range(Gf):
-        E, _ = _e_tile(qn, rp, cost, fl[:, r], bl[:, Gf - 1 - r], r,
+
+    def block(gq, xs):
+        r, f_left, b_left = xs
+        E, _ = _e_tile(qn, rp, cost, f_left, b_left, r,
                        spec=spec, W=W, n_pad=n_pad, R=R)
-        rc = lax.slice(rp, (r * W,), ((r + 1) * W,))
+        rc = lax.dynamic_slice(rp, (r * W,), (W,))
         diff = qn[:, :, None] - rc[None, None, :]
         if spec.distance == "sqeuclidean":
             g = (2.0 * ctw) * E * diff            # dC/dq = 2 (q - r)
@@ -250,12 +249,22 @@ def _fold_grads(queries, reference, cost, fck, rck, ct, *,
                 f"fused kernel backward supports sqeuclidean/abs, got "
                 f"{spec.distance!r} (the registry should have routed "
                 f"this spec elsewhere)")
-        gq = gq + g.sum(axis=2)
-        gr_segs.append(-g.sum(axis=(0, 1)))       # dC/dr = -dC/dq
-    if R > Gf:                                    # band-skipped blocks
-        gr_segs.append(jnp.zeros(((R - Gf) * W,), jnp.float32))
-    gr = jnp.concatenate(gr_segs)[:n]
+        return gq + g.sum(axis=2), -g.sum(axis=(0, 1))   # dC/dr = -dC/dq
+
+    gq, gr = lax.scan(block, jnp.zeros((B, m), jnp.float32),
+                      _block_xs(fck, rck, B, Gf, m))
+    # band-skipped trailing blocks carry no gradient
+    gr = jnp.pad(gr.reshape(-1), (0, (R - Gf) * W))[:n]
     return gq.astype(queries.dtype), gr.astype(reference.dtype)
+
+
+def _block_xs(fck, rck, batch: int, grid_blocks: int, m: int):
+    """Per-block scan operands: (block index, forward left strip,
+    reverse left strip of the same original block), block-major."""
+    fl = _unpack_ckpt(fck, batch, grid_blocks, m)
+    bl = _unpack_ckpt(rck, batch, grid_blocks, m)
+    return (jnp.arange(grid_blocks), jnp.moveaxis(fl, 1, 0),
+            jnp.moveaxis(jnp.flip(bl, axis=1), 1, 0))
 
 
 # --------------------------------------------------------- custom_vjp
@@ -343,14 +352,16 @@ def _soft_align_impl(queries, reference, *, spec: DPSpec,
     qn = queries.astype(jnp.float32)
     rp = jnp.pad(reference.astype(jnp.float32), (0, n_pad - n),
                  constant_values=PAD_VALUE)
-    fl = _unpack_ckpt(fck, B, Gf, m)
-    bl = _unpack_ckpt(rck, B, Gf, m)
-    tiles = [_e_tile(qn, rp, cost, fl[:, r], bl[:, Gf - 1 - r], r,
-                     spec=spec, W=W, n_pad=n_pad, R=R)[0]
-             for r in range(Gf)]
-    if R > Gf:       # band-skipped trailing blocks: all out of band
-        tiles.append(jnp.zeros((B, m, (R - Gf) * W), jnp.float32))
-    E = jnp.concatenate(tiles, axis=2)[:, :, :n]
+
+    def block(xs):
+        r, f_left, b_left = xs
+        return _e_tile(qn, rp, cost, f_left, b_left, r,
+                       spec=spec, W=W, n_pad=n_pad, R=R)[0]
+
+    tiles = lax.map(block, _block_xs(fck, rck, B, Gf, m))  # (Gf, B, m, W)
+    E = jnp.moveaxis(tiles, 0, 2).reshape(B, m, Gf * W)
+    # band-skipped trailing blocks: all out of band
+    E = jnp.pad(E, ((0, 0), (0, 0), (0, (R - Gf) * W)))[:, :, :n]
     return cost, end, E
 
 

@@ -38,10 +38,14 @@ def _kernel(x_ref, o_ref, *, n: int, eps: float):
 
 
 def normalizer_pallas(x: jnp.ndarray, *, n: int, eps: float = 1e-12,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool | None = None) -> jnp.ndarray:
     """x: (G, SUBLANES, Lp) with the true (unpadded) length ``n``.
     Padding columns (>= n) must be zero; their output is garbage and is
-    sliced off by the ops.py wrapper."""
+    sliced off by the ops.py wrapper.  interpret: None =
+    ``ops.default_interpret()`` (compiled on TPU)."""
+    if interpret is None:
+        from repro.kernels.ops import default_interpret  # imports us
+        interpret = default_interpret()
     G, S, Lp = x.shape
     assert S == SUBLANES
     kernel = functools.partial(_kernel, n=n, eps=eps)

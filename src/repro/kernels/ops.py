@@ -31,7 +31,7 @@ from repro.core.spec import (DEFAULT_SPEC, NO_WINDOW,  # noqa: F401
 # sentinels in core/spec.py.
 from repro.kernels.sdtw_wavefront import (LANES, SUBLANES,
                                           sdtw_wavefront_pallas)
-from repro.kernels.wavefront import KernelPlan, build_plan
+from repro.kernels.wavefront import KernelPlan, build_plan, query_pack_len
 from repro.kernels.normalizer import normalizer_pallas
 
 
@@ -134,13 +134,15 @@ def unswizzle_reference(r_layout: jnp.ndarray) -> jnp.ndarray:
 
 
 def prepare_queries(q: jnp.ndarray) -> jnp.ndarray:
-    """(B, M) -> (G, SUBLANES, M + 2*(LANES-1)) reversed + padded."""
+    """(B, M) -> (G, SUBLANES, query_pack_len(M)): each query reversed
+    behind LANES-1 zeros, zero-padded on the right to the pack length."""
     B, M = q.shape
     b_pad = ceil_to(B, SUBLANES)
     q = jnp.pad(q, ((0, b_pad - B), (0, 0)))
     qrev = jnp.flip(q, axis=1)
-    qrev = jnp.pad(qrev, ((0, 0), (LANES - 1, LANES - 1)))
-    return qrev.reshape(-1, SUBLANES, M + 2 * (LANES - 1))
+    mp = query_pack_len(M)
+    qrev = jnp.pad(qrev, ((0, 0), (LANES - 1, mp - M - (LANES - 1))))
+    return qrev.reshape(-1, SUBLANES, mp)
 
 
 def validate_prepped(q_prepped, r_layout, *, m: int, n: int,
@@ -171,11 +173,11 @@ def validate_prepped(q_prepped, r_layout, *, m: int, n: int,
             f"with swizzle_reference(reference, {segment_width})")
     if getattr(q_prepped, "ndim", None) != 3 or \
             q_prepped.shape[1] != SUBLANES or \
-            q_prepped.shape[2] != m + 2 * (LANES - 1):
+            q_prepped.shape[2] != query_pack_len(m):
         raise ValueError(
             f"query pack {tuple(getattr(q_prepped, 'shape', ()))} does "
             f"not match m={m}: expected (G, {SUBLANES}, "
-            f"{m + 2 * (LANES - 1)}) from prepare_queries")
+            f"{query_pack_len(m)}) from prepare_queries")
 
 
 def kernel_plan(spec: DPSpec | None = None, *, m: int, n: int,
@@ -271,7 +273,7 @@ def sdtw_wavefront_prepped(q_prepped: jnp.ndarray, r_layout: jnp.ndarray, *,
                            extras: tuple = ()):
     """Dispatch the wavefront kernel on pre-packed operands.
 
-    q_prepped: (G, SUBLANES, m + 2*(LANES-1)) from :func:`prepare_queries`
+    q_prepped: (G, SUBLANES, query_pack_len(m)) from :func:`prepare_queries`
     r_layout:  (R, w, LANES) from :func:`swizzle_reference`
     extras:    the spec family's packed extra operands from
                :func:`family_extras` (required iff the plan's

@@ -31,13 +31,13 @@ def sdtw_wavefront_pallas(q_rev_pad: jnp.ndarray,
                           *extras: jnp.ndarray,
                           m: int, segment_width: int,
                           compute_dtype=jnp.float32,
-                          interpret: bool = True,
+                          interpret: bool | None = None,
                           spec: DPSpec = DEFAULT_SPEC,
                           with_window: bool = False,
                           n: int | None = None):
     """Raw pallas_call wrapper. Use ``repro.kernels.ops.sdtw_wavefront``.
 
-    q_rev_pad: (G, SUBLANES, Mp) reversed queries, Mp = m + 2*(LANES-1)
+    q_rev_pad: (G, SUBLANES, Mp) reversed queries, Mp = query_pack_len(m)
     r_layout:  (R, w, LANES) pre-swizzled reference blocks
     returns (costs (G, SUBLANES) f32, ends (G, SUBLANES) i32), plus
     starts (G, SUBLANES) i32 in the middle when ``with_window`` —

@@ -78,7 +78,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.spec import (KERNEL_BIG, NO_WINDOW, SOFT_BIG, DPSpec)
+from repro.core.spec import (KERNEL_BIG, NO_WINDOW, SOFT_BIG, DPSpec,
+                             soft_exp, soft_log)
 
 LANES = 128          # TPU VPU lane count (the paper's wavefront width = 64)
 SUBLANES = 8         # queries processed per grid step (sublane packing)
@@ -97,6 +98,41 @@ _EXTRA_KIND = {
     "bt": "r",       # erp: gap-cost prefix over the reference
     "bl": "q",       # erp: gap-cost prefix over each query
 }
+
+
+def query_pack_len(m: int) -> int:
+    """Lane width of one prepared query row: LANES-1 leading zeros, the
+    reversed query, then 2*LANES-1 zeros.  The last per-step window
+    starts at m + LANES - 2, so the two aligned lane tiles every window
+    is read from (:func:`_lane_window`) stay inside the row."""
+    return m + 3 * LANES - 2
+
+
+def strip_len(m: int) -> int:
+    """Lane width of a boundary strip: m rows, padded to whole tiles."""
+    return _ceil_to(m, LANES)
+
+
+def _ceil_to(x: int, k: int) -> int:
+    return (x + k - 1) // k * k
+
+
+def _aligned(col):
+    """(tile start, lane offset) of a dynamic lane column: Mosaic only
+    slices the lane dimension at provable multiples of LANES."""
+    off = lax.rem(col, LANES)
+    return pl.multiple_of(col - off, LANES), off
+
+
+def _lane_window(ref, start, lane):
+    """``ref[0, :, start:start + LANES]`` at a dynamic, unaligned start:
+    the two aligned lane tiles covering it, each rotated by the same
+    amount and spliced at the tile boundary."""
+    base, off = _aligned(start)
+    shift = lax.rem(LANES - off, LANES)
+    lo = pltpu.roll(ref[0, :, pl.ds(base, LANES)], shift, 1)
+    hi = pltpu.roll(ref[0, :, pl.ds(base + LANES, LANES)], shift, 1)
+    return jnp.where(lane < LANES - off, lo, hi)
 
 
 # ------------------------------------------------------------- channels
@@ -142,8 +178,7 @@ class CarryChannel:
                       for _ in range(w))
         # t=0: only lane 0 is active (row 0); its left column is the
         # previous block's strip (block > 0) or the edge sentinel
-        strip0 = pl.load(strip_ref,
-                         (slice(None), pl.dslice(0, 1))).astype(dt)
+        strip0 = self.read_strip(strip_ref, 0, compute_dtype=compute_dtype)
         left0 = jnp.where(lane == 0,
                           jnp.where(rblk > 0, strip0, edge), edge)
         prev_left0 = jnp.full((SUBLANES, LANES), self.edge_init, dt)
@@ -161,21 +196,36 @@ class CarryChannel:
         return jnp.where(lane == 0, lane0, rolled)
 
     def read_strip(self, strip_ref, t, *, compute_dtype):
-        return pl.load(strip_ref, (slice(None), pl.dslice(t, 1))) \
+        """Strip row ``t`` for every query, in lane 0 (the only lane
+        that reads it): the aligned lane tile holding column t, rotated
+        so that column lands in lane 0."""
+        base, off = _aligned(t)
+        tile = strip_ref[:, pl.ds(base, LANES)]
+        return pltpu.roll(tile, lax.rem(LANES - off, LANES), 1) \
             .astype(self.reg_dtype(compute_dtype))
 
-    def write_strip(self, strip_ref, i, last):
-        """Publish the channel's right column (lane LANES-1) for the
-        next reference block."""
-        col = lax.slice(last, (0, LANES - 1), (SUBLANES, LANES))
-        pl.store(strip_ref, (slice(None), pl.dslice(i, 1)),
-                 col.astype(self.strip_dtype))
+    def write_strip(self, strip_ref, i, last, *, lane):
+        """Publish the channel's right column (lane LANES-1) as strip
+        row ``i`` for the next reference block: a read-modify-write of
+        the aligned lane tile holding column i."""
+        base, off = _aligned(i)
+        col = pltpu.roll(last, lax.rem(off + 1, LANES), 1)  # lane off
+        tile = strip_ref[:, pl.ds(base, LANES)]
+        strip_ref[:, pl.ds(base, LANES)] = jnp.where(
+            lane == off, col.astype(self.strip_dtype), tile)
 
     def strip_shape(self, m: int):
-        return pltpu.VMEM((SUBLANES, m), self.strip_dtype)
+        return pltpu.VMEM((SUBLANES, strip_len(m)), self.strip_dtype)
 
 
 # ---------------------------------------------------------------- folds
+def _write(out_ref, col):
+    """Store a per-query (S, 1) result as the output block's lane-dense
+    (S, LANES) tile (TPU blocks are whole (8, 128) tiles)."""
+    out_ref[0] = jnp.broadcast_to(col, (SUBLANES, LANES)).astype(
+        out_ref.dtype)
+
+
 @dataclasses.dataclass(frozen=True)
 class MinArgminFold:
     """Streaming (min, argmin[, argstart]) over bottom-row cells — the
@@ -223,19 +273,23 @@ class MinArgminFold:
             scr[2][...] = jnp.where(take, best_s, scr[2][...])
 
     def _cross_lane(self, scr):
+        """(min, its earliest column, and the lane-hit mask) per query,
+        each (S, 1): a where/min reduce across lanes — every column
+        belongs to exactly one lane, so the hit lane is unique."""
         mv = scr[0][...]                                  # (S, L) f32
-        best = jnp.min(mv, axis=1)                        # (S,)
-        arg = jnp.argmin(mv, axis=1)                      # (S,)
-        idx = jnp.take_along_axis(scr[1][...], arg[:, None], axis=1)[:, 0]
-        return best, arg, idx
+        best = jnp.min(mv, axis=1, keepdims=True)
+        cols = scr[1][...]
+        end = jnp.min(jnp.where(mv == best, cols, _J_MAX), axis=1,
+                      keepdims=True)
+        return best, end, (mv == best) & (cols == end)
 
     def finalize(self, scr, outs, plan):
-        best, arg, idx = self._cross_lane(scr)
-        outs[0][0, :] = best
-        outs[1][0, :] = idx
+        best, end, hit = self._cross_lane(scr)
+        _write(outs[0], best)
+        _write(outs[1], end)
         if self.with_window:
-            outs[2][0, :] = jnp.take_along_axis(
-                scr[2][...], arg[:, None], axis=1)[:, 0]
+            _write(outs[2], jnp.min(jnp.where(hit, scr[2][...], _J_MAX),
+                                    axis=1, keepdims=True))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -278,26 +332,26 @@ class SoftMinFold:
         m_safe = jnp.maximum(m_run, mx)
         add = xs[0] * 0.0
         for x in xs:
-            add = add + jnp.exp(x - m_safe)
-        s_new = s_run * jnp.exp(m_run - m_safe) + add
+            add = add + soft_exp(x - m_safe)
+        s_new = s_run * soft_exp(m_run - m_safe) + add
         scr[2][...] = jnp.where(at_bottom, m_safe, m_run)
         scr[3][...] = jnp.where(at_bottom, s_new, s_run)
 
     def finalize(self, scr, outs, plan):
-        best, _, idx = MinArgminFold()._cross_lane(scr[:2])
+        best, idx, _ = MinArgminFold()._cross_lane(scr[:2])
         m_l, s_l = scr[2][...], scr[3][...]               # (S, L)
-        m_g = jnp.max(m_l, axis=1)                        # (S,)
-        s_g = jnp.sum(s_l * jnp.exp(m_l - m_g[:, None]), axis=1)
-        cost = -plan.spec.gamma * (m_g + jnp.log(s_g))
+        m_g = jnp.max(m_l, axis=1, keepdims=True)         # (S, 1)
+        s_g = jnp.sum(s_l * soft_exp(m_l - m_g), axis=1, keepdims=True)
+        cost = -plan.spec.gamma * (m_g + soft_log(s_g))
         # blocked band: every bottom cell was masked to ~SOFT_BIG — the
         # logsumexp is a finite ~SOFT_BIG value; report +inf like the
         # engine and the numpy oracle.  (Pad-dominated paths stay
         # finite ~1e12 << SOFT_BIG/2: the kernel's long-standing
         # blocked-band-with-reachable-padding semantics, see ops.py.)
         blocked = best >= jnp.asarray(SOFT_BIG / 2, jnp.float32)
-        outs[0][0, :] = jnp.where(blocked,
-                                  jnp.asarray(jnp.inf, jnp.float32), cost)
-        outs[1][0, :] = idx
+        _write(outs[0], jnp.where(blocked,
+                                  jnp.asarray(jnp.inf, jnp.float32), cost))
+        _write(outs[1], idx)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -329,12 +383,12 @@ class CornerFold:
 
     def finalize(self, scr, outs, plan):
         # exactly one lane ever wrote the corner; min() selects it
-        corner = jnp.min(scr[0][...], axis=1)                 # (S,)
+        corner = jnp.min(scr[0][...], axis=1, keepdims=True)  # (S, 1)
         blocked = corner >= jnp.asarray(plan.big / 2, jnp.float32)
-        outs[0][0, :] = jnp.where(
-            blocked, jnp.asarray(jnp.inf, jnp.float32), corner)
-        outs[1][0, :] = jnp.where(blocked, jnp.asarray(0, jnp.int32),
-                                  jnp.asarray(plan.n - 1, jnp.int32))
+        _write(outs[0], jnp.where(
+            blocked, jnp.asarray(jnp.inf, jnp.float32), corner))
+        _write(outs[1], jnp.where(blocked, jnp.asarray(0, jnp.int32),
+                                  jnp.asarray(plan.n - 1, jnp.int32)))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -375,14 +429,14 @@ class LocalCellsFold:
 
     def _cross_lane(self, scr):
         mv = scr[0][...]                                      # (S, L)
-        best = jnp.min(mv, axis=1)                            # (S,)
-        js = jnp.where(mv == best[:, None], scr[1][...], _J_MAX)
-        return best, jnp.min(js, axis=1)
+        best = jnp.min(mv, axis=1, keepdims=True)             # (S, 1)
+        js = jnp.where(mv == best, scr[1][...], _J_MAX)
+        return best, jnp.min(js, axis=1, keepdims=True)
 
     def finalize(self, scr, outs, plan):
         best, end = self._cross_lane(scr)
-        outs[0][0, :] = best
-        outs[1][0, :] = end
+        _write(outs[0], best)
+        _write(outs[1], end)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -429,17 +483,17 @@ class SoftCellsFold:
         m_safe = jnp.maximum(m_run, mx)
         add = jnp.zeros_like(m_safe)
         for x in xs:
-            add = add + jnp.exp(x - m_safe)
+            add = add + soft_exp(x - m_safe)
         scr[2][...] = m_safe
-        scr[3][...] = s_run * jnp.exp(m_run - m_safe) + add
+        scr[3][...] = s_run * soft_exp(m_run - m_safe) + add
 
     def finalize(self, scr, outs, plan):
         _, end = LocalCellsFold()._cross_lane(scr[:2])
         m_l, s_l = scr[2][...], scr[3][...]                   # (S, L)
-        m_g = jnp.max(m_l, axis=1)                            # (S,)
-        s_g = jnp.sum(s_l * jnp.exp(m_l - m_g[:, None]), axis=1)
-        outs[0][0, :] = -plan.spec.gamma * (m_g + jnp.log(s_g))
-        outs[1][0, :] = end
+        m_g = jnp.max(m_l, axis=1, keepdims=True)             # (S, 1)
+        s_g = jnp.sum(s_l * soft_exp(m_l - m_g), axis=1, keepdims=True)
+        _write(outs[0], -plan.spec.gamma * (m_g + soft_log(s_g)))
+        _write(outs[1], end)
 
 
 # ----------------------------------------------------------------- plan
@@ -774,9 +828,11 @@ def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
         # true boundary there.
         refs[plan.num_outputs - 1][0, 0] = jnp.where(
             rblk > 0, strip_refs[0][...].astype(jnp.float32),
-            jnp.full((SUBLANES, m), plan.big, jnp.float32))
+            jnp.full((SUBLANES, strip_len(m)), plan.big, jnp.float32))
 
-    r_blk = r_ref[0]                      # (w, LANES)
+    def ref_row(ref, k):                  # (1, LANES): reference slot k
+        return ref[0, k:k + 1, :].astype(cdt)
+
     # global ref index of lane's k=0 cell; a reverse band-skip grid
     # starts block_offset layout blocks in (leading flipped columns are
     # out of band for every row), forward grids start at 0
@@ -789,10 +845,7 @@ def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
 
         # q value for (query s, lane l) = q[s, t - l]; q_ref stores the
         # REVERSED query so this is an ascending slice (no lane flip).
-        qv = pl.load(q_ref, (pl.dslice(0, 1), slice(None),
-                             pl.dslice(m - 1 + LANES - 1 - t,
-                                       LANES)))[0]   # (S, L)
-        qv = qv.astype(cdt)
+        qv = _lane_window(q_ref, m - 1 + LANES - 1 - t, lane).astype(cdt)
 
         # per-step family operand values, laid out exactly like qv /
         # r_blk.  q_prev = q[i_l - 1] is the t-1 slice of the same
@@ -800,18 +853,12 @@ def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
         # pad; lane 0's masked convention value 0 is injected instead).
         ex_step = {}
         if plan.family == "twed":
-            qp = pl.load(q_ref, (pl.dslice(0, 1), slice(None),
-                                 pl.dslice(m - 1 + LANES - 1
-                                           - jnp.maximum(t - 1, 0),
-                                           LANES)))[0].astype(cdt)
+            qp = _lane_window(q_ref, m - 1 + LANES - 1
+                              - jnp.maximum(t - 1, 0), lane).astype(cdt)
             ex_step["q_prev"] = jnp.where(is_row0, jnp.zeros_like(qp), qp)
-            rp_blk = ex_refs["r_prev"][0]                 # (w, LANES)
         elif plan.family == "erp":
-            bt_blk = ex_refs["bt"][0]                     # (w, LANES)
-            ex_step["bl"] = pl.load(
-                ex_refs["bl"], (pl.dslice(0, 1), slice(None),
-                                pl.dslice(m - 1 + LANES - 1 - t,
-                                          LANES)))[0].astype(cdt)
+            ex_step["bl"] = _lane_window(ex_refs["bl"], m - 1 + LANES - 1 - t,
+                                         lane).astype(cdt)
 
         rows = {ch.name: [] for ch in channels}
         lefts = {ch.name: c[1] for ch, c in zip(channels, carry)}
@@ -823,10 +870,10 @@ def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
                 vals3[ch.name] = (lefts[ch.name], up, upleft)
             ex_k = None
             if plan.family == "twed":
-                ex_k = dict(ex_step, r_prev=rp_blk[k].astype(cdt))
+                ex_k = dict(ex_step, r_prev=ref_row(ex_refs["r_prev"], k))
             elif plan.family == "erp":
-                ex_k = dict(ex_step, bt=bt_blk[k].astype(cdt))
-            new = plan.cell(qv, r_blk[k].astype(cdt), is_row0=is_row0,
+                ex_k = dict(ex_step, bt=ref_row(ex_refs["bt"], k))
+            new = plan.cell(qv, ref_row(r_ref, k), is_row0=is_row0,
                             i_l=i_l, j_col=j_base + k, vals3=vals3,
                             extras=ex_k)
             for ch in channels:
@@ -861,7 +908,8 @@ def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
         @pl.when((i127 >= 0) & (i127 < m))
         def _store():
             for ch, strip_ref in zip(channels, strip_refs):
-                ch.write_strip(strip_ref, i127, rows[ch.name][w - 1])
+                ch.write_strip(strip_ref, i127, rows[ch.name][w - 1],
+                               lane=lane)
 
         return tuple(new_carry)
 
@@ -877,11 +925,11 @@ def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
 
 def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
                    r_layout: jnp.ndarray, *extras: jnp.ndarray,
-                   interpret: bool = True):
+                   interpret: bool | None = None):
     """Execute a :class:`KernelPlan` as one ``pallas_call``.
 
     q_rev_pad: (G, SUBLANES, Mp) reversed queries from
-               ``ops.prepare_queries``, Mp = m + 2*(LANES-1)
+               ``ops.prepare_queries``, Mp = ``query_pack_len(m)``
                (a reverse plan takes the FLIPPED queries prepared the
                same way, against ``ops.swizzle_reference_reverse``)
     r_layout:  (R, w, LANES) pre-swizzled reference blocks
@@ -890,6 +938,7 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
                — see ``ops.family_extras``.  They ride the SAME
                pallas_call through plan-driven in_specs; no family
                adds a second kernel.
+    interpret: None = ``ops.default_interpret()`` (compiled on TPU).
     returns    (costs (G, SUBLANES) f32, ends (G, SUBLANES) i32), plus
                starts in the middle for window plans, plus a trailing
                (G, grid_blocks, SUBLANES, m) f32 boundary-strip tensor
@@ -912,27 +961,31 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
             f"reference layout {tuple(r_layout.shape)} does not match "
             f"the plan (segment_width={plan.segment_width}, "
             f"num_ref_blocks={plan.num_ref_blocks})")
-    if Mp != plan.m + 2 * (LANES - 1):
+    if Mp != query_pack_len(plan.m):
         raise ValueError(
-            f"query pack length {Mp} != m + 2*(LANES-1) = "
-            f"{plan.m + 2 * (LANES - 1)} (m={plan.m})")
+            f"query pack length {Mp} != query_pack_len(m) = "
+            f"{query_pack_len(plan.m)} (m={plan.m})")
 
+    if interpret is None:
+        from repro.kernels.ops import default_interpret  # imports us
+        interpret = default_interpret()
     kernel = functools.partial(_generic_kernel, plan=plan)
     grid = (G, plan.grid_blocks)
-    out_shape = [jax.ShapeDtypeStruct((G, SUBLANES), jnp.float32),
-                 jax.ShapeDtypeStruct((G, SUBLANES), jnp.int32)]
-    out_specs = [pl.BlockSpec((1, SUBLANES), lambda b, r: (b, 0)),
-                 pl.BlockSpec((1, SUBLANES), lambda b, r: (b, 0))]
-    if plan.with_window:
-        out_shape.append(jax.ShapeDtypeStruct((G, SUBLANES), jnp.int32))
-        out_specs.append(pl.BlockSpec((1, SUBLANES), lambda b, r: (b, 0)))
+    # per-query results leave as lane-dense (SUBLANES, LANES) tiles,
+    # every lane holding the same value (see _write)
+    dtypes = [jnp.float32, jnp.int32] + ([jnp.int32] * plan.with_window)
+    out_shape = [jax.ShapeDtypeStruct((G, SUBLANES, LANES), dt)
+                 for dt in dtypes]
+    out_specs = [pl.BlockSpec((1, SUBLANES, LANES), lambda b, r: (b, 0, 0))
+                 for _ in dtypes]
+    ms = strip_len(plan.m)
     if plan.checkpoint:
         # one (SUBLANES, m) entry-boundary strip per executed block:
         # the O(M * N/block) residual the fused soft backward
         # re-materializes E tiles from (kernels/backward.py)
         out_shape.append(jax.ShapeDtypeStruct(
-            (G, plan.grid_blocks, SUBLANES, plan.m), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, 1, SUBLANES, plan.m),
+            (G, plan.grid_blocks, SUBLANES, ms), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, SUBLANES, ms),
                                       lambda b, r: (b, r, 0, 0)))
     off = plan.block_offset
     in_specs = [
@@ -969,7 +1022,9 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
         out_shape=tuple(out_shape), scratch_shapes=scratch,
         interpret=interpret, **kwargs,
     )(q_rev_pad, r_layout, *extras)
+    out = [x[:, :, 0] for x in out[:len(dtypes)]] + \
+        [x[..., :plan.m] for x in out[len(dtypes):]]
     if plan.with_window:
         costs, ends, starts = out
         return costs, starts, ends
-    return out                    # (costs, ends[, checkpoints])
+    return tuple(out)             # (costs, ends[, checkpoints])

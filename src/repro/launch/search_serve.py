@@ -40,6 +40,7 @@ import time
 from repro import obs
 from repro.core.spec import DISTANCES, REDUCTIONS, DPSpec
 from repro.data.cbf import make_search_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.search import ReferenceIndex, SearchConfig, SearchService
 
 log = logging.getLogger(__name__)
@@ -86,6 +87,7 @@ def main(argv=None):
                     help="per-request deadline; omit for none (--stream)")
     args = ap.parse_args(argv)
     obs.configure_logging()
+    enable_compile_cache()
 
     spec = DPSpec(distance=args.distance, reduction=args.reduction,
                   gamma=args.gamma, band=args.band)

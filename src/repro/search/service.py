@@ -443,6 +443,10 @@ class SearchService:
         return nominations
 
     # ----------------------------------------------------------- sweeps
+    def sessions(self) -> list[Aligner]:
+        """The per-reference sweep sessions built so far."""
+        return list(self._aligners.values())
+
     def _aligner(self, entry) -> Aligner:
         """The reference's precompiled session (built on first sweep).
 

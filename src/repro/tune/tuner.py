@@ -269,6 +269,8 @@ def autotune(reference, *, m: int, batch: int,
 
     measured: dict[str, float] = {}
     started = time.monotonic()
+    from repro.backends import registry
+    on_tpu = registry._device_default() == "tpu"
 
     def exhausted() -> bool:
         if len(measured) >= budget.max_trials:
@@ -281,7 +283,11 @@ def autotune(reference, *, m: int, batch: int,
             return
         try:
             secs = float(timer(label, make_fn))
-        except Exception as e:   # a failing trial loses, never crashes
+        except Exception as e:
+            if on_tpu and label.startswith("kernel:"):
+                # the compiled kernel is the TPU's main path: a trial
+                # that cannot run it is a broken kernel, not a slow one
+                raise
             log.warning("tune trial %s failed: %s", label, e)
             return
         measured[label] = secs

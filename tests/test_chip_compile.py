@@ -1,0 +1,108 @@
+"""Compile every kernel plan of the main path for a TPU v5e that is
+described, not attached, at the paper's shapes (512 queries x 2,000
+samples against a 100,000-sample reference).
+
+Interpret mode cannot see what the chip's compiler refuses (lane slices
+not aligned to 128, blocks that break the (8, 128) tiling rule, gathers
+Mosaic cannot lower, programs larger than the device memory).  These
+compiles can, in a second or two each, and each must hold the Pallas
+kernel as a ``tpu_custom_call``.  Nothing runs: results are checked by
+the interpret-mode suites and on the chip by ``chip_smoke.py``.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.core.spec import DPSpec
+from repro.kernels import backward, ops
+from repro.kernels.wavefront import LANES, SUBLANES, query_pack_len
+
+B, M, N = 512, 2_000, 100_000      # configs/paper_sdtw.PAPER
+W = 8                              # default segment width
+
+PLANS = {
+    "hardmin": (DPSpec(), False),
+    "window": (DPSpec(), True),
+    "softmin": (DPSpec(reduction="softmin", gamma=0.5), False),
+    "band_skip": (DPSpec(band=128), False),
+    "twed": (DPSpec(family="twed"), False),
+    "erp": (DPSpec(family="erp"), False),
+    "local": (DPSpec(family="local"), False),
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A single v5e chip of a described 2x2 topology.  The persistent
+    compilation cache is off meanwhile: entries compiled for a described
+    chip cannot be read back here."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture
+def tpu_math(monkeypatch):
+    """Trace the soft-min's exp/log as the chip would (the check is made
+    at trace time), with no such program left in JAX's caches after."""
+    from repro.core import spec
+    monkeypatch.setattr(spec, "_tpu_math", lambda: True)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def _sds(shape, sharding, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", sorted(PLANS))
+def test_wavefront_plan_compiles(one_chip, tpu_math, name):
+    spec, window = PLANS[name]
+    blocks = ops.ceil_to(N, LANES * W) // (LANES * W)
+    q = _sds((B // SUBLANES, SUBLANES, query_pack_len(M)), one_chip)
+    r = _sds((blocks, W, LANES), one_chip)
+    extras = {"twed": (r,), "erp": (r, q)}.get(name, ())
+
+    def sweep(q, r, *extras):
+        return ops.sdtw_wavefront_prepped(
+            q, r, batch=B, m=M, n=N, segment_width=W, interpret=False,
+            spec=spec, return_window=window, extras=extras)
+    _assert_kernel(jax.jit(sweep).lower(q, r, *extras).compile())
+
+
+def test_fused_soft_backward_compiles(one_chip, tpu_math):
+    """Forward+reverse sweeps and the tile fold of the soft-DTW
+    gradient at 8 x 2,000 against 100,000 fit one chip."""
+    spec = DPSpec(reduction="softmin", gamma=0.5)
+
+    def loss(q, r):
+        return backward.sdtw_soft_fused(q, r, spec=spec, segment_width=W,
+                                        interpret=False)[0].sum()
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        _sds((8, M), one_chip), _sds((N,), one_chip)).compile()
+    _assert_kernel(compiled)
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("shape", [(B, M), (1, N)])
+def test_normalizer_compiles(one_chip, shape):
+    compiled = jax.jit(lambda x: ops.normalize(x, interpret=False)).lower(
+        _sds(shape, one_chip)).compile()
+    _assert_kernel(compiled)

@@ -34,7 +34,6 @@ def test_hlo_cost_counts_collectives_inside_loops():
 import jax, jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.utils import hlo_cost
 mesh = jax.make_mesh((2,), ("x",))
 def f(a):
@@ -43,7 +42,7 @@ def f(a):
         return c + 1.0, lax.psum(c, "x")   # one all-reduce per iteration
     _, ys = lax.scan(body, a, None, length=5)
     return ys[-1]
-g = shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P())
+g = jax.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P())
 c = jax.jit(g).lower(jax.ShapeDtypeStruct((8, 128), jnp.float32)).compile()
 got = hlo_cost.analyze(c.as_text())
 # 5 iterations x (4*128 rows local) x 4B x2 (all-reduce) = 2*5*4*128*4

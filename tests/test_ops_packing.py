@@ -8,6 +8,7 @@ from repro.core.api import sdtw
 from repro.core.ref import sdtw_ref
 from repro.kernels import ops
 from repro.kernels.sdtw_wavefront import LANES, SUBLANES
+from repro.kernels.wavefront import query_pack_len
 
 
 def test_swizzle_round_trip(rng):
@@ -36,11 +37,17 @@ def test_prepare_queries_layout(rng):
     B, M = 3, 20
     q = rng.normal(size=(B, M)).astype(np.float32)
     qk = np.asarray(ops.prepare_queries(jnp.asarray(q)))
-    assert qk.shape == (1, SUBLANES, M + 2 * (LANES - 1))
-    # row s holds the reversed query between the two LANES-1 pads
+    mp = query_pack_len(M)
+    assert qk.shape == (1, SUBLANES, mp)
+    # every per-step window, read as two aligned lane tiles, stays
+    # inside the row: the last one starts at M + LANES - 2
+    assert mp >= (M + LANES - 2) // LANES * LANES + 2 * LANES
+    # row s holds the reversed query behind LANES-1 zeros, then zeros
     for s in range(B):
+        np.testing.assert_array_equal(qk[0, s, :LANES - 1], 0.0)
         np.testing.assert_array_equal(
             qk[0, s, LANES - 1:LANES - 1 + M], q[s, ::-1])
+        np.testing.assert_array_equal(qk[0, s, LANES - 1 + M:], 0.0)
     # rows beyond B are zero padding, dropped by the [:B] trim
     np.testing.assert_array_equal(qk[0, B:], 0.0)
 

@@ -177,3 +177,41 @@ def test_fused_grad_never_materializes_mn(data):
     assert fused_peak < mn, (fused_peak, mn)
     engine_peak = _max_buffer_elems(lambda qq: grad_engine(qq)[1], q)
     assert engine_peak >= mn, (engine_peak, mn)
+
+
+# ------------------------------------------- soft-min exp / log on TPU
+@pytest.fixture
+def tpu_math(monkeypatch):
+    """Trace the soft-min's exp/log as for a TPU (the accurate float32
+    pair), with no program traced otherwise left in JAX's caches."""
+    from repro.core import spec
+    monkeypatch.setattr(spec, "_tpu_math", lambda: True)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def test_tpu_exp_log_accuracy():
+    from repro.core import spec
+    for lo, hi in [(-87, -5), (-5, 0), (0, 5)]:
+        x = np.linspace(lo, hi, 4096, dtype=np.float32)
+        got = np.asarray(spec._exp_f32(jnp.asarray(x)), np.float64)
+        want = np.exp(x.astype(np.float64))
+        assert np.max(np.abs(got - want) / want) < 2e-7
+    edge = spec._exp_f32(jnp.asarray([-np.inf, -100.0, 0.0], jnp.float32))
+    assert np.asarray(edge).tolist() == [0.0, 0.0, 1.0]
+    s = np.linspace(1, 3, 4096, dtype=np.float32)
+    got = np.asarray(spec._log_f32(jnp.asarray(s)), np.float64)
+    assert np.max(np.abs(got - np.log(s.astype(np.float64)))) < 3e-7
+
+
+def test_tpu_math_grad_parity(data, tpu_math):
+    """With the soft-min traced as for a TPU, the fused kernel gradient
+    still matches the gradient through the engine."""
+    q, r = data
+    spec = _spec(0.5)
+    gk = jax.grad(lambda q: kb.sdtw_soft_fused(
+        q, r, spec=spec, segment_width=SEG, interpret=True)[0].sum())(q)
+    ge = jax.grad(lambda q: sdtw_engine(q, r, spec=spec)[0].sum())(q)
+    np.testing.assert_allclose(np.asarray(gk), np.asarray(ge),
+                               rtol=1e-4, atol=1e-4)

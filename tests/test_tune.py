@@ -309,6 +309,30 @@ def test_deterministic_winner_on_fake_timer(data):
         assert "kernel:w2" in res.measured
 
 
+@pytest.mark.parametrize("device", ["cpu", "tpu"])
+def test_failing_kernel_trial(data, monkeypatch, device):
+    """Off the chip a kernel trial that raises just loses to the engine;
+    on a TPU it is the compiled main path failing, so it surfaces."""
+    from repro.backends import registry
+    monkeypatch.setattr(registry, "_device_default", lambda: device)
+    _, r = data
+
+    def timer(label, make_fn):
+        if label.startswith("kernel:"):
+            raise RuntimeError("Mosaic refused the kernel")
+        return 1.0
+
+    def run():
+        return tune.autotune(r, m=20, batch=5, candidates=WIDTHS,
+                             interpret=True, cache=tune.TuningCache(None),
+                             metrics=MetricsRegistry(), timer=timer)
+    if device == "tpu":
+        with pytest.raises(RuntimeError, match="Mosaic refused"):
+            run()
+    else:
+        assert run().backend == "engine"
+
+
 def test_budget_max_trials_respected(data):
     _, r = data
     timer = fake_timer({})
