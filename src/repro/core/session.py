@@ -20,10 +20,18 @@ An ``Aligner`` is constructed once per (reference, spec, backend) and
   * caches the swizzled ``(R, w, LANES)`` kernel layout from
     ``kernels/ops.py`` prep, so the kernel backend's offline reference
     layout optimization (paper §3) is actually offline;
-  * memoizes one jitted executable per (batch shape, dtype, outputs)
-    request — warm calls are cache-lookup + dispatch, zero retraces
-    (``Aligner.stats`` counts traces/compiles/hits; the tier-1 suite
-    asserts the zero).
+  * memoizes one compiled executable per (batch shape, dtype,
+    outputs) request, traced and compiled inside the
+    ``aligner.build`` span — warm calls are cache-lookup + dispatch,
+    zero retraces (``Aligner.stats`` counts traces/compiles/hits; the
+    tier-1 suite asserts the zero);
+  * counts the work of each wavefront kernel dispatch into the
+    process-wide ``kernel.wavefront.*`` counters
+    (``kernels.ops.count_wavefront``), from a work count made once per
+    executable.
+
+Each call runs inside an ``aligner.call`` span, which holds
+``aligner.build`` (cold calls) and ``aligner.dispatch``.
 
 Results are typed :class:`~repro.core.result.SDTWResult` pytrees, same
 as ``repro.sdtw``; capability validation (spec × backend × outputs)
@@ -41,6 +49,7 @@ import dataclasses
 import logging
 import threading
 from collections import OrderedDict
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -64,18 +73,18 @@ class AlignerStats:
     ``traces`` counts executions of a traced function body (a Python
     side effect inside the jitted closure, so it only ticks while JAX
     is tracing); a warm call leaves it unchanged.  ``compiles`` counts
-    jitted executables successfully brought to their first dispatch —
-    ``jax.jit`` traces *and compiles* lazily at that first call, so the
-    counter ticks AFTER the call returns, never at build time: a build
-    whose first dispatch raises leaves ``compiles`` (and the executable
-    cache) untouched, and eager strategies (distributed) never tick it.
+    jitted executables successfully brought to their first dispatch:
+    the build traces and compiles, and the counter ticks only AFTER
+    the first dispatch returns — a build or first dispatch that raises
+    leaves ``compiles`` (and the executable cache) untouched, and
+    eager strategies (distributed) never tick it.
     ``calls``/``cache_hits`` count dispatches; ``evictions`` counts
     executables dropped by the ``max_executables`` LRU bound.
 
     Every field is mirrored into the session's
-    :class:`~repro.obs.MetricsRegistry` under ``aligner.*`` (plus an
-    ``aligner.cache_hit_rate`` gauge), so cross-session aggregates live
-    in ``repro.obs`` while this dataclass stays the per-session view.
+    :class:`~repro.obs.MetricsRegistry` under ``aligner.*``, so
+    cross-session aggregates live in ``repro.obs`` while this dataclass
+    stays the per-session view.
     """
     calls: int = 0
     cache_hits: int = 0
@@ -85,6 +94,21 @@ class AlignerStats:
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Executable:
+    """One cached executable of a session.
+
+    ``run`` is what a call dispatches: the compiled program, or the
+    eager strategy itself.  ``jitted`` is the jitted function the
+    program was compiled from (None for eager strategies), kept for
+    :meth:`Aligner.hlo_texts`.  ``work`` holds the
+    :meth:`~repro.kernels.wavefront.KernelPlan.work` of each wavefront
+    kernel dispatch that one call makes."""
+    run: Callable
+    jitted: Callable | None
+    work: tuple = ()
 
 
 class Aligner:
@@ -275,11 +299,13 @@ class Aligner:
         """One executable for one (batch shape, dtype, outputs) key.
 
         Capability validation happens here (loud registry errors);
-        the returned ``(callable, jitted)`` pair runs normalize-queries
-        + the fused sweep as ONE traced computation, returning the
-        sweep-level ``SDTWResult``.  ``jitted=False`` marks the
-        eager strategies (distributed), whose dispatches must not tick
-        the trace/compile counters — nothing is traced or built.
+        the returned ``(callable, jitted, work)`` triple runs
+        normalize-queries + the fused sweep as ONE traced computation,
+        returning the sweep-level ``SDTWResult``.  ``jitted=False``
+        marks the eager strategies (distributed), whose dispatches must
+        not tick the trace/compile counters — nothing is traced or
+        built.  ``work`` is the wavefront kernel work of each dispatch
+        that one call makes (empty off the kernel backend).
         """
         # re-validate with the requested outputs: an Aligner built for
         # a capable (spec, backend) pair can still be asked for an
@@ -303,10 +329,17 @@ class Aligner:
             # (repro.kernels.backward) and fills cost/end/E together —
             # no engine cost matrix, no derivation pass
             from repro.kernels import backward
+            from repro.kernels import ops as _ops
             w = self.resolved_width(batch_shape, req)
             interp, spec = self.interpret, self.spec
             reference = self.reference
             norm = self.normalize
+            work = _ops.wavefront_work(spec, batch=batch_shape[0],
+                                       m=batch_shape[1], n=self.length,
+                                       segment_width=w)
+            # the checkpointed forward and the reverse sweep execute
+            # the same blocks over the same real columns
+            works = () if work is None else (work, work)
 
             def run_fused(q):
                 stats.traces += 1
@@ -318,7 +351,7 @@ class Aligner:
                     interpret=interp)
                 return SDTWResult(cost=cost, end=end, soft_alignment=E)
 
-            return jax.jit(run_fused), True
+            return jax.jit(run_fused), True, works
 
         if self.backend.name == "kernel":
             # the session's whole point on the kernel path: the layout
@@ -340,6 +373,9 @@ class Aligner:
             # and closed over next to r_layout
             extras_ref = _ops.family_extras_ref(spec, self.reference,
                                                 segment_width=w)
+            work = _ops.wavefront_work(spec, batch=B, m=m, n=n,
+                                       segment_width=w)
+            works = () if work is None else (work,)
 
             def run(q):
                 stats.traces += 1
@@ -355,7 +391,7 @@ class Aligner:
                     return_window="start" in sweep, extras=extras)
                 return from_sweep(out, sweep)
 
-            return jax.jit(run), True
+            return jax.jit(run), True, works
 
         backend, spec = self.backend, self.spec
         norm = self.normalize and not pre_normalized
@@ -375,7 +411,7 @@ class Aligner:
                     interpret=interp, outputs=sweep, options=opts)
                 return backend.execute(spec, plan)
 
-            return run_eager, False
+            return run_eager, False, ()
 
         def run(q):
             stats.traces += 1
@@ -387,7 +423,7 @@ class Aligner:
                 interpret=interp, outputs=sweep, options=opts)
             return backend.execute(spec, plan)
 
-        return jax.jit(run), True
+        return jax.jit(run), True, ()
 
     def _fused(self, req: frozenset) -> bool:
         """Does this request dispatch the kernel's fused forward+reverse
@@ -403,6 +439,10 @@ class Aligner:
         first call for a given (batch shape, dtype, outputs) traces and
         compiles; every later call with the same key is dispatch-only.
         """
+        with self._tracer.span("aligner.call"):
+            return self._align(queries, outputs)
+
+    def _align(self, queries, outputs) -> SDTWResult:
         queries = jnp.asarray(queries)
         validate_batch_inputs(queries, self.reference,
                               segment_width=None if self._auto_width
@@ -432,24 +472,35 @@ class Aligner:
                                        backend=self.backend.name,
                                        batch=list(queries.shape),
                                        outputs=sorted(req)):
-                    entry = self._build(queries.shape, queries.dtype, req)
+                    fn, jitted, work = self._build(queries.shape,
+                                                   queries.dtype, req)
+                    # jax.jit would trace and compile lazily, inside
+                    # the first dispatch: do both here, so this span
+                    # holds them and the dispatch span only runs
+                    entry = _Executable(
+                        run=fn.lower(queries).compile() if jitted
+                        else fn,
+                        jitted=fn if jitted else None, work=work)
                 log.debug("built executable key=%s backend=%s",
                           key, self.backend.name)
             with self._tracer.span("aligner.dispatch",
                                    backend=self.backend.name,
                                    batch=list(queries.shape),
                                    cold=cold) as sp:
-                res = entry[0](queries)
+                res = entry.run(queries)
                 sp.sync(res)
+            if entry.work:
+                from repro.kernels import ops as _ops
+                for work in entry.work:
+                    _ops.count_wavefront(work)
             if cold:
-                # cache + count only now: jax.jit traces AND compiles
-                # lazily at that first dispatch, so an executable (and
-                # its ``compiles`` tick) exists exactly when the call
-                # above succeeded — eager strategies (jitted=False)
+                # cache + count only now: an executable (and its
+                # ``compiles`` tick) exists exactly when its first
+                # dispatch succeeded — eager strategies (jitted None)
                 # build none and tick nothing
                 with self._fns_lock:
                     self._fns[key] = entry
-                    if entry[1]:
+                    if entry.jitted is not None:
                         self.stats.compiles += 1
                         m.inc("aligner.compiles")
                     while len(self._fns) > self.max_executables:
@@ -467,9 +518,6 @@ class Aligner:
         if derived:
             res = _derive_outputs(res, req, queries, self.reference,
                                   self.spec)
-        m.set_gauge("aligner.cache_hit_rate",
-                    m.value("aligner.cache_hits") /
-                    max(m.value("aligner.calls"), 1))
         return res.restrict(req)
 
     __call__ = align
@@ -477,16 +525,17 @@ class Aligner:
     def executables(self) -> int:
         """How many distinct jitted executables this session holds."""
         with self._fns_lock:
-            return sum(1 for _, jitted in self._fns.values() if jitted)
+            return sum(1 for e in self._fns.values()
+                       if e.jitted is not None)
 
     def hlo_texts(self) -> list[str]:
         """Compiled HLO of every jitted executable this session holds:
         what the device runs.  On a TPU a kernel session's text holds
-        the Pallas kernel as a ``tpu_custom_call``.  Lowering traces
-        each executable once more (``stats.traces`` counts it)."""
+        the Pallas kernel as a ``tpu_custom_call``.  Each text is
+        lowered and compiled again from the kept jitted function."""
         with self._fns_lock:
-            held = [(key, fn) for key, (fn, jitted) in self._fns.items()
-                    if jitted]
+            held = [(key, e.jitted) for key, e in self._fns.items()
+                    if e.jitted is not None]
         return [fn.lower(jax.ShapeDtypeStruct(shape, jnp.dtype(dtype)))
                 .compile().as_text() for (shape, dtype, _), fn in held]
 

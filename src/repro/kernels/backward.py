@@ -80,12 +80,6 @@ def _geometry(spec: DPSpec, m: int, n: int, w: int):
     return W, n_pad, R, Gf
 
 
-def _statically_blocked(spec: DPSpec, m: int, n: int) -> bool:
-    """The band excludes every real bottom-row cell: no alignment
-    exists (same static short-circuit as ``ops.sdtw_wavefront``)."""
-    return spec.band is not None and m - 1 - spec.band > n - 1
-
-
 # ------------------------------------------------------------- sweeps
 @functools.partial(jax.jit, static_argnames=("spec", "segment_width",
                                              "interpret"))
@@ -282,7 +276,7 @@ def _sdtw_soft_kernel(queries, reference, spec, segment_width,
 def _sdtw_soft_fwd(queries, reference, spec, segment_width, interpret):
     B, m = queries.shape
     n = reference.shape[0]
-    if _statically_blocked(spec, m, n):
+    if ops.band_blocks_all(spec, m, n):
         out = (jnp.full((B,), jnp.inf, jnp.float32),
                jnp.zeros((B,), jnp.int32))
         return out, (queries, reference)
@@ -297,7 +291,7 @@ def _sdtw_soft_fwd(queries, reference, spec, segment_width, interpret):
 def _sdtw_soft_bwd(spec, segment_width, interpret, res, cts):
     ct_cost = cts[0]               # cts[1] is the int end's float0 ct
     queries, reference = res[0], res[1]
-    if _statically_blocked(spec, queries.shape[1], reference.shape[0]):
+    if ops.band_blocks_all(spec, queries.shape[1], reference.shape[0]):
         return jnp.zeros_like(queries), jnp.zeros_like(reference)
     _, _, cost, fck, rck = res
     return _fold_grads(queries, reference, cost, fck, rck, ct_cost,
@@ -380,7 +374,7 @@ def soft_alignment_fused(queries, reference, *, spec: DPSpec,
     _validate_soft(spec, "soft_alignment_fused")
     B, m = queries.shape
     n = reference.shape[0]
-    if _statically_blocked(spec, m, n):
+    if ops.band_blocks_all(spec, m, n):
         return (jnp.full((B,), jnp.inf, jnp.float32),
                 jnp.zeros((B,), jnp.int32),
                 jnp.zeros((B, m, n), jnp.float32))

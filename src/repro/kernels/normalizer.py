@@ -56,4 +56,5 @@ def normalizer_pallas(x: jnp.ndarray, *, n: int, eps: float = 1e-12,
         out_specs=pl.BlockSpec((1, S, Lp), lambda g: (g, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((G, S, Lp), x.dtype),
         interpret=interpret,
+        name="sdtw_normalizer",
     )(x)

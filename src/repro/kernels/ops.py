@@ -24,6 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.spec import (DEFAULT_SPEC, NO_WINDOW,  # noqa: F401
                              PAD_VALUE, DPSpec)
 # PAD_VALUE re-exported: cost >= (q - 1e6)^2 never wins — the dtype
@@ -195,6 +196,55 @@ def kernel_plan(spec: DPSpec | None = None, *, m: int, n: int,
                       n=n if sp.family != "sdtw" else None)
 
 
+def band_blocks_all(spec: DPSpec, m: int, n: int) -> bool:
+    """Does the band exclude every fold-eligible cell, so that no
+    alignment exists?  Static in (m, n, band): such a call is answered
+    without dispatching the kernel."""
+    if spec.band is None:
+        return False
+    if spec.family in ("twed", "erp"):
+        # global families: the corner (m-1, n-1) sits |m-n| off the
+        # diagonal — a tighter band disconnects the global path
+        return spec.band < abs(m - n)
+    if spec.family == "local":
+        return False                 # cell (0, 0) is always in band
+    return m - 1 - spec.band > n - 1
+
+
+def wavefront_work(spec: DPSpec | None = None, *, batch: int, m: int,
+                   n: int, segment_width: int = 8) -> dict | None:
+    """:meth:`KernelPlan.work` of one dispatch of these (unpadded)
+    shapes, or None where :func:`band_blocks_all` answers the call and
+    no kernel runs.  The work does not depend on the compute dtype or
+    on the outputs asked for."""
+    sp = DEFAULT_SPEC if spec is None else spec
+    if band_blocks_all(sp, m, n):
+        return None
+    return kernel_plan(sp, m=m, n=n,
+                       segment_width=segment_width).work(batch, n)
+
+
+_WORK_COUNTERS = tuple((k, f"kernel.wavefront.{k}") for k in
+                       ("grid_steps", "loop_steps", "lane_cells",
+                        "cells_real"))
+
+
+def count_wavefront(work: dict) -> None:
+    """Add one wavefront dispatch's :meth:`KernelPlan.work` to the
+    process-wide counters ``kernel.wavefront.dispatches`` /
+    ``.grid_steps`` / ``.loop_steps`` / ``.lane_cells`` /
+    ``.cells_real`` of :func:`repro.obs.default_registry`.
+
+    Process-wide because the chip and its kernels belong to the
+    process, not to a session.  Call it on the host once per dispatch
+    that ran, never from code that runs while JAX traces: a jitted
+    function's body runs once per trace, not once per call."""
+    reg = obs.default_registry()
+    reg.inc("kernel.wavefront.dispatches")
+    for key, name in _WORK_COUNTERS:
+        reg.inc(name, work[key])
+
+
 @functools.partial(jax.jit, static_argnames=("spec", "segment_width",
                                              "compute_dtype"))
 def family_extras_ref(spec: DPSpec, reference, *, segment_width,
@@ -317,26 +367,15 @@ def sdtw_wavefront_prepped(q_prepped: jnp.ndarray, r_layout: jnp.ndarray, *,
     validate_prepped(q_prepped, r_layout, m=m, n=n,
                      segment_width=segment_width)
     sp = DEFAULT_SPEC if spec is None else spec
-    if sp.band is not None:
-        if sp.family in ("twed", "erp"):
-            # global families: the corner (m-1, n-1) sits |m-n| off the
-            # diagonal — a tighter band disconnects the global path
-            blocked = sp.band < abs(m - n)
-        elif sp.family == "local":
-            blocked = False              # cell (0, 0) is always in band
-        else:
-            blocked = m - 1 - sp.band > n - 1
-        if blocked:
-            # the band excludes every fold-eligible cell: no alignment
-            # exists.  Static in (m, n, band), so answer without
-            # touching the kernel — engine parity (+inf, end 0,
-            # NO_WINDOW start)
-            costs = jnp.full((batch,), jnp.inf, jnp.float32)
-            ends = jnp.zeros((batch,), jnp.int32)
-            if return_window:
-                return (costs, jnp.full((batch,), NO_WINDOW, jnp.int32),
-                        ends)
-            return costs, ends
+    if band_blocks_all(sp, m, n):
+        # no alignment exists: answer without touching the kernel —
+        # engine parity (+inf, end 0, NO_WINDOW start)
+        costs = jnp.full((batch,), jnp.inf, jnp.float32)
+        ends = jnp.zeros((batch,), jnp.int32)
+        if return_window:
+            return (costs, jnp.full((batch,), NO_WINDOW, jnp.int32),
+                    ends)
+        return costs, ends
     out = _dispatch(q_prepped, r_layout, tuple(extras), m=m,
                     segment_width=segment_width,
                     compute_dtype=compute_dtype,
@@ -365,6 +404,9 @@ def sdtw_wavefront(queries: jnp.ndarray, reference: jnp.ndarray, *,
     interpret: None = auto (compiled on TPU, interpreted elsewhere).
     Returns (costs (B,) f32, end_indices (B,) i32), or
     (costs, starts, ends) when ``return_window``.
+
+    A call on concrete arrays counts its dispatch
+    (:func:`count_wavefront`); a call while JAX traces counts nothing.
     """
     queries = jnp.asarray(queries)
     reference = jnp.asarray(reference)
@@ -376,10 +418,16 @@ def sdtw_wavefront(queries: jnp.ndarray, reference: jnp.ndarray, *,
     extras = family_extras(sp, queries, reference,
                            segment_width=segment_width,
                            compute_dtype=compute_dtype)
-    return sdtw_wavefront_prepped(
+    out = sdtw_wavefront_prepped(
         qk, rk, batch=B, m=M, n=N, segment_width=segment_width,
         compute_dtype=compute_dtype, interpret=interpret, spec=spec,
         return_window=return_window, extras=extras)
+    if not isinstance(qk, jax.core.Tracer):      # not while tracing
+        work = wavefront_work(sp, batch=B, m=M, n=N,
+                              segment_width=segment_width)
+        if work is not None:
+            count_wavefront(work)
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=("n", "interpret"))

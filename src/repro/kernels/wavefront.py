@@ -699,6 +699,37 @@ class KernelPlan:
             "padded_cols": self.num_ref_blocks * block_cols,
         }
 
+    def work(self, batch: int, n: int) -> dict:
+        """What one dispatch of this plan does for ``batch`` queries
+        against a reference of true length ``n``, as plain integers:
+
+          * ``grid_steps``: query groups x executed reference blocks;
+          * ``loop_steps``: serial wavefront steps, ``m + LANES - 1``
+            per grid step (the pipeline fills and drains every block);
+          * ``lane_cells``: cells those steps update, SUBLANES x LANES
+            x ``segment_width`` each, padding and pipeline fill
+            included;
+          * ``cells_real``: the real query x real column cells inside
+            the executed blocks, never more than ``lane_cells``.
+
+        Forward and reverse sweeps execute the same number of blocks
+        and hold the same real columns, so both read the same work."""
+        block_cols = LANES * self.segment_width
+        if not 0 < n <= self.num_ref_blocks * block_cols:
+            raise ValueError(
+                f"reference length n={n} does not fit the plan's "
+                f"{self.num_ref_blocks} blocks of {block_cols} columns")
+        grid_steps = _ceil_to(batch, SUBLANES) // SUBLANES \
+            * self.grid_blocks
+        loop_steps = grid_steps * (self.m + LANES - 1)
+        return {
+            "grid_steps": grid_steps,
+            "loop_steps": loop_steps,
+            "lane_cells": loop_steps * SUBLANES * block_cols,
+            "cells_real": batch * self.m
+            * min(n, self.grid_blocks * block_cols),
+        }
+
     # ------------------------------------------------------------ cell
     def cell(self, qv, rv, *, is_row0, i_l, j_col, vals3, extras=None):
         """One DP cell across every channel.
@@ -1017,10 +1048,13 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"))
+    # a stable name for the profiler's trace: the HLO op is named
+    # after it, whatever jitted function dispatches the kernel
+    name = "sdtw_wavefront_reverse" if plan.reverse else "sdtw_wavefront"
     out = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=tuple(out_specs),
         out_shape=tuple(out_shape), scratch_shapes=scratch,
-        interpret=interpret, **kwargs,
+        interpret=interpret, name=name, **kwargs,
     )(q_rev_pad, r_layout, *extras)
     out = [x[:, :, 0] for x in out[:len(dtypes)]] + \
         [x[..., :plan.m] for x in out[len(dtypes):]]

@@ -13,6 +13,13 @@ every dispatch would serialize the pipeline) skips the block and tags
 the event ``synced: False`` so a reader knows the number is
 enqueue-side.
 
+With ``profiler=True`` every span is also a
+``jax.profiler.TraceAnnotation`` of the same name, so it lands on the
+host timeline of any profile the process takes (``jax.profiler.trace``,
+TensorBoard, Perfetto), on the device trace's clock: idle gaps on the
+device can then be laid beside the host work that caused them.  Off by
+default.
+
 Spans nest through a per-thread stack: each finished event records its
 depth and parent span, and completed events are appended in finish
 order (children before parents), which the tier-1 suite asserts.
@@ -54,7 +61,7 @@ class Span:
     exit when the tracer is device_sync)."""
 
     __slots__ = ("name", "args", "start_ns", "end_ns", "depth", "parent",
-                 "_sync_values")
+                 "_sync_values", "_annotation")
 
     def __init__(self, name: str, args: dict, depth: int,
                  parent: str | None):
@@ -65,6 +72,7 @@ class Span:
         self.start_ns = 0
         self.end_ns = 0
         self._sync_values: list = []
+        self._annotation = None
 
     def set(self, **kw) -> "Span":
         self.args.update(kw)
@@ -107,12 +115,16 @@ class Tracer:
     quantiles over repeated regions (p50/p99 dispatch latency) come for
     free.  ``device_sync``: block on values registered via
     :meth:`Span.sync` before timing the exit (see module docstring).
+    ``profiler``: also write every span into the JAX profiler's trace
+    as a ``TraceAnnotation`` (see module docstring).
     """
 
     def __init__(self, *, metrics: MetricsRegistry | None = None,
-                 device_sync: bool = False, max_events: int = 1_000_000):
+                 device_sync: bool = False, max_events: int = 1_000_000,
+                 profiler: bool = False):
         self.metrics = metrics
         self.device_sync = bool(device_sync)
+        self.profiler = bool(profiler)
         self.max_events = max_events
         self.epoch_ns = time.perf_counter_ns()
         self._events: list[dict] = []
@@ -135,10 +147,22 @@ class Tracer:
         return st
 
     def _enter(self, span: Span) -> None:
+        if self.profiler:
+            import jax
+            span._annotation = jax.profiler.TraceAnnotation(span.name)
+            span._annotation.__enter__()
         self._stack().append(span)
         span.start_ns = time.perf_counter_ns()
 
     def _exit(self, span: Span, *, error: bool) -> None:
+        try:
+            self._finish(span, error=error)
+        finally:
+            if span._annotation is not None:
+                span._annotation.__exit__(None, None, None)
+                span._annotation = None
+
+    def _finish(self, span: Span, *, error: bool) -> None:
         synced = False
         if self.device_sync and span._sync_values and not error:
             _block(span._sync_values)

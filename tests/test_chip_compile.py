@@ -6,7 +6,8 @@ Interpret mode cannot see what the chip's compiler refuses (lane slices
 not aligned to 128, blocks that break the (8, 128) tiling rule, gathers
 Mosaic cannot lower, programs larger than the device memory).  These
 compiles can, in a second or two each, and each must hold the Pallas
-kernel as a ``tpu_custom_call``.  Nothing runs: results are checked by
+kernel as a ``tpu_custom_call`` under its stable name, which the
+benchmark's trace reduction finds the kernel by.  Nothing runs: results are checked by
 the interpret-mode suites and on the chip by ``chip_smoke.py``.
 """
 import os
@@ -68,8 +69,11 @@ def _sds(shape, sharding, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_kernel(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_kernel(compiled, name):
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"%{name}" in text
+    return text
 
 
 @pytest.mark.parametrize("name", sorted(PLANS))
@@ -84,7 +88,9 @@ def test_wavefront_plan_compiles(one_chip, tpu_math, name):
         return ops.sdtw_wavefront_prepped(
             q, r, batch=B, m=M, n=N, segment_width=W, interpret=False,
             spec=spec, return_window=window, extras=extras)
-    _assert_kernel(jax.jit(sweep).lower(q, r, *extras).compile())
+    text = _assert_kernel(jax.jit(sweep).lower(q, r, *extras).compile(),
+                          "sdtw_wavefront")
+    assert "sdtw_normalizer" not in text
 
 
 def test_fused_soft_backward_compiles(one_chip, tpu_math):
@@ -97,7 +103,8 @@ def test_fused_soft_backward_compiles(one_chip, tpu_math):
                                         interpret=False)[0].sum()
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         _sds((8, M), one_chip), _sds((N,), one_chip)).compile()
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, "sdtw_wavefront")
+    _assert_kernel(compiled, "sdtw_wavefront_reverse")
     assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
@@ -105,4 +112,5 @@ def test_fused_soft_backward_compiles(one_chip, tpu_math):
 def test_normalizer_compiles(one_chip, shape):
     compiled = jax.jit(lambda x: ops.normalize(x, interpret=False)).lower(
         _sds(shape, one_chip)).compile()
-    _assert_kernel(compiled)
+    text = _assert_kernel(compiled, "sdtw_normalizer")
+    assert "wavefront" not in text

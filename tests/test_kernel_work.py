@@ -1,0 +1,148 @@
+"""The wavefront kernel's work counts: ``KernelPlan.work`` against
+numbers worked out by hand, and the process-wide ``kernel.wavefront.*``
+counters that a session and the one-shot call add at dispatch (kernel
+interpreted on the CPU)."""
+import jax
+import numpy as np
+import pytest
+
+import repro
+from repro import obs
+from repro.core.spec import DPSpec
+from repro.kernels import ops
+from repro.kernels.wavefront import KernelPlan
+
+KEYS = ("dispatches", "grid_steps", "loop_steps", "lane_cells",
+        "cells_real")
+
+
+def counters() -> dict:
+    reg = obs.default_registry()
+    return {k: reg.value(f"kernel.wavefront.{k}") for k in KEYS}
+
+
+def plus(before: dict, *works) -> dict:
+    out = dict(before, dispatches=before["dispatches"] + len(works))
+    for w in works:
+        for k, v in w.items():
+            out[k] += v
+    return out
+
+
+@pytest.mark.parametrize("spec, batch, m, n, w, want", [
+    # the paper's batch: 64 groups x 98 blocks, 2,000 + 127 steps each
+    (DPSpec(), 512, 2_000, 100_000, 8,
+     {"grid_steps": 6_272, "loop_steps": 13_340_544,
+      "lane_cells": 109_285_736_448, "cells_real": 102_400_000_000}),
+    # 13 queries fill 2 groups; 1,000 columns pad to 2 blocks of 512
+    (DPSpec(), 13, 20, 1_000, 4,
+     {"grid_steps": 4, "loop_steps": 4 * 147,
+      "lane_cells": 4 * 147 * 8 * 512, "cells_real": 13 * 20 * 1_000}),
+    # band 100 at m = 200 keeps columns up to 298: 2 of 20 blocks of
+    # 256 run, and only their 512 columns hold real cells
+    (DPSpec(band=100), 8, 200, 5_000, 2,
+     {"grid_steps": 2, "loop_steps": 2 * 327,
+      "lane_cells": 2 * 327 * 8 * 256, "cells_real": 8 * 200 * 512}),
+])
+def test_plan_work_by_hand(spec, batch, m, n, w, want):
+    plan = ops.kernel_plan(spec, m=m, n=n, segment_width=w)
+    work = plan.work(batch, n)
+    assert work == want
+    assert all(type(v) is int for v in work.values())
+    assert work["cells_real"] <= work["lane_cells"]
+
+
+def test_banded_plan_skips_blocks_and_reverse_reads_the_same():
+    spec = DPSpec(reduction="softmin", gamma=0.5, band=100)
+    fwd = ops.kernel_plan(spec, m=200, n=5_000, segment_width=2)
+    assert fwd.skipped_blocks == 18
+    rev = KernelPlan(spec=spec, m=200, segment_width=2,
+                     num_ref_blocks=fwd.num_ref_blocks, reverse=True,
+                     checkpoint=True)
+    assert rev.work(8, 5_000) == fwd.work(8, 5_000)
+
+
+def test_plan_work_refuses_a_reference_the_plan_cannot_hold():
+    plan = ops.kernel_plan(m=20, n=1_000, segment_width=4)
+    with pytest.raises(ValueError, match="does not fit"):
+        plan.work(8, 1_025)
+    with pytest.raises(ValueError, match="does not fit"):
+        plan.work(8, 0)
+
+
+def _data(b=3, m=20, n=300, seed=0):
+    rng = np.random.default_rng(seed)
+    return (rng.normal(size=(b, m)).astype(np.float32),
+            rng.normal(size=n).astype(np.float32))
+
+
+def test_session_counts_one_dispatch_per_call():
+    q, r = _data()
+    obs.reset()
+    aligner = repro.Aligner(r, backend="kernel",
+                            metrics=obs.MetricsRegistry(),
+                            tracer=obs.Tracer())
+    work = ops.kernel_plan(m=20, n=300).work(3, 300)
+    start = counters()
+    aligner(q)                                   # the cold call counts
+    assert counters() == plus(start, work)
+    aligner(q)                                   # and every cache hit
+    aligner(q + 1.0)
+    assert aligner.stats.cache_hits == 2
+    assert counters() == plus(start, work, work, work)
+
+
+def test_session_window_plan_counts_its_own_work():
+    q, r = _data(b=9, m=16, n=700)
+    obs.reset()
+    aligner = repro.Aligner(r, backend="kernel", segment_width=2,
+                            metrics=obs.MetricsRegistry(),
+                            tracer=obs.Tracer())
+    aligner(q, outputs=("cost", "start", "end"))
+    work = ops.kernel_plan(m=16, n=700, segment_width=2,
+                           with_window=True).work(9, 700)
+    assert work["grid_steps"] == 2 * 3
+    assert counters() == plus(dict.fromkeys(KEYS, 0), work)
+
+
+def test_fused_soft_session_counts_both_sweeps():
+    q, r = _data(b=2, m=12, n=200)
+    spec = DPSpec(reduction="softmin", gamma=0.5)
+    obs.reset()
+    aligner = repro.Aligner(r, backend="kernel", spec=spec,
+                            metrics=obs.MetricsRegistry(),
+                            tracer=obs.Tracer())
+    aligner(q, outputs=("cost", "soft_alignment"))
+    work = ops.kernel_plan(spec, m=12, n=200).work(2, 200)
+    assert counters() == plus(dict.fromkeys(KEYS, 0), work, work)
+
+
+def test_blocked_band_dispatches_and_counts_nothing():
+    q, r = _data(b=2, m=50, n=20)
+    obs.reset()
+    aligner = repro.Aligner(r, backend="kernel", band=5,
+                            metrics=obs.MetricsRegistry(),
+                            tracer=obs.Tracer())
+    res = aligner(q)
+    assert np.all(np.isinf(np.asarray(res.cost)))
+    assert counters() == dict.fromkeys(KEYS, 0)
+
+
+def test_engine_session_counts_nothing():
+    q, r = _data()
+    obs.reset()
+    aligner = repro.Aligner(r, backend="engine",
+                            metrics=obs.MetricsRegistry(),
+                            tracer=obs.Tracer())
+    aligner(q)
+    assert counters() == dict.fromkeys(KEYS, 0)
+
+
+def test_one_shot_counts_on_the_host_and_not_while_tracing():
+    q, r = _data()
+    obs.reset()
+    ops.sdtw_wavefront(q, r)
+    work = ops.kernel_plan(m=20, n=300).work(3, 300)
+    assert counters() == plus(dict.fromkeys(KEYS, 0), work)
+    jax.jit(lambda q, r: ops.sdtw_wavefront(q, r))(q, r)
+    assert counters() == plus(dict.fromkeys(KEYS, 0), work)
