@@ -59,6 +59,7 @@ materializes E itself (the ``outputs=("soft_alignment",)`` /
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -90,7 +91,8 @@ def _checkpoint_sweeps(queries, reference, *, spec: DPSpec,
     queries (B, m), reference (n,) — already normalized.  Returns
     ``(cost, end, rev_cost, fwd_ckpt, rev_ckpt)`` with the per-query
     vectors still BATCH-PADDED to a SUBLANES multiple (callers slice
-    ``[:B]``) and the checkpoints shaped (G, Gf, SUBLANES, m).
+    ``[:B]``) and the checkpoints shaped (G', Gf, S, m), S queries of
+    the plan's rows per step in each of G' grid steps.
     ``rev_cost`` is the reverse sweep's own total-cost readout — equal
     to ``cost`` up to float error (parity diagnostic)."""
     w = segment_width
@@ -99,12 +101,12 @@ def _checkpoint_sweeps(queries, reference, *, spec: DPSpec,
     r32 = reference.astype(jnp.float32)
     rf = ops.swizzle_reference(r32, w)
     R = rf.shape[0]
-    fwd = KernelPlan(spec=spec, m=m, segment_width=w, num_ref_blocks=R,
-                     checkpoint=True)
+    fwd = ops.plan_rows(KernelPlan(spec=spec, m=m, segment_width=w,
+                                   num_ref_blocks=R, checkpoint=True),
+                        queries.shape[0])
     cost, end, fck = wavefront_call(fwd, ops.prepare_queries(q32), rf,
                                     interpret=interpret)
-    rev = KernelPlan(spec=spec, m=m, segment_width=w, num_ref_blocks=R,
-                     checkpoint=True, reverse=True)
+    rev = dataclasses.replace(fwd, reverse=True)
     rcost, _rend, rck = wavefront_call(
         rev, ops.prepare_queries(jnp.flip(q32, axis=1)),
         ops.swizzle_reference_reverse(r32, w), interpret=interpret)
@@ -113,8 +115,8 @@ def _checkpoint_sweeps(queries, reference, *, spec: DPSpec,
 
 
 def _unpack_ckpt(ck, batch: int, grid_blocks: int, m: int):
-    """(G, Gf, SUBLANES, m) kernel checkpoints -> (batch, Gf, m) with
-    the (group, sublane) packing of ``prepare_queries`` undone."""
+    """(G', Gf, S, m) kernel checkpoints -> (batch, Gf, m) with the
+    (grid step, row) packing of the sweep undone."""
     return ck.transpose(0, 2, 1, 3).reshape(-1, grid_blocks, m)[:batch]
 
 

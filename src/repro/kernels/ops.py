@@ -19,6 +19,7 @@ dispatch-only.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -30,9 +31,9 @@ from repro.core.spec import (DEFAULT_SPEC, NO_WINDOW,  # noqa: F401
 # PAD_VALUE re-exported: cost >= (q - 1e6)^2 never wins — the dtype
 # rationale (and why it rules out cosine) lives with the other
 # sentinels in core/spec.py.
-from repro.kernels.sdtw_wavefront import (LANES, SUBLANES,
-                                          sdtw_wavefront_pallas)
-from repro.kernels.wavefront import KernelPlan, build_plan, query_pack_len
+from repro.kernels.wavefront import (LANES, SUBLANES, KernelPlan,
+                                     build_plan, query_pack_len,
+                                     wavefront_call)
 from repro.kernels.normalizer import normalizer_pallas
 
 
@@ -181,19 +182,33 @@ def validate_prepped(q_prepped, r_layout, *, m: int, n: int,
             f"{query_pack_len(m)}) from prepare_queries")
 
 
+def plan_rows(plan: KernelPlan, batch: int) -> KernelPlan:
+    """``plan`` with the queries per serial step a dispatch of ``batch``
+    queries runs: two packed groups (``2 * SUBLANES``) whenever the
+    batch fills at least two groups, else one.  Every query's
+    arithmetic is the same either way; the wider step pays the serial
+    step's fixed latency once for twice the queries."""
+    return dataclasses.replace(
+        plan, rows_per_step=2 * SUBLANES if batch > SUBLANES else SUBLANES)
+
+
 def kernel_plan(spec: DPSpec | None = None, *, m: int, n: int,
                 segment_width: int = 8, compute_dtype=jnp.float32,
-                with_window: bool = False) -> KernelPlan:
+                with_window: bool = False,
+                batch: int | None = None) -> KernelPlan:
     """The :class:`~repro.kernels.wavefront.KernelPlan` a dispatch of
     these (unpadded) shapes executes — band-skip geometry included, so
     callers (search stats, benchmarks) can read ``plan.grid_blocks``
-    vs ``plan.num_ref_blocks`` without running the kernel."""
+    vs ``plan.num_ref_blocks`` without running the kernel.  Given the
+    ``batch``, the plan also carries the rows per step that batch
+    runs (:func:`plan_rows`); without it, one group a step."""
     sp = DEFAULT_SPEC if spec is None else spec
     blocks = ceil_to(n, LANES * segment_width) // (LANES * segment_width)
-    return build_plan(sp, m=m,
+    plan = build_plan(sp, m=m,
                       segment_width=segment_width, num_ref_blocks=blocks,
                       compute_dtype=compute_dtype, with_window=with_window,
                       n=n if sp.family != "sdtw" else None)
+    return plan if batch is None else plan_rows(plan, batch)
 
 
 def band_blocks_all(spec: DPSpec, m: int, n: int) -> bool:
@@ -220,8 +235,8 @@ def wavefront_work(spec: DPSpec | None = None, *, batch: int, m: int,
     sp = DEFAULT_SPEC if spec is None else spec
     if band_blocks_all(sp, m, n):
         return None
-    return kernel_plan(sp, m=m, n=n,
-                       segment_width=segment_width).work(batch, n)
+    return kernel_plan(sp, m=m, n=n, segment_width=segment_width,
+                       batch=batch).work(batch, n)
 
 
 _WORK_COUNTERS = tuple((k, f"kernel.wavefront.{k}") for k in
@@ -233,7 +248,9 @@ def count_wavefront(work: dict) -> None:
     """Add one wavefront dispatch's :meth:`KernelPlan.work` to the
     process-wide counters ``kernel.wavefront.dispatches`` /
     ``.grid_steps`` / ``.loop_steps`` / ``.lane_cells`` /
-    ``.cells_real`` of :func:`repro.obs.default_registry`.
+    ``.cells_real`` of :func:`repro.obs.default_registry`, and to
+    ``.wide_dispatches`` where the plan carried two query groups a
+    step (``wide_dispatches / dispatches``: how often that engages).
 
     Process-wide because the chip and its kernels belong to the
     process, not to a session.  Call it on the host once per dispatch
@@ -241,6 +258,8 @@ def count_wavefront(work: dict) -> None:
     function's body runs once per trace, not once per call."""
     reg = obs.default_registry()
     reg.inc("kernel.wavefront.dispatches")
+    if work["rows_per_step"] > SUBLANES:
+        reg.inc("kernel.wavefront.wide_dispatches")
     for key, name in _WORK_COUNTERS:
         reg.inc(name, work[key])
 
@@ -301,15 +320,10 @@ def _prep(queries, reference, *, segment_width, compute_dtype):
             swizzle_reference(reference.astype(compute_dtype), segment_width))
 
 
-@functools.partial(jax.jit, static_argnames=("m", "n", "segment_width",
-                                             "interpret", "compute_dtype",
-                                             "spec", "with_window"))
-def _dispatch(q_prepped, r_layout, extras=(), *, m, segment_width,
-              compute_dtype, interpret, spec, with_window=False, n=None):
-    out = sdtw_wavefront_pallas(
-        q_prepped, r_layout, *extras, m=m, segment_width=segment_width,
-        compute_dtype=compute_dtype, interpret=interpret, spec=spec,
-        with_window=with_window, n=n)
+@functools.partial(jax.jit, static_argnames=("plan", "interpret"))
+def _dispatch(q_prepped, r_layout, extras=(), *, plan, interpret):
+    out = wavefront_call(plan, q_prepped, r_layout, *extras,
+                         interpret=interpret)
     return tuple(x.reshape(-1) for x in out)
 
 
@@ -362,7 +376,9 @@ def sdtw_wavefront_prepped(q_prepped: jnp.ndarray, r_layout: jnp.ndarray, *,
     Soft-min specs run the soft carry channel (running logsumexp fold,
     see ``repro.kernels.wavefront``); Sakoe–Chiba specs automatically
     execute the band-skip plan — fewer grid steps, identical outputs
-    (``kernel_plan(...)`` exposes the geometry).
+    (``kernel_plan(...)`` exposes the geometry); an operand of two or
+    more query groups runs two groups a serial step (:func:`plan_rows`),
+    again with identical outputs.
     """
     validate_prepped(q_prepped, r_layout, m=m, n=n,
                      segment_width=segment_width)
@@ -376,12 +392,13 @@ def sdtw_wavefront_prepped(q_prepped: jnp.ndarray, r_layout: jnp.ndarray, *,
             return (costs, jnp.full((batch,), NO_WINDOW, jnp.int32),
                     ends)
         return costs, ends
-    out = _dispatch(q_prepped, r_layout, tuple(extras), m=m,
-                    segment_width=segment_width,
-                    compute_dtype=compute_dtype,
-                    interpret=_resolve_interpret(interpret),
-                    spec=sp, with_window=return_window,
-                    n=n if sp.family != "sdtw" else None)
+    plan = plan_rows(build_plan(
+        sp, m=m, segment_width=segment_width,
+        num_ref_blocks=r_layout.shape[0], compute_dtype=compute_dtype,
+        with_window=return_window, n=n if sp.family != "sdtw" else None),
+        q_prepped.shape[0] * SUBLANES)
+    out = _dispatch(q_prepped, r_layout, tuple(extras), plan=plan,
+                    interpret=_resolve_interpret(interpret))
     if return_window:
         costs, starts, ends = out
         # clamp padded-column starts like the ends, but keep the

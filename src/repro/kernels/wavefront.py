@@ -48,8 +48,14 @@ DESIGN — mapping channels back to the paper's AMD/HIP mechanisms:
     The soft-min fold is the logsumexp analogue: per-lane running
     (max, scaled-sum) pairs merged into one global
     ``-gamma * logsumexp`` at finalize.
-  * batch of queries  -> grid axis 0, SUBLANES queries per step packed
-    in the sublane dimension (the paper's block-per-query batching).
+  * batch of queries  -> grid axis 0, packed SUBLANES queries to a
+    group in the sublane dimension (the paper's block-per-query
+    batching).  A step carries ``plan.rows_per_step`` queries: one
+    group, or two consecutive groups as one (2 * SUBLANES, LANES) tile
+    whenever the batch fills two groups (``ops.plan_rows``), so the
+    serial step's fixed latency (scalar window arithmetic, XLU round
+    trips, the fold's VMEM load and store) is paid once for 16
+    queries.  Each query's arithmetic is the same at either height.
 
 Band-skip: with a Sakoe–Chiba band every cell (i, j) with
 ``j > (m - 1) + band`` is out of band for EVERY query row, so trailing
@@ -82,7 +88,8 @@ from repro.core.spec import (KERNEL_BIG, NO_WINDOW, SOFT_BIG, DPSpec,
                              soft_exp, soft_log)
 
 LANES = 128          # TPU VPU lane count (the paper's wavefront width = 64)
-SUBLANES = 8         # queries processed per grid step (sublane packing)
+SUBLANES = 8         # queries per packed group (sublane packing); a
+#                      grid step carries one group or two
 
 _J_MAX = 2 ** 31 - 1   # lexicographic-min column sentinel (int32 max):
 #                        any real column index beats it, so it doubles
@@ -174,14 +181,14 @@ class CarryChannel:
         """(prev_row registers, left column, prev-left) at t = 0."""
         dt = self.reg_dtype(compute_dtype)
         edge = jnp.asarray(self.edge_init, dt)
-        prev0 = tuple(jnp.full((SUBLANES, LANES), self.prev_init, dt)
+        prev0 = tuple(jnp.full(lane.shape, self.prev_init, dt)
                       for _ in range(w))
         # t=0: only lane 0 is active (row 0); its left column is the
         # previous block's strip (block > 0) or the edge sentinel
         strip0 = self.read_strip(strip_ref, 0, compute_dtype=compute_dtype)
         left0 = jnp.where(lane == 0,
                           jnp.where(rblk > 0, strip0, edge), edge)
-        prev_left0 = jnp.full((SUBLANES, LANES), self.edge_init, dt)
+        prev_left0 = jnp.full(lane.shape, self.edge_init, dt)
         return (prev0, left0, prev_left0)
 
     def roll_carry(self, last, *, lane, strip_val, use_strip,
@@ -214,16 +221,20 @@ class CarryChannel:
         strip_ref[:, pl.ds(base, LANES)] = jnp.where(
             lane == off, col.astype(self.strip_dtype), tile)
 
-    def strip_shape(self, m: int):
-        return pltpu.VMEM((SUBLANES, strip_len(m)), self.strip_dtype)
+    def strip_shape(self, m: int, rows: int):
+        return pltpu.VMEM((rows, strip_len(m)), self.strip_dtype)
 
 
 # ---------------------------------------------------------------- folds
 def _write(out_ref, col):
     """Store a per-query (S, 1) result as the output block's lane-dense
     (S, LANES) tile (TPU blocks are whole (8, 128) tiles)."""
-    out_ref[0] = jnp.broadcast_to(col, (SUBLANES, LANES)).astype(
+    out_ref[0] = jnp.broadcast_to(col, out_ref.shape[1:]).astype(
         out_ref.dtype)
+
+
+def _fill(ref, value):
+    ref[...] = jnp.full(ref.shape, value, ref.dtype)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -233,19 +244,18 @@ class MinArgminFold:
 
     with_window: bool = False
 
-    def scratch_shapes(self):
-        shapes = [pltpu.VMEM((SUBLANES, LANES), jnp.float32),   # min
-                  pltpu.VMEM((SUBLANES, LANES), jnp.int32)]     # argmin
+    def scratch_shapes(self, rows):
+        shapes = [pltpu.VMEM((rows, LANES), jnp.float32),   # min
+                  pltpu.VMEM((rows, LANES), jnp.int32)]     # argmin
         if self.with_window:
-            shapes.append(pltpu.VMEM((SUBLANES, LANES), jnp.int32))
+            shapes.append(pltpu.VMEM((rows, LANES), jnp.int32))
         return shapes
 
     def init(self, scr):
-        scr[0][...] = jnp.full((SUBLANES, LANES), KERNEL_BIG, jnp.float32)
-        scr[1][...] = jnp.full((SUBLANES, LANES), NO_WINDOW, jnp.int32)
+        _fill(scr[0], KERNEL_BIG)
+        _fill(scr[1], NO_WINDOW)
         if self.with_window:
-            scr[2][...] = jnp.full((SUBLANES, LANES), NO_WINDOW,
-                                   jnp.int32)
+            _fill(scr[2], NO_WINDOW)
 
     def _segment_best(self, rows, j_base, w):
         """(value, global column[, start]) of the best cell in each
@@ -306,15 +316,15 @@ class SoftMinFold:
     detection (all bottom cells masked -> +inf, engine parity).
     """
 
-    def scratch_shapes(self):
-        return MinArgminFold().scratch_shapes() + [
-            pltpu.VMEM((SUBLANES, LANES), jnp.float32),   # running max m
-            pltpu.VMEM((SUBLANES, LANES), jnp.float32)]   # scaled sum s
+    def scratch_shapes(self, rows):
+        return MinArgminFold().scratch_shapes(rows) + [
+            pltpu.VMEM((rows, LANES), jnp.float32),   # running max m
+            pltpu.VMEM((rows, LANES), jnp.float32)]   # scaled sum s
 
     def init(self, scr):
         MinArgminFold().init(scr[:2])
-        scr[2][...] = jnp.full((SUBLANES, LANES), -SOFT_BIG, jnp.float32)
-        scr[3][...] = jnp.zeros((SUBLANES, LANES), jnp.float32)
+        _fill(scr[2], -SOFT_BIG)
+        _fill(scr[3], 0.0)
 
     def update(self, scr, *, at_bottom, rows, j_base, plan, in_grid=None):
         MinArgminFold().update(scr[:2], at_bottom=at_bottom, rows=rows,
@@ -368,11 +378,11 @@ class CornerFold:
     flows strictly left-to-right, so cell (m-1, n-1) never reads them.
     """
 
-    def scratch_shapes(self):
-        return [pltpu.VMEM((SUBLANES, LANES), jnp.float32)]
+    def scratch_shapes(self, rows):
+        return [pltpu.VMEM((rows, LANES), jnp.float32)]
 
     def init(self, scr):
-        scr[0][...] = jnp.full((SUBLANES, LANES), KERNEL_BIG, jnp.float32)
+        _fill(scr[0], KERNEL_BIG)
 
     def update(self, scr, *, at_bottom, rows, j_base, plan, in_grid=None):
         acc = scr[0][...]
@@ -406,13 +416,13 @@ class LocalCellsFold:
     ``v < big/2`` guard.
     """
 
-    def scratch_shapes(self):
-        return [pltpu.VMEM((SUBLANES, LANES), jnp.float32),   # lex value
-                pltpu.VMEM((SUBLANES, LANES), jnp.int32)]     # lex column
+    def scratch_shapes(self, rows):
+        return [pltpu.VMEM((rows, LANES), jnp.float32),   # lex value
+                pltpu.VMEM((rows, LANES), jnp.int32)]     # lex column
 
     def init(self, scr):
-        scr[0][...] = jnp.full((SUBLANES, LANES), KERNEL_BIG, jnp.float32)
-        scr[1][...] = jnp.full((SUBLANES, LANES), _J_MAX, jnp.int32)
+        _fill(scr[0], KERNEL_BIG)
+        _fill(scr[1], _J_MAX)
 
     def update(self, scr, *, at_bottom, rows, j_base, plan, in_grid=None):
         big_half = jnp.asarray(plan.big / 2, jnp.float32)
@@ -455,15 +465,15 @@ class SoftCellsFold:
     engine's masked diagonals.
     """
 
-    def scratch_shapes(self):
-        return LocalCellsFold().scratch_shapes() + [
-            pltpu.VMEM((SUBLANES, LANES), jnp.float32),   # running max m
-            pltpu.VMEM((SUBLANES, LANES), jnp.float32)]   # scaled sum s
+    def scratch_shapes(self, rows):
+        return LocalCellsFold().scratch_shapes(rows) + [
+            pltpu.VMEM((rows, LANES), jnp.float32),   # running max m
+            pltpu.VMEM((rows, LANES), jnp.float32)]   # scaled sum s
 
     def init(self, scr):
         LocalCellsFold().init(scr[:2])
-        scr[2][...] = jnp.full((SUBLANES, LANES), -SOFT_BIG, jnp.float32)
-        scr[3][...] = jnp.zeros((SUBLANES, LANES), jnp.float32)
+        _fill(scr[2], -SOFT_BIG)
+        _fill(scr[3], 0.0)
 
     def update(self, scr, *, at_bottom, rows, j_base, plan, in_grid=None):
         LocalCellsFold().update(scr[:2], at_bottom=at_bottom, rows=rows,
@@ -535,8 +545,18 @@ class KernelPlan:
     #                              valid-cell set j < n).  sdtw plans
     #                              leave it None so their jit cache
     #                              stays keyed on padded shapes alone.
+    rows_per_step: int = SUBLANES  # queries a serial step carries: one
+    #                              packed group, or two whenever the
+    #                              batch fills two (ops.plan_rows).
+    #                              Every plan kind gains from two: its
+    #                              loop body at 16 rows is under twice
+    #                              its body at 8 (PERF.md section 5)
 
     def __post_init__(self):
+        if self.rows_per_step not in (SUBLANES, 2 * SUBLANES):
+            raise ValueError(
+                f"rows_per_step is {SUBLANES} or {2 * SUBLANES} (one or "
+                f"two query groups a step), got {self.rows_per_step}")
         if self.spec.family != "sdtw":
             if self.n is None:
                 raise ValueError(
@@ -703,12 +723,15 @@ class KernelPlan:
         """What one dispatch of this plan does for ``batch`` queries
         against a reference of true length ``n``, as plain integers:
 
-          * ``grid_steps``: query groups x executed reference blocks;
+          * ``rows_per_step``: the plan's queries per serial step;
+          * ``grid_steps``: steps along the batch (query groups, two
+            at a time at 2 * SUBLANES rows, an odd count rounded up) x
+            executed reference blocks;
           * ``loop_steps``: serial wavefront steps, ``m + LANES - 1``
             per grid step (the pipeline fills and drains every block);
-          * ``lane_cells``: cells those steps update, SUBLANES x LANES
-            x ``segment_width`` each, padding and pipeline fill
-            included;
+          * ``lane_cells``: cells those steps update, ``rows_per_step``
+            x LANES x ``segment_width`` each, padding, pad group and
+            pipeline fill included;
           * ``cells_real``: the real query x real column cells inside
             the executed blocks, never more than ``lane_cells``.
 
@@ -719,13 +742,14 @@ class KernelPlan:
             raise ValueError(
                 f"reference length n={n} does not fit the plan's "
                 f"{self.num_ref_blocks} blocks of {block_cols} columns")
-        grid_steps = _ceil_to(batch, SUBLANES) // SUBLANES \
-            * self.grid_blocks
+        rows = self.rows_per_step
+        grid_steps = _ceil_to(batch, rows) // rows * self.grid_blocks
         loop_steps = grid_steps * (self.m + LANES - 1)
         return {
+            "rows_per_step": rows,
             "grid_steps": grid_steps,
             "loop_steps": loop_steps,
-            "lane_cells": loop_steps * SUBLANES * block_cols,
+            "lane_cells": loop_steps * rows * block_cols,
             "cells_real": batch * self.m
             * min(n, self.grid_blocks * block_cols),
         }
@@ -823,7 +847,8 @@ def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
     """One (batch-group, reference-block) grid cell, assembled from the
     plan's channels and fold.
 
-    q_ref:  (1, SUBLANES, Mp)  reversed+padded queries (see ops.py)
+    q_ref:  (1, rows, Mp)      reversed+padded queries (see ops.py),
+                               ``plan.rows_per_step`` of them
     r_ref:  (1, w, LANES)      reference block,
                                [k, l] = r[blk*LANES*w + l*w + k]
     refs:   ``plan.extra_inputs`` family operand refs (laid out like
@@ -844,7 +869,7 @@ def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
     rblk = pl.program_id(1)
     m, w = plan.m, plan.segment_width
     cdt = plan.compute_dtype
-    lane = lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 1)
+    lane = lax.broadcasted_iota(jnp.int32, (plan.rows_per_step, LANES), 1)
 
     @pl.when(rblk == 0)
     def _init():
@@ -859,7 +884,8 @@ def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
         # true boundary there.
         refs[plan.num_outputs - 1][0, 0] = jnp.where(
             rblk > 0, strip_refs[0][...].astype(jnp.float32),
-            jnp.full((SUBLANES, strip_len(m)), plan.big, jnp.float32))
+            jnp.full((plan.rows_per_step, strip_len(m)), plan.big,
+                     jnp.float32))
 
     def ref_row(ref, k):                  # (1, LANES): reference slot k
         return ref[0, k:k + 1, :].astype(cdt)
@@ -954,6 +980,14 @@ def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
         fold.finalize(scr, out_refs, plan)
 
 
+def _stack_groups(x, rows: int):
+    """(G, SUBLANES, Mp) -> (ceil(G / k), rows, Mp), k = rows / SUBLANES
+    consecutive query groups per block, the last padded with zeros."""
+    k = rows // SUBLANES
+    x = jnp.pad(x, ((0, -x.shape[0] % k), (0, 0), (0, 0)))
+    return x.reshape(-1, rows, x.shape[2])
+
+
 def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
                    r_layout: jnp.ndarray, *extras: jnp.ndarray,
                    interpret: bool | None = None):
@@ -972,9 +1006,16 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
     interpret: None = ``ops.default_interpret()`` (compiled on TPU).
     returns    (costs (G, SUBLANES) f32, ends (G, SUBLANES) i32), plus
                starts in the middle for window plans, plus a trailing
-               (G, grid_blocks, SUBLANES, m) f32 boundary-strip tensor
-               for checkpoint plans — every channel rides the SAME
-               pallas_call, never a second sweep.
+               (G', grid_blocks, rows, m) f32 boundary-strip tensor for
+               checkpoint plans, one strip per grid step:
+               ``plan.rows_per_step`` queries in packed order,
+               G' = ceil(G * SUBLANES / rows) — every channel rides the
+               SAME pallas_call, never a second sweep.
+
+    A plan of 2 * SUBLANES rows takes two consecutive query groups per
+    grid step, as one (2 * SUBLANES, Mp) block: the reshape moves no
+    data under the TPU's (8, 128) tiling.  An odd group count gains one
+    group of zero queries, trimmed from the outputs like batch padding.
     """
     G, S, Mp = q_rev_pad.shape
     R, w, L = r_layout.shape
@@ -1001,26 +1042,27 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
         from repro.kernels.ops import default_interpret  # imports us
         interpret = default_interpret()
     kernel = functools.partial(_generic_kernel, plan=plan)
-    grid = (G, plan.grid_blocks)
-    # per-query results leave as lane-dense (SUBLANES, LANES) tiles,
-    # every lane holding the same value (see _write)
+    rows = plan.rows_per_step
+    grid = (_ceil_to(G * SUBLANES, rows) // rows, plan.grid_blocks)
+    # per-query results leave as lane-dense (rows, LANES) tiles, every
+    # lane holding the same value (see _write)
     dtypes = [jnp.float32, jnp.int32] + ([jnp.int32] * plan.with_window)
-    out_shape = [jax.ShapeDtypeStruct((G, SUBLANES, LANES), dt)
+    out_shape = [jax.ShapeDtypeStruct((grid[0], rows, LANES), dt)
                  for dt in dtypes]
-    out_specs = [pl.BlockSpec((1, SUBLANES, LANES), lambda b, r: (b, 0, 0))
+    out_specs = [pl.BlockSpec((1, rows, LANES), lambda b, r: (b, 0, 0))
                  for _ in dtypes]
     ms = strip_len(plan.m)
     if plan.checkpoint:
-        # one (SUBLANES, m) entry-boundary strip per executed block:
-        # the O(M * N/block) residual the fused soft backward
+        # one (rows, m) entry-boundary strip per executed block: the
+        # O(M * N/block) residual the fused soft backward
         # re-materializes E tiles from (kernels/backward.py)
         out_shape.append(jax.ShapeDtypeStruct(
-            (G, plan.grid_blocks, SUBLANES, ms), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, 1, SUBLANES, ms),
+            (grid[0], plan.grid_blocks, rows, ms), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, rows, ms),
                                       lambda b, r: (b, r, 0, 0)))
     off = plan.block_offset
     in_specs = [
-        pl.BlockSpec((1, SUBLANES, Mp), lambda b, r: (b, 0, 0)),
+        pl.BlockSpec((1, rows, Mp), lambda b, r: (b, 0, 0)),
         # grid step r reads layout block r + offset (reverse band-skip
         # grids start past the leading out-of-band flipped blocks)
         pl.BlockSpec((1, w, LANES), lambda b, r: (r + off, 0, 0)),
@@ -1041,9 +1083,14 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
                     f"be packed like the prepared queries "
                     f"{tuple(q_rev_pad.shape)}")
             in_specs.append(
-                pl.BlockSpec((1, SUBLANES, Mp), lambda b, r: (b, 0, 0)))
-    scratch = [ch.strip_shape(plan.m) for ch in plan.channels]
-    scratch += plan.fold.scratch_shapes()
+                pl.BlockSpec((1, rows, Mp), lambda b, r: (b, 0, 0)))
+    scratch = [ch.strip_shape(plan.m, rows) for ch in plan.channels]
+    scratch += plan.fold.scratch_shapes(rows)
+    if rows != SUBLANES:
+        q_rev_pad = _stack_groups(q_rev_pad, rows)
+        extras = tuple(_stack_groups(x, rows) if _EXTRA_KIND[name] == "q"
+                       else x for name, x in zip(plan.extra_inputs,
+                                                 extras))
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
@@ -1056,7 +1103,10 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
         out_shape=tuple(out_shape), scratch_shapes=scratch,
         interpret=interpret, name=name, **kwargs,
     )(q_rev_pad, r_layout, *extras)
-    out = [x[:, :, 0] for x in out[:len(dtypes)]] + \
+    per_query = out[:len(dtypes)]
+    if rows != SUBLANES:
+        per_query = [x.reshape(-1, SUBLANES, LANES)[:G] for x in per_query]
+    out = [x[:, :, 0] for x in per_query] + \
         [x[..., :plan.m] for x in out[len(dtypes):]]
     if plan.with_window:
         costs, ends, starts = out

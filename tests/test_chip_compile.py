@@ -11,6 +11,7 @@ benchmark's trace reduction finds the kernel by.  Nothing runs: results are chec
 the interpret-mode suites and on the chip by ``chip_smoke.py``.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -76,6 +77,13 @@ def _assert_kernel(compiled, name):
     return text
 
 
+def _rows_per_step(text, name):
+    """Queries per grid step of each ``name`` kernel in compiled HLO:
+    the sublane extent of its first (steps, rows, LANES) output."""
+    return re.findall(rf"%{name}(?:\.\d+)? = \(?f32\[\d+,(\d+),",
+                      text)
+
+
 @pytest.mark.parametrize("name", sorted(PLANS))
 def test_wavefront_plan_compiles(one_chip, tpu_math, name):
     spec, window = PLANS[name]
@@ -91,6 +99,8 @@ def test_wavefront_plan_compiles(one_chip, tpu_math, name):
     text = _assert_kernel(jax.jit(sweep).lower(q, r, *extras).compile(),
                           "sdtw_wavefront")
     assert "sdtw_normalizer" not in text
+    # 64 query groups: every kind takes two groups a step
+    assert _rows_per_step(text, "sdtw_wavefront") == ["16"]
 
 
 def test_fused_soft_backward_compiles(one_chip, tpu_math):
@@ -104,8 +114,11 @@ def test_fused_soft_backward_compiles(one_chip, tpu_math):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         _sds((8, M), one_chip), _sds((N,), one_chip)).compile()
     _assert_kernel(compiled, "sdtw_wavefront")
-    _assert_kernel(compiled, "sdtw_wavefront_reverse")
-    assert compiled.as_text().count("tpu_custom_call") >= 2
+    text = _assert_kernel(compiled, "sdtw_wavefront_reverse")
+    assert text.count("tpu_custom_call") >= 2
+    # one group of 8 queries: both sweeps keep one group a step
+    assert _rows_per_step(text, "sdtw_wavefront") == ["8"]
+    assert _rows_per_step(text, "sdtw_wavefront_reverse") == ["8"]
 
 
 @pytest.mark.parametrize("shape", [(B, M), (1, N)])

@@ -12,8 +12,8 @@ from repro.core.spec import DPSpec
 from repro.kernels import ops
 from repro.kernels.wavefront import KernelPlan
 
-KEYS = ("dispatches", "grid_steps", "loop_steps", "lane_cells",
-        "cells_real")
+KEYS = ("dispatches", "wide_dispatches", "grid_steps", "loop_steps",
+        "lane_cells", "cells_real")
 
 
 def counters() -> dict:
@@ -22,26 +22,29 @@ def counters() -> dict:
 
 
 def plus(before: dict, *works) -> dict:
-    out = dict(before, dispatches=before["dispatches"] + len(works))
+    out = dict(before)
     for w in works:
+        out["dispatches"] += 1
+        out["wide_dispatches"] += w["rows_per_step"] == 16
         for k, v in w.items():
-            out[k] += v
+            if k != "rows_per_step":
+                out[k] += v
     return out
 
 
 @pytest.mark.parametrize("spec, batch, m, n, w, want", [
     # the paper's batch: 64 groups x 98 blocks, 2,000 + 127 steps each
     (DPSpec(), 512, 2_000, 100_000, 8,
-     {"grid_steps": 6_272, "loop_steps": 13_340_544,
+     {"rows_per_step": 8, "grid_steps": 6_272, "loop_steps": 13_340_544,
       "lane_cells": 109_285_736_448, "cells_real": 102_400_000_000}),
     # 13 queries fill 2 groups; 1,000 columns pad to 2 blocks of 512
     (DPSpec(), 13, 20, 1_000, 4,
-     {"grid_steps": 4, "loop_steps": 4 * 147,
+     {"rows_per_step": 8, "grid_steps": 4, "loop_steps": 4 * 147,
       "lane_cells": 4 * 147 * 8 * 512, "cells_real": 13 * 20 * 1_000}),
     # band 100 at m = 200 keeps columns up to 298: 2 of 20 blocks of
     # 256 run, and only their 512 columns hold real cells
     (DPSpec(band=100), 8, 200, 5_000, 2,
-     {"grid_steps": 2, "loop_steps": 2 * 327,
+     {"rows_per_step": 8, "grid_steps": 2, "loop_steps": 2 * 327,
       "lane_cells": 2 * 327 * 8 * 256, "cells_real": 8 * 200 * 512}),
 ])
 def test_plan_work_by_hand(spec, batch, m, n, w, want):
@@ -50,6 +53,34 @@ def test_plan_work_by_hand(spec, batch, m, n, w, want):
     assert work == want
     assert all(type(v) is int for v in work.values())
     assert work["cells_real"] <= work["lane_cells"]
+
+
+@pytest.mark.parametrize("spec, batch, m, n, w, want", [
+    # the paper's batch two groups a step: 32 steps along the batch x
+    # 98 blocks, the same lane-cells as one group a step
+    (DPSpec(), 512, 2_000, 100_000, 8,
+     {"rows_per_step": 16, "grid_steps": 3_136, "loop_steps": 6_670_272,
+      "lane_cells": 109_285_736_448, "cells_real": 102_400_000_000}),
+    # 24 queries fill 3 groups: the pad group makes 2 steps of 16 rows,
+    # and its lane-cells count
+    (DPSpec(), 24, 20, 1_000, 4,
+     {"rows_per_step": 16, "grid_steps": 4, "loop_steps": 4 * 147,
+      "lane_cells": 4 * 147 * 16 * 512, "cells_real": 24 * 20 * 1_000}),
+    # 9 queries fill 2 groups, 7 rows of the second padding
+    (DPSpec(band=100), 9, 200, 5_000, 2,
+     {"rows_per_step": 16, "grid_steps": 2, "loop_steps": 2 * 327,
+      "lane_cells": 2 * 327 * 16 * 256, "cells_real": 9 * 200 * 512}),
+    # one group never takes the wide step
+    (DPSpec(), 8, 20, 1_000, 4,
+     {"rows_per_step": 8, "grid_steps": 2, "loop_steps": 2 * 147,
+      "lane_cells": 2 * 147 * 8 * 512, "cells_real": 8 * 20 * 1_000}),
+])
+def test_batch_plan_work_by_hand(spec, batch, m, n, w, want):
+    plan = ops.kernel_plan(spec, m=m, n=n, segment_width=w, batch=batch)
+    assert plan.rows_per_step == want["rows_per_step"]
+    assert plan.work(batch, n) == want
+    assert ops.wavefront_work(spec, batch=batch, m=m, n=n,
+                              segment_width=w) == want
 
 
 def test_banded_plan_skips_blocks_and_reverse_reads_the_same():
@@ -99,9 +130,10 @@ def test_session_window_plan_counts_its_own_work():
                             metrics=obs.MetricsRegistry(),
                             tracer=obs.Tracer())
     aligner(q, outputs=("cost", "start", "end"))
-    work = ops.kernel_plan(m=16, n=700, segment_width=2,
-                           with_window=True).work(9, 700)
-    assert work["grid_steps"] == 2 * 3
+    work = ops.kernel_plan(m=16, n=700, segment_width=2, with_window=True,
+                           batch=9).work(9, 700)
+    assert work["rows_per_step"] == 16       # 9 queries fill two groups
+    assert work["grid_steps"] == 1 * 3
     assert counters() == plus(dict.fromkeys(KEYS, 0), work)
 
 
@@ -136,6 +168,17 @@ def test_engine_session_counts_nothing():
                             tracer=obs.Tracer())
     aligner(q)
     assert counters() == dict.fromkeys(KEYS, 0)
+
+
+@pytest.mark.parametrize("batch, wide", [(3, 0), (8, 0), (9, 1), (24, 1)])
+def test_wide_dispatches_count_only_two_group_plans(batch, wide):
+    q, r = _data(b=batch)
+    obs.reset()
+    ops.sdtw_wavefront(q, r)
+    work = ops.wavefront_work(batch=batch, m=20, n=300)
+    assert work["rows_per_step"] == (16 if wide else 8)
+    assert counters()["wide_dispatches"] == wide
+    assert counters() == plus(dict.fromkeys(KEYS, 0), work)
 
 
 def test_one_shot_counts_on_the_host_and_not_while_tracing():
