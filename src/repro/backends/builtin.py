@@ -39,6 +39,11 @@ _FULL = _WINDOWED | {"soft_alignment"}
 _ALL_FAMILIES = frozenset({"sdtw", "twed", "erp", "local"})
 _GLOBAL_WINDOWS = frozenset({"sdtw", "twed", "erp"})   # start output
 
+# multivariate (B, M, D) inputs: the sweep outputs of the sdtw family on
+# the executors that add per-feature costs (ref, kernel).  Paths and
+# expected alignments are derived by univariate code above the sweep.
+_SWEEP = frozenset({"cost", "end", "start"})
+
 
 # ------------------------------------------------------------------ ref
 def _exec_ref(spec, plan):
@@ -55,7 +60,8 @@ register(Backend(
         distances=_ALL, reductions=_BOTH, banding=True,
         differentiable=True, per_query_reference=True, exact=True,
         outputs=_FULL, families=_ALL_FAMILIES,
-        window_families=_GLOBAL_WINDOWS, device="any",
+        window_families=_GLOBAL_WINDOWS, multivariate_outputs=_SWEEP,
+        multivariate_reductions=_BOTH, device="any",
         notes="trusted row-scan oracle; slow, for validation"),
     execute=_exec_ref,
 ))
@@ -132,9 +138,14 @@ register(Backend(
         distances=frozenset({"sqeuclidean", "abs"}), reductions=_BOTH,
         banding=True, differentiable=True, per_query_reference=False,
         exact=True, outputs=_FULL, families=_ALL_FAMILIES,
+        # multivariate inputs: the feature cost is summed inside the
+        # same pallas_call; hard-min only, since the fused backward
+        # that makes soft-min differentiable is univariate
+        multivariate_outputs=_SWEEP, multivariate_reductions=_HARD,
         device="tpu (interpret=True elsewhere)",
         notes="Pallas wavefront kernel (hard+soft, band-skip grids, "
-              "fused reverse-sweep backward); shared 1-D reference only"),
+              "fused reverse-sweep backward); one shared reference, "
+              "(N,) or (N, D)"),
     execute=_exec_kernel,
 ))
 

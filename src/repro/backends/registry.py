@@ -71,13 +71,23 @@ class Capabilities:
     #   (twed/erp) have trivial starts (column 0, NO_WINDOW when the
     #   band blocks the corner); the local family has no window lane
     #   anywhere yet.
+    multivariate_outputs: frozenset = frozenset()
+    #   the sweep outputs served on multivariate (B, M, D) inputs of
+    #   the sdtw family; empty: the backend declines them
+    multivariate_reductions: frozenset = frozenset()
+    #   the reductions served on multivariate inputs
     device: str = "any"            # human-readable requirement
     notes: str = ""
 
-    def unsupported_reason(self, spec: DPSpec,
-                           outputs=None) -> str | None:
+    def unsupported_reason(self, spec: DPSpec, outputs=None,
+                           features: int = 1) -> str | None:
         """None when the spec (and every requested output, if any) is
-        executable, else a short reason."""
+        executable on inputs of ``features`` features, else a short
+        reason."""
+        if features > 1:
+            reason = self._multivariate_reason(spec, outputs)
+            if reason is not None:
+                return reason
         if spec.family not in self.families:
             return f"family {spec.family!r}"
         if spec.distance not in self.distances:
@@ -117,6 +127,23 @@ class Capabilities:
                         "expected alignment needs a softmin spec "
                         "(reduction='softmin'; hard-min paths are "
                         "outputs=('path',))")
+        return None
+
+    def _multivariate_reason(self, spec: DPSpec, outputs) -> str | None:
+        what = "multivariate (B, M, D) inputs"
+        if not self.multivariate_outputs:
+            return what
+        if spec.family != "sdtw":
+            return (f"family {spec.family!r} on {what}: the feature cost "
+                    "serves the sdtw recurrence only")
+        if spec.distance == "cosine":
+            return f"distance 'cosine' on {what}"
+        if spec.reduction not in self.multivariate_reductions:
+            return f"{spec.reduction} on {what}"
+        if outputs is not None:
+            missing = normalize_outputs(outputs) - self.multivariate_outputs
+            if missing:
+                return f"output(s) {sorted(missing)} on {what}"
         return None
 
 
@@ -225,15 +252,17 @@ def get(name: str) -> Backend:
     return _expand(name, DPSpec())[0]
 
 
-def supports(name: str, spec: DPSpec, *, outputs=None) -> bool:
+def supports(name: str, spec: DPSpec, *, outputs=None,
+             features: int = 1) -> bool:
     backend, spec = _expand(name, spec)
     return backend.capabilities.unsupported_reason(
-        spec, outputs=outputs) is None
+        spec, outputs=outputs, features=features) is None
 
 
 def capable(spec: DPSpec, *, exact_only: bool = False,
             outputs=None,
-            differentiable: bool = False) -> list[str]:
+            differentiable: bool = False,
+            features: int = 1) -> list[str]:
     """Backend names able to execute ``spec`` (and fulfill every
     requested output, when asked), in preference order (device-aware:
     the kernel leads on TPU, the engine elsewhere).
@@ -242,6 +271,10 @@ def capable(spec: DPSpec, *, exact_only: bool = False,
     gradients.  The Pallas kernel qualifies for soft-min specs: its
     costs carry the fused reverse-sweep custom_vjp
     (repro.kernels.backward), so jax.grad works at kernel speed.
+    ``features`` > 1 asks for multivariate (B, M, D) inputs; on them a
+    backend is differentiable only where its soft-min needs no backward
+    of its own (the kernel's fused backward is univariate, and the
+    kernel declines soft-min there).
     """
     _ensure_builtins()
     ordered = [n for n in _priority() if n in _REGISTRY]
@@ -249,7 +282,8 @@ def capable(spec: DPSpec, *, exact_only: bool = False,
     out = []
     for n in ordered:
         caps = _REGISTRY[n].capabilities
-        if caps.unsupported_reason(spec, outputs=outputs) is None \
+        if caps.unsupported_reason(spec, outputs=outputs,
+                                   features=features) is None \
                 and (caps.exact or not exact_only) \
                 and (caps.differentiable or not differentiable):
             out.append(n)
@@ -263,8 +297,8 @@ def validate(name: str, spec: DPSpec) -> Backend:
     return resolve(name, spec)[0]
 
 
-def resolve(name: str, spec: DPSpec, *,
-            outputs=None) -> tuple[Backend, DPSpec]:
+def resolve(name: str, spec: DPSpec, *, outputs=None,
+            features: int = 1) -> tuple[Backend, DPSpec]:
     """Alias expansion + capability validation.
 
     Returns the concrete backend and the (possibly alias-rewritten)
@@ -272,13 +306,15 @@ def resolve(name: str, spec: DPSpec, *,
     reduction="softmin").  ``outputs`` additionally requires the
     backend to fulfill every requested result field (e.g.
     ``{"start"}`` for matched windows), failing with the same loud
-    who-can-instead error.
+    who-can-instead error.  ``features`` > 1 validates multivariate
+    (B, M, D) inputs of that many features.
     """
     backend, spec = _expand(name, spec)
-    reason = backend.capabilities.unsupported_reason(spec,
-                                                     outputs=outputs)
+    reason = backend.capabilities.unsupported_reason(
+        spec, outputs=outputs, features=features)
     if reason is not None:
-        alternatives = [n for n in capable(spec, outputs=outputs)
+        alternatives = [n for n in capable(spec, outputs=outputs,
+                                           features=features)
                         if n != backend.name]
         hint = f": use one of {alternatives}" if alternatives else ""
         raise ValueError(
@@ -290,7 +326,8 @@ def resolve(name: str, spec: DPSpec, *,
 def select(spec: DPSpec, *, preferred: str | None = None,
            outputs=None,
            differentiable: bool = False,
-           workload: tuple | None = None) -> tuple[Backend, DPSpec]:
+           workload: tuple | None = None,
+           features: int = 1) -> tuple[Backend, DPSpec]:
     """Pick a backend for the spec: the preferred one when capable,
     else the first capable backend in preference order (the auto-
     fallback path: ``preferred=None, outputs={"start", ...}`` lands on
@@ -304,23 +341,28 @@ def select(spec: DPSpec, *, preferred: str | None = None,
     verdict on this machine, the measured winner beats the static
     device-priority guess (still restricted to capable backends — a
     verdict can re-rank choices, never bypass capability checks).
+    Tuning verdicts are univariate: ``features`` > 1 (multivariate
+    (B, M, D) inputs) selects by capability and priority alone.
 
     Returns ``(backend, spec)`` with alias overrides applied — execute
     with the RETURNED spec, never the one you passed in.
     """
     if preferred is not None:
-        backend, spec = resolve(preferred, spec, outputs=outputs)
+        backend, spec = resolve(preferred, spec, outputs=outputs,
+                                features=features)
         _record_selection(backend.name, spec, "preferred by caller")
         return backend, spec
     choices = capable(spec, outputs=outputs,
-                      differentiable=differentiable)
-    if workload is not None and choices:
+                      differentiable=differentiable, features=features)
+    if workload is not None and choices and features == 1:
         tuned = _tuned_choice(spec, workload, outputs, choices)
         if tuned is not None:
             _record_selection(tuned, spec, "tuned verdict")
             return _REGISTRY[tuned], spec
     if not choices:
         what = f"spec {spec.describe()}"
+        if features > 1:
+            what += f" on multivariate inputs of {features} features"
         if outputs is not None:
             what += f" with outputs={sorted(normalize_outputs(outputs))}"
         if differentiable:
@@ -384,6 +426,7 @@ def capability_rows() -> list[dict]:
             "per_query_reference": c.per_query_reference,
             "exact": c.exact,
             "outputs": ",".join(sorted(c.outputs - _BASE_OUTPUTS)) or "-",
+            "multivariate": ",".join(sorted(c.multivariate_outputs)) or "-",
             "device": c.device,
         })
     return rows

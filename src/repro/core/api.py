@@ -52,11 +52,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.backends import registry
-from repro.core.normalize import normalize_batch
+from repro.core.normalize import normalize_batch, normalize_reference
 from repro.core.result import (ALL_OUTPUTS, DEFAULT_OUTPUTS,  # noqa: F401
                                SDTWResult, normalize_outputs,
                                sweep_outputs)
-from repro.core.spec import DPSpec, resolve_spec, validate_batch_inputs
+from repro.core.spec import (DPSpec, resolve_spec, univariate,
+                             validate_batch_inputs)
 
 
 def _derive_outputs(res: SDTWResult, req: frozenset, queries, reference,
@@ -148,7 +149,12 @@ def sdtw(queries, reference, *,
          options: dict | None = None) -> SDTWResult:
     """Align a batch of queries against one reference.
 
-    queries: (B, M); reference: (N,).  Returns an
+    queries: (B, M); reference: (N,) — or multivariate queries
+    (B, M, D) against a reference (N, D) of the same D features, whose
+    cell cost adds the per-feature costs (sdtw family; the backends
+    that take them, and which outputs, are in the registry's
+    ``multivariate_outputs``).  One feature, (B, M, 1) against (N, 1),
+    runs exactly the univariate path.  Returns an
     :class:`~repro.core.result.SDTWResult` carrying exactly the
     requested ``outputs`` (everything else ``None``):
 
@@ -162,7 +168,8 @@ def sdtw(queries, reference, *,
                                    specs).
 
     Mirrors the paper's pipeline: optional z-normalization of both
-    inputs (§5.1), then the batched subsequence-DTW sweep (§5.2) under
+    inputs over time, feature by feature for multivariate inputs
+    (§5.1), then the batched subsequence-DTW sweep (§5.2) under
     the resolved spec.  ``spec`` carries the recurrence; the
     ``distance`` / ``reduction`` / ``gamma`` / ``band`` kwargs are
     per-call overrides of its fields (``gamma`` alone implies
@@ -191,6 +198,11 @@ def sdtw(queries, reference, *,
     validate_batch_inputs(queries, reference,
                           segment_width=None if auto_width
                           else segment_width)
+    queries, reference = univariate(queries, reference)
+    features = queries.shape[2] if queries.ndim == 3 else 1
+    if auto_width and features > 1:
+        raise ValueError("segment_width='auto' tunes univariate "
+                         "workloads: pin a width for multivariate inputs")
     resolved = resolve_spec(spec, distance=distance, reduction=reduction,
                             gamma=gamma, band=band, family=family,
                             nu=nu, lam=lam, gap=gap,
@@ -201,17 +213,19 @@ def sdtw(queries, reference, *,
                 int(queries.shape[0]))
     if backend is None:
         backend_impl, resolved = registry.select(resolved, outputs=req,
-                                                 workload=workload)
+                                                 workload=workload,
+                                                 features=features)
     else:
         backend_impl, resolved = registry.resolve(backend, resolved,
-                                                  outputs=req)
+                                                  outputs=req,
+                                                  features=features)
     if auto_width:
         segment_width, backend_impl = _auto_width(
             backend_impl, resolved, req, reference, workload,
             pinned=backend is not None, interpret=interpret)
     if normalize:
         queries = normalize_batch(queries)
-        reference = normalize_batch(reference)
+        reference = normalize_reference(reference)
     fused_soft = (backend_impl.name == "kernel" and resolved.soft
                   and "soft_alignment" in req)
     if fused_soft:

@@ -8,7 +8,9 @@ formulation the paper adopts::
 
 The Pallas kernel in ``repro.kernels.normalizer`` implements the same
 computation with an explicit VMEM reduction; this module is the public
-API and the pure-jnp reference.
+API and the pure-jnp reference.  Every series is normalized over time:
+a univariate one along its last axis, a multivariate one feature by
+feature.
 """
 
 from __future__ import annotations
@@ -19,14 +21,26 @@ import jax.numpy as jnp
 
 def normalize_batch(x: jnp.ndarray, *, eps: float = 1e-12,
                     accum_dtype=jnp.float32) -> jnp.ndarray:
-    """Z-normalize along the last axis. x: (..., L)."""
+    """Z-normalize each series over time.  x: (..., L) univariate
+    series, normalized along the last axis; or a (B, L, D) batch of
+    multivariate series, each feature normalized over time (axis -2):
+    per-utterance mean and variance normalization."""
+    axis = -2 if x.ndim == 3 else -1
     xf = x.astype(accum_dtype)
-    n = x.shape[-1]
-    s = jnp.sum(xf, axis=-1, keepdims=True) / n
-    sq = jnp.sum(xf * xf, axis=-1, keepdims=True) / n - s * s
+    n = x.shape[axis]
+    s = jnp.sum(xf, axis=axis, keepdims=True) / n
+    sq = jnp.sum(xf * xf, axis=axis, keepdims=True) / n - s * s
     # clamp tiny negative variance from the E[x^2]-E[x]^2 formulation
     std = jnp.sqrt(jnp.maximum(sq, eps))
     return ((xf - s) / std).astype(x.dtype)
+
+
+def normalize_reference(reference: jnp.ndarray) -> jnp.ndarray:
+    """Z-normalize one reference over time: (N,), or (N, D) with each
+    feature normalized over time."""
+    if reference.ndim == 2:
+        return normalize_batch(reference[None])[0]
+    return normalize_batch(reference)
 
 
 normalize = jax.jit(normalize_batch)

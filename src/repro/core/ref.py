@@ -49,6 +49,21 @@ def _np_cost(spec: DPSpec, a: float, b: float) -> float:
     return 1.0 - (a * b) / (abs(a) * abs(b) + 1e-8)
 
 
+def _np_costs(spec: DPSpec, q: np.ndarray, r: np.ndarray) -> np.ndarray:
+    """The (..., N) local costs of query samples ``q`` against every
+    reference sample: scalars q (...,) against r (N,), or feature
+    vectors q (..., D) against r (N, D), whose per-feature costs are
+    added in feature order."""
+    if r.ndim == 1:
+        return _np_cost(spec, q[..., None], r)
+    if spec.distance == "cosine":
+        raise ValueError("distance 'cosine' has no multivariate form here")
+    total = _np_cost(spec, q[..., None, 0], r[:, 0])
+    for d in range(1, r.shape[1]):
+        total = total + _np_cost(spec, q[..., None, d], r[:, d])
+    return total
+
+
 def _np_softmin(vals, gamma: float) -> float:
     a = -np.asarray(vals, dtype=np.float64) / gamma
     mx = np.max(a)
@@ -61,8 +76,10 @@ def sdtw_numpy(q: np.ndarray, r: np.ndarray,
                spec: DPSpec | None = None) -> tuple[float, int]:
     """Brute-force full-matrix sDTW. O(M*N) memory. Trusted oracle.
 
-    Returns (cost, end_index) where end_index is the reference column at
-    which the best alignment ends.  For soft-min specs the cost is the
+    q (M,) and r (N,), or multivariate q (M, D) and r (N, D), whose
+    cell cost adds the per-feature costs.  Returns (cost, end_index)
+    where end_index is the reference column at which the best
+    alignment ends.  For soft-min specs the cost is the
     smoothed soft-min over the bottom row (matching the engine's
     streaming logsumexp readout) and the end index is the bottom row's
     hard argmin.
@@ -71,13 +88,14 @@ def sdtw_numpy(q: np.ndarray, r: np.ndarray,
     q = np.asarray(q, dtype=np.float64)
     r = np.asarray(r, dtype=np.float64)
     m, n = len(q), len(r)
+    C = _np_costs(spec, q, r)                 # (m, n) local costs
     D = np.full((m + 1, n + 1), np.inf, dtype=np.float64)
     D[0, :] = 0.0  # subsequence: free start anywhere in the reference
     for i in range(1, m + 1):
         for j in range(1, n + 1):
             if spec.band is not None and abs((i - 1) - (j - 1)) > spec.band:
                 continue                      # out of band: stays +inf
-            c = _np_cost(spec, q[i - 1], r[j - 1])
+            c = C[i - 1, j - 1]
             if i == 1:
                 prev = 0.0                    # free start: D[-1, j] == 0
             elif spec.soft:
@@ -105,17 +123,18 @@ def sdtw_bottom_row(queries: np.ndarray, r: np.ndarray,
     with ``A[j] = c[j] + min(D[i-1, j], D[i-1, j-1])`` is a min-plus
     prefix scan: ``D[i] = S + minimum.accumulate(A - S)``, ``S`` the
     running sum of the row's costs.  Row 0 is the free start
-    ``D[0] = c``.  queries (B, M), r (N,) -> (B, N).
+    ``D[0] = c``.  queries (B, M), r (N,) -> (B, N); multivariate
+    queries (B, M, D) against r (N, D) add the per-feature costs.
     """
     spec = DEFAULT_SPEC if spec is None else spec
     if spec.soft or spec.band is not None:
         raise ValueError("sdtw_bottom_row is the hard-min unbanded "
                          f"oracle; got {spec.describe()}")
     q = np.asarray(queries, dtype=np.float64)
-    r = np.asarray(r, dtype=np.float64)[None, :]
-    d = _np_cost(spec, q[:, :1], r)
+    r = np.asarray(r, dtype=np.float64)
+    d = _np_costs(spec, q[:, 0], r)
     for i in range(1, q.shape[1]):
-        c = _np_cost(spec, q[:, i:i + 1], r)
+        c = _np_costs(spec, q[:, i], r)
         diag = np.concatenate(
             [np.full((q.shape[0], 1), np.inf), d[:, :-1]], axis=1)
         s = np.cumsum(c, axis=1)
@@ -161,12 +180,15 @@ def _sdtw_rowscan_single(q: jnp.ndarray, r: jnp.ndarray,
     banded = spec.band is not None
     n = r.shape[0]
     jj = jnp.arange(n)
+    # one query sample against every reference sample: scalars, or
+    # feature vectors (q (M, D) against r (N, D))
+    costs = spec.cell_cost if r.ndim == 1 else spec.feature_cost
 
     # Virtual row -1 is all zeros (free start): D[0, j] = cost(0, j). For
     # hard-min that is min(D[-1,j]=0, D[0,j-1]>=0, D[-1,j-1]=0) = 0; for
     # soft-min the free start is the same exact-zero boundary (matching
     # the engine's free_start mask).
-    row0 = spec.cell_cost(q[0], r)
+    row0 = costs(q[0], r)
     starts0 = jj.astype(jnp.int32)          # row 0: a path starts HERE
     if banded:
         ok0 = spec.band_valid(0, jj)
@@ -180,7 +202,7 @@ def _sdtw_rowscan_single(q: jnp.ndarray, r: jnp.ndarray,
             valid = spec.band_valid(i, jj)
         else:
             qi = xs
-        cost = spec.cell_cost(qi, r)
+        cost = costs(qi, r)
 
         def col_step(carry, cxs):
             left, upleft, s_left, s_upleft = carry
@@ -247,6 +269,9 @@ def _dp_rowscan_single(q: jnp.ndarray, r: jnp.ndarray, spec: DPSpec,
     """
     fam = spec.family
     local = fam == "local"
+    if q.ndim != 1:
+        raise ValueError(f"family {fam!r} is univariate: multivariate "
+                         "(M, D) series run the sdtw family only")
     if return_window and local:
         raise ValueError(
             "return_window is undefined for the local family: a local "
@@ -330,8 +355,9 @@ def sdtw_ref(queries: jnp.ndarray, reference: jnp.ndarray,
              return_window: bool = False):
     """Batched scan-based sDTW oracle.
 
-    queries:   (B, M) float
-    reference: (N,) shared or (B, N) per-query
+    queries:   (B, M) float, or (B, M, D) multivariate (sdtw family)
+    reference: (N,) shared or (B, N) per-query; (N, D) or (B, N, D)
+               for multivariate queries
     spec:      recurrence spec; None = squared-Euclidean hard-min unbanded
     return_window: also return the matched windows' start columns
                (hard-min specs only)
@@ -349,7 +375,7 @@ def sdtw_ref(queries: jnp.ndarray, reference: jnp.ndarray,
         _sdtw_rowscan_single if spec.family == "sdtw"
         else _dp_rowscan_single,
         spec=spec, return_window=return_window)
-    if reference.ndim == 1:
+    if reference.ndim == queries.ndim - 1:         # one shared reference
         fn = jax.vmap(single, in_axes=(0, None))
     else:
         fn = jax.vmap(single, in_axes=(0, 0))

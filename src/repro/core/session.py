@@ -19,12 +19,17 @@ An ``Aligner`` is constructed once per (reference, spec, backend) and
     normalized per call, inside the compiled executable);
   * caches the swizzled ``(R, w, LANES)`` kernel layout from
     ``kernels/ops.py`` prep, so the kernel backend's offline reference
-    layout optimization (paper §3) is actually offline;
+    layout optimization (paper §3) is actually offline; the reference
+    normalization and the swizzle run inside ``aligner.layout`` spans;
   * memoizes one compiled executable per (batch shape, dtype,
     outputs) request, traced and compiled inside the
     ``aligner.build`` span — warm calls are cache-lookup + dispatch,
     zero retraces (``Aligner.stats`` counts traces/compiles/hits; the
-    tier-1 suite asserts the zero);
+    tier-1 suite asserts the zero).  The reference (its kernel layout
+    and the families' reference operands) is an ARGUMENT of the
+    compiled call, never a constant: on the kernel backend one
+    program, shared by the whole process, serves every reference of
+    a shape;
   * counts the work of each wavefront kernel dispatch into the
     process-wide ``kernel.wavefront.*`` counters
     (``kernels.ops.count_wavefront``), from a work count made once per
@@ -32,6 +37,9 @@ An ``Aligner`` is constructed once per (reference, spec, backend) and
 
 Each call runs inside an ``aligner.call`` span, which holds
 ``aligner.build`` (cold calls) and ``aligner.dispatch``.
+
+The reference is (N,), or (N, D) for multivariate (B, M, D) query
+batches of D features (an (N, 1) reference is the univariate (N,)).
 
 Results are typed :class:`~repro.core.result.SDTWResult` pytrees, same
 as ``repro.sdtw``; capability validation (spec × backend × outputs)
@@ -46,6 +54,7 @@ pre-normalized reference and dispatches.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import threading
 from collections import OrderedDict
@@ -57,13 +66,50 @@ import numpy as np
 
 from repro import obs
 from repro.backends import registry
-from repro.core.normalize import normalize_batch
+from repro.core.normalize import normalize_batch, normalize_reference
 from repro.core.api import _derive_outputs
-from repro.core.result import (DEFAULT_OUTPUTS, SDTWResult,
+from repro.core.result import (DEFAULT_OUTPUTS, SDTWResult, from_sweep,
                                normalize_outputs, sweep_outputs)
 from repro.core.spec import DPSpec, resolve_spec, validate_batch_inputs
 
 log = logging.getLogger(__name__)
+
+_TRACING = threading.local()
+#   ``session``: the Aligner whose build is lowering on this thread —
+#   the one a traced function body counts its trace against
+
+
+def _count_trace() -> None:
+    """Called from every traced executable body: a Python side effect,
+    so it runs only while JAX traces, and counts the trace against the
+    session whose build is lowering on this thread."""
+    session = getattr(_TRACING, "session", None)
+    if session is not None:
+        session.stats.traces += 1
+        session._metrics.inc("aligner.traces")
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "spec", "n", "segment_width", "interpret", "sweep", "normalize"))
+def _kernel_program(q, r_layout, extras_ref, *, spec, n, segment_width,
+                    interpret, sweep, normalize):
+    """The kernel backend's whole call: normalize the queries, pack
+    them, and run the one wavefront dispatch against the reference
+    layout and the family's reference operands — both arguments, so
+    every session over a reference of the same shape shares one
+    program."""
+    from repro.kernels import ops as _ops
+    _count_trace()
+    if normalize:
+        q = normalize_batch(q)
+    q32 = q.astype(jnp.float32)
+    qk = _ops.prepare_queries(q32)
+    extras = tuple(extras_ref) + _ops.family_extras_query(spec, q32)
+    out = _ops.sdtw_wavefront_prepped(
+        qk, r_layout, batch=q.shape[0], m=q.shape[1], n=n,
+        segment_width=segment_width, interpret=interpret, spec=spec,
+        return_window="start" in sweep, extras=extras)
+    return from_sweep(out, sweep)
 
 
 @dataclasses.dataclass
@@ -72,7 +118,9 @@ class AlignerStats:
 
     ``traces`` counts executions of a traced function body (a Python
     side effect inside the jitted closure, so it only ticks while JAX
-    is tracing); a warm call leaves it unchanged.  ``compiles`` counts
+    is tracing); a warm call leaves it unchanged, and so does the
+    build of a kernel program another session over a reference of the
+    same shape already traced.  ``compiles`` counts
     jitted executables successfully brought to their first dispatch:
     the build traces and compiles, and the counter ticks only AFTER
     the first dispatch returns — a build or first dispatch that raises
@@ -97,17 +145,31 @@ class AlignerStats:
 
 
 @dataclasses.dataclass(frozen=True)
+class _Bound:
+    """A jitted function with its static arguments bound; ``lower``
+    goes through the jitted function itself, so every binding of the
+    same static arguments shares its compiled programs."""
+    jitted: Callable
+    static: dict
+
+    def lower(self, *args):
+        return self.jitted.lower(*args, **self.static)
+
+
+@dataclasses.dataclass(frozen=True)
 class _Executable:
     """One cached executable of a session.
 
-    ``run`` is what a call dispatches: the compiled program, or the
-    eager strategy itself.  ``jitted`` is the jitted function the
-    program was compiled from (None for eager strategies), kept for
-    :meth:`Aligner.hlo_texts`.  ``work`` holds the
-    :meth:`~repro.kernels.wavefront.KernelPlan.work` of each wavefront
-    kernel dispatch that one call makes."""
+    ``run`` is what a call dispatches, as ``run(queries, *args)``: the
+    compiled program, or the eager strategy itself.  ``jitted`` is the
+    jitted function the program was compiled from (None for eager
+    strategies), kept for :meth:`Aligner.hlo_texts`.  ``args`` are the
+    session's reference operands every call passes after the queries.
+    ``work`` holds the :meth:`~repro.kernels.wavefront.KernelPlan.work`
+    of each wavefront kernel dispatch that one call makes."""
     run: Callable
     jitted: Callable | None
+    args: tuple = ()
     work: tuple = ()
 
 
@@ -168,11 +230,21 @@ class Aligner:
                  metrics: obs.MetricsRegistry | None = None,
                  tracer: obs.Tracer | None = None):
         reference = jnp.asarray(reference)
-        if reference.ndim != 1:
+        if reference.ndim not in (1, 2):
             raise ValueError(
-                f"reference must be 1-D (length,), got {reference.shape}")
+                f"reference must be 1-D (length,) or 2-D (length, "
+                f"features), got {reference.shape}")
         if reference.shape[0] == 0:
             raise ValueError("empty reference (reference.shape[0] == 0)")
+        if reference.ndim == 2 and reference.shape[1] == 0:
+            raise ValueError("zero features (reference.shape[1] == 0)")
+        if reference.ndim == 2 and reference.shape[1] == 1:
+            reference = reference[:, 0]       # one feature: univariate
+        self.features = 1 if reference.ndim == 1 else int(reference.shape[1])
+        if self.features > 1 and isinstance(segment_width, str):
+            raise ValueError("segment_width='auto' tunes univariate "
+                             "workloads: pin a width for a multivariate "
+                             "reference")
         resolved = resolve_spec(spec, distance=distance,
                                 reduction=reduction, gamma=gamma,
                                 band=band)
@@ -183,14 +255,21 @@ class Aligner:
         # backends).  Per-call requests still re-validate in _build.
         hint = None if outputs is None else normalize_outputs(outputs)
         if backend is None:
-            self.backend, self.spec = registry.select(resolved,
-                                                      outputs=hint)
+            self.backend, self.spec = registry.select(
+                resolved, outputs=hint, features=self.features)
         else:
-            self.backend, self.spec = registry.resolve(backend, resolved,
-                                                       outputs=hint)
+            self.backend, self.spec = registry.resolve(
+                backend, resolved, outputs=hint, features=self.features)
+        self._metrics = obs.default_registry() if metrics is None else \
+            metrics
+        self._tracer = obs.default_tracer() if tracer is None else tracer
         self.normalize = normalize
-        self.reference = (normalize_batch(reference) if normalize
-                          else reference)
+        if normalize:
+            with self._tracer.span("aligner.layout", step="normalize",
+                                   shape=list(reference.shape)) as sp:
+                reference = normalize_reference(reference)
+                sp.sync(reference)
+        self.reference = reference
         self.length = int(reference.shape[0])
         self._auto_width = isinstance(segment_width, str)
         if self._auto_width and segment_width != "auto":
@@ -217,9 +296,6 @@ class Aligner:
         self._fns_lock = threading.RLock()
         self._fns: OrderedDict = OrderedDict()
         self.stats = AlignerStats()
-        self._metrics = obs.default_registry() if metrics is None else \
-            metrics
-        self._tracer = obs.default_tracer() if tracer is None else tracer
         log.debug("Aligner(n=%d, backend=%s, spec=%s)", self.length,
                   self.backend.name, self.spec.describe())
 
@@ -277,8 +353,11 @@ class Aligner:
         key = (segment_width, jnp.dtype(compute_dtype).name)
         cached = self._layouts.get(key)
         if cached is None:
-            self._layouts[key] = _ops.swizzle_reference(
-                self.reference.astype(compute_dtype), segment_width)
+            with self._tracer.span("aligner.layout", step="swizzle",
+                                   segment_width=segment_width) as sp:
+                self._layouts[key] = _ops.swizzle_reference(
+                    self.reference.astype(compute_dtype), segment_width)
+                sp.sync(self._layouts[key])
             self._layouts_verified.add(key)
         elif key not in self._layouts_verified:
             want = np.asarray(self.reference.astype(compute_dtype))
@@ -299,9 +378,11 @@ class Aligner:
         """One executable for one (batch shape, dtype, outputs) key.
 
         Capability validation happens here (loud registry errors);
-        the returned ``(callable, jitted, work)`` triple runs
-        normalize-queries + the fused sweep as ONE traced computation,
-        returning the sweep-level ``SDTWResult``.  ``jitted=False``
+        the returned ``(fn, jitted, args, work)`` runs normalize-queries
+        + the fused sweep as ONE traced computation, called as
+        ``fn(queries, *args)`` and returning the sweep-level
+        ``SDTWResult``.  ``args`` are the session's reference operands:
+        arguments, never constants of the program.  ``jitted=False``
         marks the eager strategies (distributed), whose dispatches must
         not tick the trace/compile counters — nothing is traced or
         built.  ``work`` is the wavefront kernel work of each dispatch
@@ -310,10 +391,9 @@ class Aligner:
         # re-validate with the requested outputs: an Aligner built for
         # a capable (spec, backend) pair can still be asked for an
         # output the backend cannot fulfill
-        registry.resolve(self.backend.name, self.spec, outputs=req)
+        registry.resolve(self.backend.name, self.spec, outputs=req,
+                         features=self.features)
         sweep = sweep_outputs(req)
-        stats = self.stats
-        metrics = self._metrics
         fused = self._fused(req)
         # derived requests (path / soft_alignment) get their queries
         # normalized ONCE, eagerly, in align() — both the sweep and the
@@ -332,7 +412,6 @@ class Aligner:
             from repro.kernels import ops as _ops
             w = self.resolved_width(batch_shape, req)
             interp, spec = self.interpret, self.spec
-            reference = self.reference
             norm = self.normalize
             work = _ops.wavefront_work(spec, batch=batch_shape[0],
                                        m=batch_shape[1], n=self.length,
@@ -341,9 +420,8 @@ class Aligner:
             # the same blocks over the same real columns
             works = () if work is None else (work, work)
 
-            def run_fused(q):
-                stats.traces += 1
-                metrics.inc("aligner.traces")
+            def run_fused(q, reference):
+                _count_trace()
                 if norm:
                     q = normalize_batch(q)
                 cost, end, E = backward.soft_alignment_fused(
@@ -351,71 +429,41 @@ class Aligner:
                     interpret=interp)
                 return SDTWResult(cost=cost, end=end, soft_alignment=E)
 
-            return jax.jit(run_fused), True, works
+            return jax.jit(run_fused), True, (self.reference,), works
 
         if self.backend.name == "kernel":
             # the session's whole point on the kernel path: the layout
-            # prep (pad + swizzle, paper §3) is closed over as a
-            # constant, never recomputed per call
+            # prep (pad + swizzle, paper §3) is done once, here, and
+            # handed to the shared program as an argument
             from repro.kernels import ops as _ops
-            from repro.core.result import from_sweep
-            B, m = batch_shape
+            B, m = batch_shape[:2]
             w = self.resolved_width(batch_shape, req)
             r_layout = self.layout(jnp.float32, segment_width=w)
-            n = self.length
-            interp, spec = self.interpret, self.spec
-            norm = self.normalize and not pre_normalized
             # non-sdtw families ride extra operands through the same
             # pallas_call; the reference-derived ones (twed's shifted
             # layout, erp's bt prefix) are computed ONCE here — eagerly,
             # by the same standalone jit every path uses, so the
-            # session's grids stay bit-identical to the one-shot call —
-            # and closed over next to r_layout
-            extras_ref = _ops.family_extras_ref(spec, self.reference,
+            # session's grids stay bit-identical to the one-shot call
+            extras_ref = _ops.family_extras_ref(self.spec, self.reference,
                                                 segment_width=w)
-            work = _ops.wavefront_work(spec, batch=B, m=m, n=n,
-                                       segment_width=w)
-            works = () if work is None else (work,)
-
-            def run(q):
-                stats.traces += 1
-                metrics.inc("aligner.traces")
-                if norm:
-                    q = normalize_batch(q)
-                q32 = q.astype(jnp.float32)
-                qk = _ops.prepare_queries(q32)
-                extras = extras_ref + _ops.family_extras_query(spec, q32)
-                out = _ops.sdtw_wavefront_prepped(
-                    qk, r_layout, batch=B, m=m, n=n, segment_width=w,
-                    interpret=interp, spec=spec,
-                    return_window="start" in sweep, extras=extras)
-                return from_sweep(out, sweep)
-
-            return jax.jit(run), True, works
+            work = _ops.wavefront_work(self.spec, batch=B, m=m,
+                                       n=self.length, segment_width=w,
+                                       features=self.features)
+            fn = _Bound(_kernel_program, dict(
+                spec=self.spec, n=self.length, segment_width=w,
+                interpret=self.interpret, sweep=sweep,
+                normalize=self.normalize and not pre_normalized))
+            return (fn, True, (r_layout, extras_ref),
+                    () if work is None else (work,))
 
         backend, spec = self.backend, self.spec
         norm = self.normalize and not pre_normalized
-        reference, opts = self.reference, self.options
+        opts = self.options
         seg = self.resolved_width(batch_shape, req)
         interp = self.interpret
 
-        if backend.name == "distributed":
-            # shard_map pipelines carry their own jit + per-mesh cache
-            # (backends.builtin); wrapping them again buys nothing and
-            # this session builds no executable of its own
-            def run_eager(q):
-                if norm:
-                    q = normalize_batch(q)
-                plan = registry.ExecutionPlan(
-                    queries=q, reference=reference, segment_width=seg,
-                    interpret=interp, outputs=sweep, options=opts)
-                return backend.execute(spec, plan)
-
-            return run_eager, False, ()
-
-        def run(q):
-            stats.traces += 1
-            metrics.inc("aligner.traces")
+        def run(q, reference):
+            _count_trace()
             if norm:
                 q = normalize_batch(q)
             plan = registry.ExecutionPlan(
@@ -423,7 +471,12 @@ class Aligner:
                 interpret=interp, outputs=sweep, options=opts)
             return backend.execute(spec, plan)
 
-        return jax.jit(run), True, ()
+        if backend.name == "distributed":
+            # shard_map pipelines carry their own jit + per-mesh cache
+            # (backends.builtin); wrapping them again buys nothing and
+            # this session builds no executable of its own
+            return run, False, (self.reference,), ()
+        return jax.jit(run), True, (self.reference,), ()
 
     def _fused(self, req: frozenset) -> bool:
         """Does this request dispatch the kernel's fused forward+reverse
@@ -433,7 +486,8 @@ class Aligner:
 
     # -------------------------------------------------------- serving
     def align(self, queries, *, outputs=DEFAULT_OUTPUTS) -> SDTWResult:
-        """Align one query batch. queries: (B, M).
+        """Align one query batch. queries: (B, M), or (B, M, D) against
+        a reference of D features.
 
         Returns an :class:`SDTWResult` restricted to ``outputs``.  The
         first call for a given (batch shape, dtype, outputs) traces and
@@ -444,6 +498,8 @@ class Aligner:
 
     def _align(self, queries, outputs) -> SDTWResult:
         queries = jnp.asarray(queries)
+        if queries.ndim == 3 and queries.shape[2] == 1:
+            queries = queries[..., 0]         # one feature: univariate
         validate_batch_inputs(queries, self.reference,
                               segment_width=None if self._auto_width
                               else self.segment_width)
@@ -472,22 +528,27 @@ class Aligner:
                                        backend=self.backend.name,
                                        batch=list(queries.shape),
                                        outputs=sorted(req)):
-                    fn, jitted, work = self._build(queries.shape,
-                                                   queries.dtype, req)
+                    fn, jitted, args, work = self._build(
+                        queries.shape, queries.dtype, req)
                     # jax.jit would trace and compile lazily, inside
                     # the first dispatch: do both here, so this span
                     # holds them and the dispatch span only runs
+                    _TRACING.session = self
+                    try:
+                        run = (fn.lower(queries, *args).compile()
+                               if jitted else fn)
+                    finally:
+                        _TRACING.session = None
                     entry = _Executable(
-                        run=fn.lower(queries).compile() if jitted
-                        else fn,
-                        jitted=fn if jitted else None, work=work)
+                        run=run, jitted=fn if jitted else None,
+                        args=args, work=work)
                 log.debug("built executable key=%s backend=%s",
                           key, self.backend.name)
             with self._tracer.span("aligner.dispatch",
                                    backend=self.backend.name,
                                    batch=list(queries.shape),
                                    cold=cold) as sp:
-                res = entry.run(queries)
+                res = entry.run(queries, *entry.args)
                 sp.sync(res)
             if entry.work:
                 from repro.kernels import ops as _ops
@@ -534,10 +595,11 @@ class Aligner:
         the Pallas kernel as a ``tpu_custom_call``.  Each text is
         lowered and compiled again from the kept jitted function."""
         with self._fns_lock:
-            held = [(key, e.jitted) for key, e in self._fns.items()
+            held = [(key, e.jitted, e.args) for key, e in self._fns.items()
                     if e.jitted is not None]
-        return [fn.lower(jax.ShapeDtypeStruct(shape, jnp.dtype(dtype)))
-                .compile().as_text() for (shape, dtype, _), fn in held]
+        return [fn.lower(jax.ShapeDtypeStruct(shape, jnp.dtype(dtype)),
+                         *args).compile().as_text()
+                for (shape, dtype, _), fn, args in held]
 
     def __repr__(self):
         return (f"Aligner(n={self.length}, backend={self.backend.name!r}, "

@@ -341,6 +341,22 @@ class DPSpec:
         # signs agree). Degenerate but well-defined; eps guards 0-values.
         return 1.0 - (q * r) / (jnp.abs(q) * jnp.abs(r) + 1e-8)
 
+    def feature_cost(self, q, r):
+        """Local cost of feature vectors, whose last axis is the
+        feature axis: the per-feature :meth:`cell_cost` terms added in
+        feature order, so every executor that adds them the same way
+        agrees bit for bit.  Broadcasts like ``q - r`` over the other
+        axes.  Cosine is a cost of scalar samples here and is not
+        defined for vectors."""
+        if self.distance == "cosine":
+            raise ValueError("distance 'cosine' has no multivariate "
+                             "form here: use sqeuclidean or abs for "
+                             "(B, M, D) inputs")
+        total = self.cell_cost(q[..., 0], r[..., 0])
+        for d in range(1, q.shape[-1]):
+            total = total + self.cell_cost(q[..., d], r[..., d])
+        return total
+
     def reduce3(self, left, up, upleft):
         """The 3-way predecessor reduction. Hard-min keeps the operand
         order min(min(left, up), upleft) every pre-spec backend used.
@@ -553,25 +569,59 @@ def resolve_spec(spec: DPSpec | None = None, *, distance: str | None = None,
 # ``core.api.sdtw``, ``core.engine`` and ``search.SearchService``.
 
 def validate_batch_inputs(queries, reference, *, segment_width=None):
-    """The public batch contract: queries (B, M), reference (N,) shared
-    across the batch, non-empty everywhere.  (Per-query (B, N)
-    references are a backend capability — engine/ref accept them when
-    called directly, as the search service's pair sweeps do — but the
-    public ``sdtw`` contract stays 1-D.)"""
-    if queries.ndim != 2:
+    """The public batch contract: univariate queries (B, M) against a
+    reference (N,), or multivariate queries (B, M, D) against a
+    reference (N, D) with the same D features, shared across the
+    batch, non-empty everywhere.  (Per-query (B, N) references are a
+    backend capability — engine/ref accept them when called directly,
+    as the search service's pair sweeps do — but the public ``sdtw``
+    contract shares one reference.)"""
+    if queries.ndim not in (2, 3):
         raise ValueError(
-            f"queries must be 2-D (batch, length), got shape {queries.shape}")
-    if reference.ndim != 1:
+            f"queries must be 2-D (batch, length) or 3-D (batch, length, "
+            f"features), got shape {queries.shape}")
+    if reference.ndim != queries.ndim - 1:
+        want = ("1-D (length,)" if queries.ndim == 2
+                else "2-D (length, features)")
         raise ValueError(
-            f"reference must be 1-D (length,), got shape {reference.shape}")
+            f"reference must be {want} for {queries.ndim}-D queries, got "
+            f"shape {reference.shape}")
     if queries.shape[0] == 0:
         raise ValueError("empty query batch (queries.shape[0] == 0)")
     if queries.shape[1] == 0:
         raise ValueError("zero-length queries (queries.shape[1] == 0)")
     if reference.shape[0] == 0:
         raise ValueError("empty reference (reference.shape[0] == 0)")
+    if queries.ndim == 3:
+        if queries.shape[2] == 0:
+            raise ValueError("zero features (queries.shape[2] == 0)")
+        if reference.shape[1] != queries.shape[2]:
+            raise ValueError(
+                f"queries have {queries.shape[2]} features, the reference "
+                f"{reference.shape[1]}")
     if segment_width is not None and segment_width < 1:
         raise ValueError(f"segment_width must be >= 1, got {segment_width}")
+
+
+def univariate(queries, reference):
+    """Univariate views of a batch with one feature: (B, M, 1) and
+    (N, 1) become (B, M) and (N,), so that one feature runs exactly
+    the univariate path.  Anything else is returned as it is."""
+    if queries.ndim == 3 and queries.shape[2] == 1:
+        return queries[..., 0], reference[..., 0]
+    return queries, reference
+
+
+def require_univariate(series, what: str) -> None:
+    """The search and serving contract: 1-D series only.  Multivariate
+    (length, features) series run on ``repro.sdtw`` and
+    ``repro.Aligner``; search and serving are univariate."""
+    if series.ndim != 1:
+        raise ValueError(
+            f"{what} must be 1-D, got shape {series.shape}"
+            + (": multivariate (length, features) series run on "
+               "repro.sdtw and repro.Aligner only; search and serving "
+               "are univariate" if series.ndim == 2 else ""))
 
 
 def validate_query_list(queries) -> None:
@@ -579,5 +629,4 @@ def validate_query_list(queries) -> None:
     if len(queries) == 0:
         raise ValueError("empty query batch")
     for q in queries:
-        if q.ndim != 1:
-            raise ValueError(f"each query must be 1-D, got shape {q.shape}")
+        require_univariate(q, "each query")

@@ -64,6 +64,9 @@ def dp_matrix(q: np.ndarray, r: np.ndarray, spec: DPSpec,
                          "sdtw oracle is repro.core.ref.sdtw_numpy")
     q = np.asarray(q, dtype=dtype)
     r = np.asarray(r, dtype=dtype)
+    if q.ndim != 1 or r.ndim != 1:
+        raise ValueError(f"family {fam!r} is univariate: multivariate "
+                         "(M, D) series run the sdtw family only")
     m, n = len(q), len(r)
     big = dtype(spec.big)
     D = np.full((m, n), big, dtype=dtype)
@@ -129,7 +132,8 @@ def dp_oracle(q: np.ndarray, r: np.ndarray,
     executors' fold semantics:
 
     * sdtw — free-end bottom-row reduction (delegates to
-      :func:`repro.core.ref.sdtw_numpy`);
+      :func:`repro.core.ref.sdtw_numpy`, which also takes multivariate
+      (M, D) and (N, D) series);
     * twed / erp — the global corner cell ``D[m-1, n-1]``; a band that
       disconnects the corner yields ``(inf, 0)``;
     * local — the lexicographic ``(value, column)`` minimum over every

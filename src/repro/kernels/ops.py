@@ -32,8 +32,8 @@ from repro.core.spec import (DEFAULT_SPEC, NO_WINDOW,  # noqa: F401
 # rationale (and why it rules out cosine) lives with the other
 # sentinels in core/spec.py.
 from repro.kernels.wavefront import (LANES, SUBLANES, KernelPlan,
-                                     build_plan, query_pack_len,
-                                     wavefront_call)
+                                     build_plan, feature_stride,
+                                     query_pack_len, wavefront_call)
 from repro.kernels.normalizer import normalizer_pallas
 
 
@@ -99,9 +99,15 @@ def ceil_to(x: int, m: int) -> int:
 
 
 def swizzle_reference(r: jnp.ndarray, segment_width: int) -> jnp.ndarray:
-    """(N,) -> (R, w, LANES) with [b, k, l] = r[(b*LANES + l)*w + k]."""
+    """(N,) -> (R, w, LANES) with [b, k, l] = r[(b*LANES + l)*w + k];
+    a multivariate (N, D) -> (R, D, w, LANES) with [b, d, k, l] =
+    r[(b*LANES + l)*w + k, d]."""
     w = segment_width
     n_pad = ceil_to(r.shape[0], LANES * w)
+    if r.ndim == 2:
+        r = jnp.pad(r, ((0, n_pad - r.shape[0]), (0, 0)),
+                    constant_values=PAD_VALUE)
+        return r.reshape(-1, LANES, w, r.shape[1]).transpose(0, 3, 2, 1)
     r = jnp.pad(r, (0, n_pad - r.shape[0]), constant_values=PAD_VALUE)
     return r.reshape(-1, LANES, w).transpose(0, 2, 1)
 
@@ -131,13 +137,26 @@ def swizzle_reference_reverse(r: jnp.ndarray,
 
 def unswizzle_reference(r_layout: jnp.ndarray) -> jnp.ndarray:
     """(R, w, LANES) -> (R*LANES*w,) inverse of :func:`swizzle_reference`
-    (padded tail included). Used by the packing-invariant tests."""
+    (padded tail included); (R, D, w, LANES) -> (R*LANES*w, D)."""
+    if r_layout.ndim == 4:
+        return r_layout.transpose(0, 3, 2, 1).reshape(
+            -1, r_layout.shape[1])
     return r_layout.transpose(0, 2, 1).reshape(-1)
 
 
 def prepare_queries(q: jnp.ndarray) -> jnp.ndarray:
     """(B, M) -> (G, SUBLANES, query_pack_len(M)): each query reversed
-    behind LANES-1 zeros, zero-padded on the right to the pack length."""
+    behind LANES-1 zeros, zero-padded on the right to the pack length.
+    A multivariate (B, M, D) -> (G, SUBLANES, query_pack_len(M, D)):
+    each feature packed so, ``feature_stride(M)`` lanes apart."""
+    if q.ndim == 3:
+        B, M, D = q.shape
+        stride = feature_stride(M)
+        q = jnp.pad(q, ((0, ceil_to(B, SUBLANES) - B), (0, 0), (0, 0)))
+        qrev = jnp.flip(q, axis=1).transpose(0, 2, 1)      # (Bp, D, M)
+        qrev = jnp.pad(qrev, ((0, 0), (0, 0),
+                              (LANES - 1, stride - M - (LANES - 1))))
+        return qrev.reshape(-1, SUBLANES, D * stride)
     B, M = q.shape
     b_pad = ceil_to(B, SUBLANES)
     q = jnp.pad(q, ((0, b_pad - B), (0, 0)))
@@ -156,12 +175,13 @@ def validate_prepped(q_prepped, r_layout, *, m: int, n: int,
     with an opaque shape assert; these checks name the mismatch and the
     fix instead.
     """
-    if getattr(r_layout, "ndim", None) != 3 or \
-            r_layout.shape[1:] != (segment_width, LANES):
+    if getattr(r_layout, "ndim", None) not in (3, 4) or \
+            r_layout.shape[-2:] != (segment_width, LANES):
         raise ValueError(
             f"reference layout {tuple(getattr(r_layout, 'shape', ()))} "
             f"does not match segment_width={segment_width}: expected "
-            f"(R, {segment_width}, {LANES}) from "
+            f"(R, {segment_width}, {LANES}) (or (R, D, {segment_width}, "
+            f"{LANES}) for D features) from "
             f"swizzle_reference(reference, segment_width="
             f"{segment_width}) — the layout must be swizzled with the "
             f"same segment_width it is dispatched with")
@@ -173,13 +193,21 @@ def validate_prepped(q_prepped, r_layout, *, m: int, n: int,
             f"{segment_width} x {LANES}); segment_width must divide "
             f"the layout the reference was padded for — re-swizzle "
             f"with swizzle_reference(reference, {segment_width})")
+    features = layout_features(r_layout)
     if getattr(q_prepped, "ndim", None) != 3 or \
             q_prepped.shape[1] != SUBLANES or \
-            q_prepped.shape[2] != query_pack_len(m):
+            q_prepped.shape[2] != query_pack_len(m, features):
         raise ValueError(
             f"query pack {tuple(getattr(q_prepped, 'shape', ()))} does "
-            f"not match m={m}: expected (G, {SUBLANES}, "
-            f"{query_pack_len(m)}) from prepare_queries")
+            f"not match m={m} and {features} feature(s): expected (G, "
+            f"{SUBLANES}, {query_pack_len(m, features)}) from "
+            f"prepare_queries")
+
+
+def layout_features(r_layout) -> int:
+    """D of a multivariate (R, D, w, LANES) reference layout, 1 of a
+    univariate (R, w, LANES) one."""
+    return int(r_layout.shape[1]) if r_layout.ndim == 4 else 1
 
 
 def plan_rows(plan: KernelPlan, batch: int) -> KernelPlan:
@@ -195,7 +223,7 @@ def plan_rows(plan: KernelPlan, batch: int) -> KernelPlan:
 def kernel_plan(spec: DPSpec | None = None, *, m: int, n: int,
                 segment_width: int = 8, compute_dtype=jnp.float32,
                 with_window: bool = False,
-                batch: int | None = None) -> KernelPlan:
+                batch: int | None = None, features: int = 1) -> KernelPlan:
     """The :class:`~repro.kernels.wavefront.KernelPlan` a dispatch of
     these (unpadded) shapes executes — band-skip geometry included, so
     callers (search stats, benchmarks) can read ``plan.grid_blocks``
@@ -207,7 +235,8 @@ def kernel_plan(spec: DPSpec | None = None, *, m: int, n: int,
     plan = build_plan(sp, m=m,
                       segment_width=segment_width, num_ref_blocks=blocks,
                       compute_dtype=compute_dtype, with_window=with_window,
-                      n=n if sp.family != "sdtw" else None)
+                      n=n if sp.family != "sdtw" else None,
+                      features=features)
     return plan if batch is None else plan_rows(plan, batch)
 
 
@@ -227,7 +256,8 @@ def band_blocks_all(spec: DPSpec, m: int, n: int) -> bool:
 
 
 def wavefront_work(spec: DPSpec | None = None, *, batch: int, m: int,
-                   n: int, segment_width: int = 8) -> dict | None:
+                   n: int, segment_width: int = 8,
+                   features: int = 1) -> dict | None:
     """:meth:`KernelPlan.work` of one dispatch of these (unpadded)
     shapes, or None where :func:`band_blocks_all` answers the call and
     no kernel runs.  The work does not depend on the compute dtype or
@@ -236,19 +266,20 @@ def wavefront_work(spec: DPSpec | None = None, *, batch: int, m: int,
     if band_blocks_all(sp, m, n):
         return None
     return kernel_plan(sp, m=m, n=n, segment_width=segment_width,
-                       batch=batch).work(batch, n)
+                       batch=batch, features=features).work(batch, n)
 
 
 _WORK_COUNTERS = tuple((k, f"kernel.wavefront.{k}") for k in
                        ("grid_steps", "loop_steps", "lane_cells",
-                        "cells_real"))
+                        "cells_real", "feature_cells"))
 
 
 def count_wavefront(work: dict) -> None:
     """Add one wavefront dispatch's :meth:`KernelPlan.work` to the
     process-wide counters ``kernel.wavefront.dispatches`` /
     ``.grid_steps`` / ``.loop_steps`` / ``.lane_cells`` /
-    ``.cells_real`` of :func:`repro.obs.default_registry`, and to
+    ``.cells_real`` / ``.feature_cells`` (equal to ``.cells_real`` for
+    univariate dispatches) of :func:`repro.obs.default_registry`, and to
     ``.wide_dispatches`` where the plan carried two query groups a
     step (``wide_dispatches / dispatches``: how often that engages).
 
@@ -337,8 +368,10 @@ def sdtw_wavefront_prepped(q_prepped: jnp.ndarray, r_layout: jnp.ndarray, *,
                            extras: tuple = ()):
     """Dispatch the wavefront kernel on pre-packed operands.
 
-    q_prepped: (G, SUBLANES, query_pack_len(m)) from :func:`prepare_queries`
-    r_layout:  (R, w, LANES) from :func:`swizzle_reference`
+    q_prepped: (G, SUBLANES, query_pack_len(m, D)) from
+               :func:`prepare_queries`
+    r_layout:  (R, w, LANES) from :func:`swizzle_reference`, or
+               (R, D, w, LANES) for multivariate inputs of D features
     extras:    the spec family's packed extra operands from
                :func:`family_extras` (required iff the plan's
                ``extra_inputs`` is non-empty; sdtw/local take none).
@@ -395,7 +428,8 @@ def sdtw_wavefront_prepped(q_prepped: jnp.ndarray, r_layout: jnp.ndarray, *,
     plan = plan_rows(build_plan(
         sp, m=m, segment_width=segment_width,
         num_ref_blocks=r_layout.shape[0], compute_dtype=compute_dtype,
-        with_window=return_window, n=n if sp.family != "sdtw" else None),
+        with_window=return_window, n=n if sp.family != "sdtw" else None,
+        features=layout_features(r_layout)),
         q_prepped.shape[0] * SUBLANES)
     out = _dispatch(q_prepped, r_layout, tuple(extras), plan=plan,
                     interpret=_resolve_interpret(interpret))
@@ -417,7 +451,8 @@ def sdtw_wavefront(queries: jnp.ndarray, reference: jnp.ndarray, *,
                    return_window: bool = False):
     """Batched subsequence DTW via the Pallas wavefront kernel.
 
-    queries: (B, M) float; reference: (N,) float.
+    queries: (B, M) float; reference: (N,) float — or multivariate
+    (B, M, D) queries against an (N, D) reference.
     interpret: None = auto (compiled on TPU, interpreted elsewhere).
     Returns (costs (B,) f32, end_indices (B,) i32), or
     (costs, starts, ends) when ``return_window``.
@@ -427,8 +462,9 @@ def sdtw_wavefront(queries: jnp.ndarray, reference: jnp.ndarray, *,
     """
     queries = jnp.asarray(queries)
     reference = jnp.asarray(reference)
-    B, M = queries.shape
+    B, M = queries.shape[:2]
     N = reference.shape[0]
+    features = queries.shape[2] if queries.ndim == 3 else 1
     qk, rk = _prep(queries, reference, segment_width=segment_width,
                    compute_dtype=compute_dtype)
     sp = DEFAULT_SPEC if spec is None else spec
@@ -441,7 +477,8 @@ def sdtw_wavefront(queries: jnp.ndarray, reference: jnp.ndarray, *,
         return_window=return_window, extras=extras)
     if not isinstance(qk, jax.core.Tracer):      # not while tracing
         work = wavefront_work(sp, batch=B, m=M, n=N,
-                              segment_width=segment_width)
+                              segment_width=segment_width,
+                              features=features)
         if work is not None:
             count_wavefront(work)
     return out
@@ -459,8 +496,15 @@ def _normalize_padded(x, *, n: int, interpret: bool):
 
 
 def normalize(x: jnp.ndarray, *, interpret: bool | None = None) -> jnp.ndarray:
-    """Batch z-normalization via the Pallas kernel. x: (B, L) -> (B, L).
+    """Batch z-normalization via the Pallas kernel, over time. x: (B, L)
+    -> (B, L); a multivariate (B, L, D) batch normalizes each feature
+    over time, as D x B univariate rows of the same kernel.
     interpret: None = auto (compiled on TPU, interpreted elsewhere)."""
     x = jnp.asarray(x)
-    return _normalize_padded(x, n=x.shape[1],
-                             interpret=_resolve_interpret(interpret))
+    interp = _resolve_interpret(interpret)
+    if x.ndim == 3:
+        B, L, D = x.shape
+        rows = x.transpose(0, 2, 1).reshape(B * D, L)
+        out = _normalize_padded(rows, n=L, interpret=interp)
+        return out.reshape(B, D, L).transpose(0, 2, 1)
+    return _normalize_padded(x, n=x.shape[1], interpret=interp)

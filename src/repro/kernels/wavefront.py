@@ -56,6 +56,11 @@ DESIGN — mapping channels back to the paper's AMD/HIP mechanisms:
     serial step's fixed latency (scalar window arithmetic, XLU round
     trips, the fold's VMEM load and store) is paid once for 16
     queries.  Each query's arithmetic is the same at either height.
+  * multivariate series -> ``plan.features`` > 1: each query row packs
+    one reversed copy per feature (:func:`feature_stride` lanes apart)
+    and each reference block holds a (D, w, LANES) tile; every step
+    sums the D per-feature costs of its w cells on the VPU
+    (:func:`_feature_costs`) before the recurrence reads them.
 
 Band-skip: with a Sakoe–Chiba band every cell (i, j) with
 ``j > (m - 1) + band`` is out of band for EVERY query row, so trailing
@@ -107,12 +112,24 @@ _EXTRA_KIND = {
 }
 
 
-def query_pack_len(m: int) -> int:
+def query_pack_len(m: int, features: int = 1) -> int:
     """Lane width of one prepared query row: LANES-1 leading zeros, the
     reversed query, then 2*LANES-1 zeros.  The last per-step window
     starts at m + LANES - 2, so the two aligned lane tiles every window
-    is read from (:func:`_lane_window`) stay inside the row."""
-    return m + 3 * LANES - 2
+    is read from (:func:`_lane_window`) stay inside the row.  A row of
+    ``features`` > 1 holds one such pack per feature, each
+    :func:`feature_stride` lanes apart."""
+    if features == 1:
+        return m + 3 * LANES - 2
+    return features * feature_stride(m)
+
+
+def feature_stride(m: int) -> int:
+    """Lanes between the packs of consecutive features of a
+    multivariate query row: one query pack rounded up to whole lane
+    tiles, so that every feature's window lies at the same lane offset
+    of its tiles."""
+    return _ceil_to(m + 3 * LANES - 2, LANES)
 
 
 def strip_len(m: int) -> int:
@@ -140,6 +157,31 @@ def _lane_window(ref, start, lane):
     lo = pltpu.roll(ref[0, :, pl.ds(base, LANES)], shift, 1)
     hi = pltpu.roll(ref[0, :, pl.ds(base + LANES, LANES)], shift, 1)
     return jnp.where(lane < LANES - off, lo, hi)
+
+
+def _feature_costs(plan, q_ref, r_ref, t, lane):
+    """The w cell costs of every lane at step ``t`` of a multivariate
+    plan, one (rows, LANES) array per segment slot: for each feature,
+    its query window (read as :func:`_lane_window` reads one) against
+    the slot's reference row, the per-feature terms added in feature
+    order as :meth:`DPSpec.feature_cost` adds them.  All features'
+    windows share one lane offset (:func:`feature_stride`), so the
+    offset is worked out once a step."""
+    spec, cdt = plan.spec, plan.compute_dtype
+    stride = feature_stride(plan.m)
+    base, off = _aligned(plan.m - 1 + LANES - 1 - t)
+    shift = lax.rem(LANES - off, LANES)
+    keep = lane < LANES - off
+    costs = [None] * plan.segment_width
+    for d in range(plan.features):
+        at = pl.multiple_of(base + d * stride, LANES)
+        lo = pltpu.roll(q_ref[0, :, pl.ds(at, LANES)], shift, 1)
+        hi = pltpu.roll(q_ref[0, :, pl.ds(at + LANES, LANES)], shift, 1)
+        qd = jnp.where(keep, lo, hi).astype(cdt)
+        for k in range(plan.segment_width):
+            term = spec.cell_cost(qd, r_ref[0, d, k:k + 1, :].astype(cdt))
+            costs[k] = term if d == 0 else costs[k] + term
+    return costs
 
 
 # ------------------------------------------------------------- channels
@@ -545,6 +587,9 @@ class KernelPlan:
     #                              valid-cell set j < n).  sdtw plans
     #                              leave it None so their jit cache
     #                              stays keyed on padded shapes alone.
+    features: int = 1            # D of multivariate (B, M, D) inputs:
+    #                              each cell's cost adds D per-feature
+    #                              terms, computed inside the kernel
     rows_per_step: int = SUBLANES  # queries a serial step carries: one
     #                              packed group, or two whenever the
     #                              batch fills two (ops.plan_rows).
@@ -553,6 +598,14 @@ class KernelPlan:
     #                              its body at 8 (PERF.md section 5)
 
     def __post_init__(self):
+        if self.features < 1:
+            raise ValueError(f"features must be >= 1, got {self.features}")
+        if self.features > 1 and (self.spec.family != "sdtw"
+                                  or self.reverse or self.checkpoint):
+            raise ValueError(
+                "multivariate plans run the sdtw forward sweep only: "
+                f"got family {self.spec.family!r}, reverse={self.reverse}, "
+                f"checkpoint={self.checkpoint}")
         if self.rows_per_step not in (SUBLANES, 2 * SUBLANES):
             raise ValueError(
                 f"rows_per_step is {SUBLANES} or {2 * SUBLANES} (one or "
@@ -733,7 +786,9 @@ class KernelPlan:
             x LANES x ``segment_width`` each, padding, pad group and
             pipeline fill included;
           * ``cells_real``: the real query x real column cells inside
-            the executed blocks, never more than ``lane_cells``.
+            the executed blocks, never more than ``lane_cells``;
+          * ``feature_cells``: ``cells_real`` x the plan's features,
+            the per-feature cost terms the real cells add.
 
         Forward and reverse sweeps execute the same number of blocks
         and hold the same real columns, so both read the same work."""
@@ -745,17 +800,19 @@ class KernelPlan:
         rows = self.rows_per_step
         grid_steps = _ceil_to(batch, rows) // rows * self.grid_blocks
         loop_steps = grid_steps * (self.m + LANES - 1)
+        cells_real = batch * self.m * min(n, self.grid_blocks * block_cols)
         return {
             "rows_per_step": rows,
             "grid_steps": grid_steps,
             "loop_steps": loop_steps,
             "lane_cells": loop_steps * rows * block_cols,
-            "cells_real": batch * self.m
-            * min(n, self.grid_blocks * block_cols),
+            "cells_real": cells_real,
+            "feature_cells": cells_real * self.features,
         }
 
     # ------------------------------------------------------------ cell
-    def cell(self, qv, rv, *, is_row0, i_l, j_col, vals3, extras=None):
+    def cell(self, qv, rv, *, is_row0, i_l, j_col, vals3, extras=None,
+             cost=None):
         """One DP cell across every channel.
 
         ``vals3`` maps channel name -> (left, up, upleft) carries; the
@@ -772,6 +829,10 @@ class KernelPlan:
         (``q_prev``/``r_prev`` for twed, ``bt``/``bl`` prefixes for
         erp).  The boundary injection lives inside ``family_cell``, so
         the carries' edge sentinels are simply overridden at row/col 0.
+
+        ``cost`` is the cell's local cost where the caller has it
+        already (multivariate plans, :func:`_feature_costs`); then
+        ``qv`` and ``rv`` go unused.
         """
         spec = self.spec
         big = jnp.asarray(self.big, self.compute_dtype)
@@ -788,7 +849,8 @@ class KernelPlan:
             if in_band is not None:
                 val = jnp.where(in_band, val, big)
             return {"cost": val}
-        cost = spec.cell_cost(qv, rv)
+        if cost is None:
+            cost = spec.cell_cost(qv, rv)
         if self.reverse:
             # the reverse recurrence B[i,j] = C[i,j] + smin(B[i,j+1],
             # B[i+1,j], B[i+1,j+1]) run as a FORWARD sweep in flipped
@@ -834,12 +896,13 @@ def build_plan(spec: DPSpec, *, m: int, segment_width: int,
                num_ref_blocks: int, compute_dtype=jnp.float32,
                with_window: bool = False,
                band_skip: bool = True,
-               n: int | None = None) -> KernelPlan:
+               n: int | None = None, features: int = 1) -> KernelPlan:
     """Convenience constructor accepting a jnp dtype object."""
     return KernelPlan(spec=spec, m=m, segment_width=segment_width,
                       num_ref_blocks=num_ref_blocks,
                       compute_dtype_name=jnp.dtype(compute_dtype).name,
-                      with_window=with_window, band_skip=band_skip, n=n)
+                      with_window=with_window, band_skip=band_skip, n=n,
+                      features=features)
 
 
 # ------------------------------------------------------------- executor
@@ -848,9 +911,12 @@ def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
     plan's channels and fold.
 
     q_ref:  (1, rows, Mp)      reversed+padded queries (see ops.py),
-                               ``plan.rows_per_step`` of them
+                               ``plan.rows_per_step`` of them; a
+                               multivariate plan's row holds one pack
+                               per feature
     r_ref:  (1, w, LANES)      reference block,
-                               [k, l] = r[blk*LANES*w + l*w + k]
+                               [k, l] = r[blk*LANES*w + l*w + k];
+                               (1, D, w, LANES) for D features
     refs:   ``plan.extra_inputs`` family operand refs (laid out like
             q_ref or r_ref per ``_EXTRA_KIND``), then plan.num_outputs
             output refs, one boundary strip per channel, then the
@@ -902,7 +968,15 @@ def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
 
         # q value for (query s, lane l) = q[s, t - l]; q_ref stores the
         # REVERSED query so this is an ascending slice (no lane flip).
-        qv = _lane_window(q_ref, m - 1 + LANES - 1 - t, lane).astype(cdt)
+        # A multivariate plan reads its features' windows into the w
+        # cell costs instead.
+        costs = [None] * w
+        if plan.features > 1:
+            costs = _feature_costs(plan, q_ref, r_ref, t, lane)
+            qv = None
+        else:
+            qv = _lane_window(q_ref, m - 1 + LANES - 1 - t,
+                              lane).astype(cdt)
 
         # per-step family operand values, laid out exactly like qv /
         # r_blk.  q_prev = q[i_l - 1] is the t-1 slice of the same
@@ -930,9 +1004,10 @@ def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
                 ex_k = dict(ex_step, r_prev=ref_row(ex_refs["r_prev"], k))
             elif plan.family == "erp":
                 ex_k = dict(ex_step, bt=ref_row(ex_refs["bt"], k))
-            new = plan.cell(qv, ref_row(r_ref, k), is_row0=is_row0,
+            rv = None if plan.features > 1 else ref_row(r_ref, k)
+            new = plan.cell(qv, rv, is_row0=is_row0,
                             i_l=i_l, j_col=j_base + k, vals3=vals3,
-                            extras=ex_k)
+                            extras=ex_k, cost=costs[k])
             for ch in channels:
                 rows[ch.name].append(new[ch.name])
                 lefts[ch.name] = new[ch.name]
@@ -997,7 +1072,9 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
                ``ops.prepare_queries``, Mp = ``query_pack_len(m)``
                (a reverse plan takes the FLIPPED queries prepared the
                same way, against ``ops.swizzle_reference_reverse``)
-    r_layout:  (R, w, LANES) pre-swizzled reference blocks
+    r_layout:  (R, w, LANES) pre-swizzled reference blocks, or
+               (R, D, w, LANES) for a plan of D features, whose
+               queries are packed (G, SUBLANES, query_pack_len(m, D))
     extras:    ``plan.extra_inputs`` family operands, in order, each
                packed like q_rev_pad ('q'-kind) or r_layout ('r'-kind)
                — see ``ops.family_extras``.  They ride the SAME
@@ -1018,7 +1095,12 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
     group of zero queries, trimmed from the outputs like batch padding.
     """
     G, S, Mp = q_rev_pad.shape
-    R, w, L = r_layout.shape
+    D = plan.features
+    if r_layout.shape[1:-2] != ((D,) if D > 1 else ()):
+        raise ValueError(
+            f"reference layout {tuple(r_layout.shape)} does not match the "
+            f"plan's {D} feature(s)")
+    R, w, L = r_layout.shape[0], *r_layout.shape[-2:]
     if len(extras) != len(plan.extra_inputs):
         raise ValueError(
             f"family {plan.family!r} plans take extra operands "
@@ -1033,10 +1115,10 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
             f"reference layout {tuple(r_layout.shape)} does not match "
             f"the plan (segment_width={plan.segment_width}, "
             f"num_ref_blocks={plan.num_ref_blocks})")
-    if Mp != query_pack_len(plan.m):
+    if Mp != query_pack_len(plan.m, D):
         raise ValueError(
-            f"query pack length {Mp} != query_pack_len(m) = "
-            f"{query_pack_len(plan.m)} (m={plan.m})")
+            f"query pack length {Mp} != query_pack_len(m, features) = "
+            f"{query_pack_len(plan.m, D)} (m={plan.m}, features={D})")
 
     if interpret is None:
         from repro.kernels.ops import default_interpret  # imports us
@@ -1065,7 +1147,9 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
         pl.BlockSpec((1, rows, Mp), lambda b, r: (b, 0, 0)),
         # grid step r reads layout block r + offset (reverse band-skip
         # grids start past the leading out-of-band flipped blocks)
-        pl.BlockSpec((1, w, LANES), lambda b, r: (r + off, 0, 0)),
+        pl.BlockSpec((1, w, LANES), lambda b, r: (r + off, 0, 0))
+        if D == 1 else
+        pl.BlockSpec((1, D, w, LANES), lambda b, r: (r + off, 0, 0, 0)),
     ]
     for name, arr in zip(plan.extra_inputs, extras):
         if _EXTRA_KIND[name] == "r":

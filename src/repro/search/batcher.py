@@ -24,6 +24,7 @@ import dataclasses
 
 import jax.numpy as jnp
 
+from repro.core.spec import require_univariate
 from repro.kernels.sdtw_wavefront import SUBLANES
 
 
@@ -72,8 +73,7 @@ class QueryBatcher:
         """Queue one query; returns the batches this fill completed
         (empty list until a bucket reaches max_slots)."""
         series = jnp.asarray(series)
-        if series.ndim != 1:
-            raise ValueError(f"query {qid!r} must be 1-D, got {series.shape}")
+        require_univariate(series, f"query {qid!r}")
         if series.shape[0] == 0:
             raise ValueError(f"query {qid!r} is empty")
         length = int(series.shape[0])

@@ -28,7 +28,7 @@ from typing import Iterable
 import jax.numpy as jnp
 
 from repro.core.normalize import normalize_batch
-from repro.core.spec import DEFAULT_SPEC, DPSpec
+from repro.core.spec import DEFAULT_SPEC, DPSpec, require_univariate
 from repro.kernels import ops as _ops
 
 
@@ -64,9 +64,7 @@ class ReferenceIndex:
     # ------------------------------------------------------------ build
     def add(self, name: str, series) -> RefEntry:
         series = jnp.asarray(series)
-        if series.ndim != 1:
-            raise ValueError(
-                f"reference {name!r} must be 1-D, got shape {series.shape}")
+        require_univariate(series, f"reference {name!r}")
         if series.shape[0] == 0:
             raise ValueError(f"reference {name!r} is empty")
         if name in self._refs:

@@ -52,6 +52,7 @@ from concurrent.futures import Future
 import jax.numpy as jnp
 
 from repro import obs
+from repro.core.spec import require_univariate
 from repro.search.batcher import QueryBatcher, grid_size
 from repro.search.index import ReferenceIndex
 from repro.search.service import Match, SearchConfig
@@ -165,9 +166,9 @@ class StreamServer:
         those are the only two server-side reasons a request does not
         get a future."""
         q = jnp.asarray(query)
-        if q.ndim != 1 or q.shape[0] == 0:
-            raise ValueError(f"query must be a non-empty 1-D series, "
-                             f"got shape {q.shape}")
+        require_univariate(q, "query")
+        if q.shape[0] == 0:
+            raise ValueError("query must be a non-empty 1-D series")
         if k < 1:
             raise ValueError(f"k must be >= 1, got {k}")
         if deadline_ms is None:

@@ -164,7 +164,7 @@ def test_session_capability_errors(data):
     with pytest.raises(ValueError, match="unknown output"):
         a(q, outputs=("cost", "bogus"))
     with pytest.raises(ValueError, match="1-D"):
-        repro.Aligner(np.zeros((2, 8), np.float32))
+        repro.Aligner(np.zeros((2, 8, 3), np.float32))
     with pytest.raises(ValueError, match="empty"):
         repro.Aligner(np.zeros((0,), np.float32))
 

@@ -127,3 +127,23 @@ def test_normalizer_compiles(one_chip, shape):
         _sds(shape, one_chip)).compile()
     text = _assert_kernel(compiled, "sdtw_normalizer")
     assert "wavefront" not in text
+
+
+def test_multivariate_session_program_compiles(one_chip):
+    """The session's whole kernel program of ``sws2013_qbe``: 32 queries
+    of 100 frames x 39 features normalized, packed and swept against a
+    7,200,000-frame archive, whose layout is an argument."""
+    from repro.core.session import _kernel_program
+    from repro.core.spec import DPSpec
+    n, d = 7_200_000, 39
+    blocks = ops.ceil_to(n, LANES * W) // (LANES * W)
+    compiled = _kernel_program.lower(
+        _sds((32, 100, d), one_chip), _sds((blocks, d, W, LANES), one_chip),
+        (), spec=DPSpec(), n=n, segment_width=W, interpret=False,
+        sweep=frozenset({"cost", "end"}), normalize=True).compile()
+    text = _assert_kernel(compiled, "sdtw_wavefront")
+    assert _rows_per_step(text, "sdtw_wavefront") == ["16"]
+    # the 1.12 GB layout is a parameter, never a constant
+    assert f"f32[{blocks},{d},{W},{LANES}]" in text
+    assert "constant" not in "".join(
+        ln for ln in text.splitlines() if f"f32[{blocks},{d}" in ln)

@@ -13,7 +13,7 @@ from repro.kernels import ops
 from repro.kernels.wavefront import KernelPlan
 
 KEYS = ("dispatches", "wide_dispatches", "grid_steps", "loop_steps",
-        "lane_cells", "cells_real")
+        "lane_cells", "cells_real", "feature_cells")
 
 
 def counters() -> dict:
@@ -36,16 +36,19 @@ def plus(before: dict, *works) -> dict:
     # the paper's batch: 64 groups x 98 blocks, 2,000 + 127 steps each
     (DPSpec(), 512, 2_000, 100_000, 8,
      {"rows_per_step": 8, "grid_steps": 6_272, "loop_steps": 13_340_544,
-      "lane_cells": 109_285_736_448, "cells_real": 102_400_000_000}),
+      "lane_cells": 109_285_736_448, "cells_real": 102_400_000_000,
+      "feature_cells": 102_400_000_000}),
     # 13 queries fill 2 groups; 1,000 columns pad to 2 blocks of 512
     (DPSpec(), 13, 20, 1_000, 4,
      {"rows_per_step": 8, "grid_steps": 4, "loop_steps": 4 * 147,
-      "lane_cells": 4 * 147 * 8 * 512, "cells_real": 13 * 20 * 1_000}),
+      "lane_cells": 4 * 147 * 8 * 512, "cells_real": 13 * 20 * 1_000,
+      "feature_cells": 13 * 20 * 1_000}),
     # band 100 at m = 200 keeps columns up to 298: 2 of 20 blocks of
     # 256 run, and only their 512 columns hold real cells
     (DPSpec(band=100), 8, 200, 5_000, 2,
      {"rows_per_step": 8, "grid_steps": 2, "loop_steps": 2 * 327,
-      "lane_cells": 2 * 327 * 8 * 256, "cells_real": 8 * 200 * 512}),
+      "lane_cells": 2 * 327 * 8 * 256, "cells_real": 8 * 200 * 512,
+      "feature_cells": 8 * 200 * 512}),
 ])
 def test_plan_work_by_hand(spec, batch, m, n, w, want):
     plan = ops.kernel_plan(spec, m=m, n=n, segment_width=w)
@@ -60,20 +63,24 @@ def test_plan_work_by_hand(spec, batch, m, n, w, want):
     # 98 blocks, the same lane-cells as one group a step
     (DPSpec(), 512, 2_000, 100_000, 8,
      {"rows_per_step": 16, "grid_steps": 3_136, "loop_steps": 6_670_272,
-      "lane_cells": 109_285_736_448, "cells_real": 102_400_000_000}),
+      "lane_cells": 109_285_736_448, "cells_real": 102_400_000_000,
+      "feature_cells": 102_400_000_000}),
     # 24 queries fill 3 groups: the pad group makes 2 steps of 16 rows,
     # and its lane-cells count
     (DPSpec(), 24, 20, 1_000, 4,
      {"rows_per_step": 16, "grid_steps": 4, "loop_steps": 4 * 147,
-      "lane_cells": 4 * 147 * 16 * 512, "cells_real": 24 * 20 * 1_000}),
+      "lane_cells": 4 * 147 * 16 * 512, "cells_real": 24 * 20 * 1_000,
+      "feature_cells": 24 * 20 * 1_000}),
     # 9 queries fill 2 groups, 7 rows of the second padding
     (DPSpec(band=100), 9, 200, 5_000, 2,
      {"rows_per_step": 16, "grid_steps": 2, "loop_steps": 2 * 327,
-      "lane_cells": 2 * 327 * 16 * 256, "cells_real": 9 * 200 * 512}),
+      "lane_cells": 2 * 327 * 16 * 256, "cells_real": 9 * 200 * 512,
+      "feature_cells": 9 * 200 * 512}),
     # one group never takes the wide step
     (DPSpec(), 8, 20, 1_000, 4,
      {"rows_per_step": 8, "grid_steps": 2, "loop_steps": 2 * 147,
-      "lane_cells": 2 * 147 * 8 * 512, "cells_real": 8 * 20 * 1_000}),
+      "lane_cells": 2 * 147 * 8 * 512, "cells_real": 8 * 20 * 1_000,
+      "feature_cells": 8 * 20 * 1_000}),
 ])
 def test_batch_plan_work_by_hand(spec, batch, m, n, w, want):
     plan = ops.kernel_plan(spec, m=m, n=n, segment_width=w, batch=batch)
