@@ -415,7 +415,7 @@ class Aligner:
             norm = self.normalize
             work = _ops.wavefront_work(spec, batch=batch_shape[0],
                                        m=batch_shape[1], n=self.length,
-                                       segment_width=w)
+                                       segment_width=w, checkpoint=True)
             # the checkpointed forward and the reverse sweep execute
             # the same blocks over the same real columns
             works = () if work is None else (work, work)

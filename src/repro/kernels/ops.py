@@ -31,9 +31,10 @@ from repro.core.spec import (DEFAULT_SPEC, NO_WINDOW,  # noqa: F401
 # PAD_VALUE re-exported: cost >= (q - 1e6)^2 never wins — the dtype
 # rationale (and why it rules out cosine) lives with the other
 # sentinels in core/spec.py.
-from repro.kernels.wavefront import (LANES, SUBLANES, KernelPlan,
-                                     build_plan, feature_stride,
-                                     query_pack_len, wavefront_call)
+from repro.kernels.wavefront import (CHAIN_LANES, LANES, SUBLANES,
+                                     KernelPlan, build_plan,
+                                     feature_stride, query_pack_len,
+                                     wavefront_call)
 from repro.kernels.normalizer import normalizer_pallas
 
 
@@ -210,26 +211,65 @@ def layout_features(r_layout) -> int:
     return int(r_layout.shape[1]) if r_layout.ndim == 4 else 1
 
 
+# A chained plan's serial step ran up to 3.4% longer than the one-chain
+# step on a v5e (PERF.md section 6), so chains are taken only
+# where they cut the steps by at least this share.
+CHAIN_STEP_GROWTH = 0.04
+# VMEM a chained plan may hold (KernelPlan.vmem_bytes): an eighth of a
+# v5e's 128 MiB.  A chain more grows the query block and the strip
+# about as many times; a one-chain plan keeps what it needs.
+CHAIN_VMEM_BUDGET = 16 * 2 ** 20
+
+
 def plan_rows(plan: KernelPlan, batch: int) -> KernelPlan:
     """``plan`` with the queries per serial step a dispatch of ``batch``
-    queries runs: two packed groups (``2 * SUBLANES``) whenever the
-    batch fills at least two groups, else one.  Every query's
-    arithmetic is the same either way; the wider step pays the serial
-    step's fixed latency once for twice the queries."""
-    return dataclasses.replace(
-        plan, rows_per_step=2 * SUBLANES if batch > SUBLANES else SUBLANES)
+    queries runs, as rows a chain and chains a step.
+
+    Rows: two packed groups (``2 * SUBLANES``) whenever the batch fills
+    at least two groups, else one.  Chains: the most (2, 4 or 8, each
+    of at least 16 lanes) such that
+
+      * the batch fills them: ``rows x chains`` is within the batch
+        rounded up to the rows, so no step carries a chain of pad
+        queries;
+      * they pay: a chain of L lanes fills and drains in L - 1 steps
+        of each sub-block of ``m + L - 1``, where one chain of LANES
+        pays LANES - 1 in each block, and the ratio of the steps,
+        ``(m + LANES - 1) / (m + L - 1)``, must reach ``1 +
+        CHAIN_STEP_GROWTH``, so long queries keep one chain;
+      * they fit: :meth:`KernelPlan.vmem_bytes` within
+        ``CHAIN_VMEM_BUDGET``;
+
+    else one.  Reverse and checkpoint sweeps keep one.  Every query's
+    arithmetic is the same at any rows and chains."""
+    rows = 2 * SUBLANES if batch > SUBLANES else SUBLANES
+    plan = dataclasses.replace(plan, rows_per_step=rows,
+                               lanes_per_chain=LANES)
+    if plan.reverse or plan.checkpoint:
+        return plan
+    for lanes in CHAIN_LANES[:-1]:                  # the most chains first
+        chained = dataclasses.replace(plan, lanes_per_chain=lanes)
+        if (chained.queries_per_step <= ceil_to(batch, rows)
+                and plan.m + LANES - 1 >= (1 + CHAIN_STEP_GROWTH)
+                * (plan.m + lanes - 1)
+                and chained.vmem_bytes() <= CHAIN_VMEM_BUDGET):
+            return chained
+    return plan
 
 
 def kernel_plan(spec: DPSpec | None = None, *, m: int, n: int,
                 segment_width: int = 8, compute_dtype=jnp.float32,
                 with_window: bool = False,
-                batch: int | None = None, features: int = 1) -> KernelPlan:
+                batch: int | None = None, features: int = 1,
+                checkpoint: bool = False) -> KernelPlan:
     """The :class:`~repro.kernels.wavefront.KernelPlan` a dispatch of
     these (unpadded) shapes executes — band-skip geometry included, so
     callers (search stats, benchmarks) can read ``plan.grid_blocks``
     vs ``plan.num_ref_blocks`` without running the kernel.  Given the
-    ``batch``, the plan also carries the rows per step that batch
-    runs (:func:`plan_rows`); without it, one group a step."""
+    ``batch``, the plan also carries the rows and chains a step that
+    batch runs (:func:`plan_rows`); without it, one group a step in
+    one chain.  ``checkpoint``: the fused soft backward's forward
+    sweep, which keeps one chain."""
     sp = DEFAULT_SPEC if spec is None else spec
     blocks = ceil_to(n, LANES * segment_width) // (LANES * segment_width)
     plan = build_plan(sp, m=m,
@@ -237,6 +277,8 @@ def kernel_plan(spec: DPSpec | None = None, *, m: int, n: int,
                       compute_dtype=compute_dtype, with_window=with_window,
                       n=n if sp.family != "sdtw" else None,
                       features=features)
+    if checkpoint:
+        plan = dataclasses.replace(plan, checkpoint=True)
     return plan if batch is None else plan_rows(plan, batch)
 
 
@@ -257,16 +299,19 @@ def band_blocks_all(spec: DPSpec, m: int, n: int) -> bool:
 
 def wavefront_work(spec: DPSpec | None = None, *, batch: int, m: int,
                    n: int, segment_width: int = 8,
-                   features: int = 1) -> dict | None:
+                   features: int = 1,
+                   checkpoint: bool = False) -> dict | None:
     """:meth:`KernelPlan.work` of one dispatch of these (unpadded)
     shapes, or None where :func:`band_blocks_all` answers the call and
     no kernel runs.  The work does not depend on the compute dtype or
-    on the outputs asked for."""
+    on the outputs asked for; it does on ``checkpoint`` (the fused soft
+    backward's sweeps), which keeps one chain."""
     sp = DEFAULT_SPEC if spec is None else spec
     if band_blocks_all(sp, m, n):
         return None
     return kernel_plan(sp, m=m, n=n, segment_width=segment_width,
-                       batch=batch, features=features).work(batch, n)
+                       batch=batch, features=features,
+                       checkpoint=checkpoint).work(batch, n)
 
 
 _WORK_COUNTERS = tuple((k, f"kernel.wavefront.{k}") for k in
@@ -281,7 +326,9 @@ def count_wavefront(work: dict) -> None:
     ``.cells_real`` / ``.feature_cells`` (equal to ``.cells_real`` for
     univariate dispatches) of :func:`repro.obs.default_registry`, and to
     ``.wide_dispatches`` where the plan carried two query groups a
-    step (``wide_dispatches / dispatches``: how often that engages).
+    step, and to ``.chained_dispatches`` where it carried more than one
+    lane chain (``wide_dispatches / dispatches`` and
+    ``chained_dispatches / dispatches``: how often each engages).
 
     Process-wide because the chip and its kernels belong to the
     process, not to a session.  Call it on the host once per dispatch
@@ -291,6 +338,8 @@ def count_wavefront(work: dict) -> None:
     reg.inc("kernel.wavefront.dispatches")
     if work["rows_per_step"] > SUBLANES:
         reg.inc("kernel.wavefront.wide_dispatches")
+    if work["lanes_per_chain"] < LANES:
+        reg.inc("kernel.wavefront.chained_dispatches")
     for key, name in _WORK_COUNTERS:
         reg.inc(name, work[key])
 
@@ -410,8 +459,9 @@ def sdtw_wavefront_prepped(q_prepped: jnp.ndarray, r_layout: jnp.ndarray, *,
     see ``repro.kernels.wavefront``); Sakoe–Chiba specs automatically
     execute the band-skip plan — fewer grid steps, identical outputs
     (``kernel_plan(...)`` exposes the geometry); an operand of two or
-    more query groups runs two groups a serial step (:func:`plan_rows`),
-    again with identical outputs.
+    more query groups runs two groups a serial step, and one that
+    fills more lane chains runs them (:func:`plan_rows`), again with
+    identical outputs.
     """
     validate_prepped(q_prepped, r_layout, m=m, n=n,
                      segment_width=segment_width)

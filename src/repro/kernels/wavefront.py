@@ -39,7 +39,7 @@ DESIGN — mapping channels back to the paper's AMD/HIP mechanisms:
   * inter-wavefront shared-memory strip -> one VMEM scratch column PER
     CHANNEL carried across the (sequential) reference-block grid axis.
     Grid steps are sequential on TPU, so the read pointer (t+1) always
-    leads the write pointer (t-127) by LANES rows and ONE buffer per
+    leads the write pointer (t-(L-1)) by L rows and ONE buffer per
     channel suffices where the paper needed two (concurrent
     wavefronts).
   * ``__hmin2`` streaming min -> the stream folds: bottom-row
@@ -56,6 +56,18 @@ DESIGN — mapping channels back to the paper's AMD/HIP mechanisms:
     serial step's fixed latency (scalar window arithmetic, XLU round
     trips, the fold's VMEM load and store) is paid once for 16
     queries.  Each query's arithmetic is the same at either height.
+  * lane chains -> ``plan.lanes_per_chain`` (L) < LANES: the lanes
+    split into k = LANES / L independent chains, each with
+    ``rows_per_step`` queries of its own, and every reference block
+    runs as k sub-blocks of m + L - 1 steps in which lane c*L + p is
+    on row t - p of chain c's queries against reference lanes u*L + p
+    of sub-block u.  A short query then fills and drains the pipeline
+    in L - 1 steps a sub-block, not LANES - 1 a block.  Each sub-block
+    tiles its L reference lanes across the chains once
+    (:func:`_tile_chains`), each chain's lane 0 reads its own strip,
+    and the fold keeps one accumulator set per sub-block, so every
+    lane folds exactly the cells, in the order, of a one-chain plan and
+    the outputs agree with it bit for bit.
   * multivariate series -> ``plan.features`` > 1: each query row packs
     one reversed copy per feature (:func:`feature_stride` lanes apart)
     and each reference block holds a (D, w, LANES) tile; every step
@@ -69,7 +81,9 @@ reference blocks whose columns all satisfy that are never visited —
 steps, not just dead lanes), ~O(N / band) fewer steps for tight bands.
 Outputs are bit-for-bit identical to the masked full-grid kernel: a
 skipped block's cells are all masked to the big sentinel, which can
-never win a fold, and no later block reads its boundary strip.
+never win a fold, and no later block reads its boundary strip.  A
+chained plan keeps the block as the unit: the sub-blocks of a kept
+block that lie past the band run, masked.
 
 The DP cell recurrence and the subsequence boundary conditions
 (``D[-1, j] = 0``, ``D[i, -1] = +inf``) are identical to
@@ -95,6 +109,11 @@ from repro.core.spec import (KERNEL_BIG, NO_WINDOW, SOFT_BIG, DPSpec,
 LANES = 128          # TPU VPU lane count (the paper's wavefront width = 64)
 SUBLANES = 8         # queries per packed group (sublane packing); a
 #                      grid step carries one group or two
+
+CHAIN_LANES = (16, 32, 64, LANES)   # lanes a chain may span: no fewer
+#                                    than 16, since each chain more adds
+#                                    a sub-block's set-up (its reference
+#                                    tile, its carry) to every block
 
 _J_MAX = 2 ** 31 - 1   # lexicographic-min column sentinel (int32 max):
 #                        any real column index beats it, so it doubles
@@ -124,17 +143,36 @@ def query_pack_len(m: int, features: int = 1) -> int:
     return features * feature_stride(m)
 
 
-def feature_stride(m: int) -> int:
+def feature_stride(m: int, lanes_per_chain: int = LANES) -> int:
     """Lanes between the packs of consecutive features of a
     multivariate query row: one query pack rounded up to whole lane
     tiles, so that every feature's window lies at the same lane offset
-    of its tiles."""
-    return _ceil_to(m + 3 * LANES - 2, LANES)
+    of its tiles.  For chains of L < LANES lanes the pack is the
+    chains' packs of L-1 leading zeros, the reversed query and 2L-1
+    zeros, interleaved in L-lane pieces (:func:`step_pack_len`)."""
+    L = lanes_per_chain
+    return -(-(m + 3 * L - 2) // L) * LANES
 
 
-def strip_len(m: int) -> int:
-    """Lane width of a boundary strip: m rows, padded to whole tiles."""
-    return _ceil_to(m, LANES)
+def step_pack_len(m: int, features: int = 1,
+                  lanes_per_chain: int = LANES) -> int:
+    """Lane width of one query row as a grid step reads it.  At one
+    chain, the prepared row itself (:func:`query_pack_len`), whose
+    univariate length (not whole lane tiles) names m.  At k = LANES / L
+    chains, lane tile tau holds positions [tau L, (tau + 1) L) of each
+    chain's pack, chain c in lanes [c L, (c + 1) L): every window a step
+    reads then lies in two aligned tiles, at the same offset for every
+    chain."""
+    if lanes_per_chain == LANES:
+        return query_pack_len(m, features)
+    return features * feature_stride(m, lanes_per_chain)
+
+
+def strip_len(m: int, lanes_per_chain: int = LANES) -> int:
+    """Lane width of a boundary strip: m rows of each chain, in tiles
+    of L rows of every chain (at one chain, m rows padded to whole
+    tiles)."""
+    return -(-m // lanes_per_chain) * LANES
 
 
 def _ceil_to(x: int, k: int) -> int:
@@ -148,18 +186,37 @@ def _aligned(col):
     return pl.multiple_of(col - off, LANES), off
 
 
-def _lane_window(ref, start, lane):
-    """``ref[0, :, start:start + LANES]`` at a dynamic, unaligned start:
-    the two aligned lane tiles covering it, each rotated by the same
-    amount and spliced at the tile boundary."""
-    base, off = _aligned(start)
-    shift = lax.rem(LANES - off, LANES)
-    lo = pltpu.roll(ref[0, :, pl.ds(base, LANES)], shift, 1)
-    hi = pltpu.roll(ref[0, :, pl.ds(base + LANES, LANES)], shift, 1)
-    return jnp.where(lane < LANES - off, lo, hi)
+def _window_at(start, lanes_per_chain):
+    """Where the L-lane window of every chain starting at position
+    ``start`` of its pack lies, packed as :func:`step_pack_len` packs
+    it: (the first of its two aligned lane tiles, the offset into it,
+    the rotation of the first tile, the rotation of the second).  Lane
+    p of a chain reads the first tile, rotated, while p < L - offset,
+    and the second after."""
+    L = lanes_per_chain
+    if L == LANES:      # one rotation amount, and no division for the
+        #                 base: three scalar ops fewer a window
+        base, off = _aligned(start)
+        shift = lax.rem(LANES - off, LANES)
+        return base, off, shift, shift
+    off = lax.rem(start, L)
+    base = pl.multiple_of(lax.div(start, L) * LANES, LANES)
+    return base, off, lax.rem(LANES - off, LANES), L - off
 
 
-def _feature_costs(plan, q_ref, r_ref, t, lane):
+def _lane_window(ref, start, p, lanes_per_chain=LANES):
+    """``ref[0, :, start:start + L]`` of every chain at a dynamic,
+    unaligned start (``p``: each lane's place in its chain): the two
+    aligned lane tiles covering it, each rotated into place and
+    spliced where the first ends."""
+    L = lanes_per_chain
+    base, off, lo_shift, hi_shift = _window_at(start, L)
+    lo = pltpu.roll(ref[0, :, pl.ds(base, LANES)], lo_shift, 1)
+    hi = pltpu.roll(ref[0, :, pl.ds(base + LANES, LANES)], hi_shift, 1)
+    return jnp.where(p < L - off, lo, hi)
+
+
+def _feature_costs(plan, q_ref, r_ref, t, p):
     """The w cell costs of every lane at step ``t`` of a multivariate
     plan, one (rows, LANES) array per segment slot: for each feature,
     its query window (read as :func:`_lane_window` reads one) against
@@ -168,20 +225,51 @@ def _feature_costs(plan, q_ref, r_ref, t, lane):
     windows share one lane offset (:func:`feature_stride`), so the
     offset is worked out once a step."""
     spec, cdt = plan.spec, plan.compute_dtype
-    stride = feature_stride(plan.m)
-    base, off = _aligned(plan.m - 1 + LANES - 1 - t)
-    shift = lax.rem(LANES - off, LANES)
-    keep = lane < LANES - off
+    L = plan.lanes_per_chain
+    stride = feature_stride(plan.m, L)
+    base, off, lo_shift, hi_shift = _window_at(plan.m - 1 + L - 1 - t, L)
+    keep = p < L - off
     costs = [None] * plan.segment_width
     for d in range(plan.features):
         at = pl.multiple_of(base + d * stride, LANES)
-        lo = pltpu.roll(q_ref[0, :, pl.ds(at, LANES)], shift, 1)
-        hi = pltpu.roll(q_ref[0, :, pl.ds(at + LANES, LANES)], shift, 1)
+        lo = pltpu.roll(q_ref[0, :, pl.ds(at, LANES)], lo_shift, 1)
+        hi = pltpu.roll(q_ref[0, :, pl.ds(at + LANES, LANES)], hi_shift, 1)
         qd = jnp.where(keep, lo, hi).astype(cdt)
         for k in range(plan.segment_width):
             term = spec.cell_cost(qd, r_ref[0, d, k:k + 1, :].astype(cdt))
             costs[k] = term if d == 0 else costs[k] + term
     return costs
+
+
+def _tile_chains(x, u, lanes_per_chain):
+    """``x`` (..., LANES) with its lanes [u L, (u + 1) L) copied into
+    every chain's L lanes: one rotation brings them to lanes [0, L),
+    then each doubling rotates the filled lanes up by as many."""
+    L, axis = lanes_per_chain, x.ndim - 1
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    y = pltpu.roll(x, lax.rem(LANES - u * L, LANES), axis)
+    filled = L
+    while filled < LANES:
+        y = jnp.where(lane < filled, y, pltpu.roll(y, filled, axis))
+        filled *= 2
+    return y
+
+
+def _one_chain_lanes(acc_ref, c, lanes_per_chain, lane):
+    """Chain ``c``'s accumulators as a one-chain plan holds them: lane
+    u L + p of the result is lane c L + p of sub-block u's
+    accumulator, which saw exactly the cells, in the same order, that
+    lane u L + p of a one-chain plan sees."""
+    L = lanes_per_chain
+    out = None
+    for u in range(LANES // L):
+        x = acc_ref[u]
+        shift = (u - c) * L % LANES
+        if shift:
+            x = pltpu.roll(x, shift, 1)
+        out = x if out is None else jnp.where(
+            (lane >= u * L) & (lane < (u + 1) * L), x, out)
+    return out
 
 
 # ------------------------------------------------------------- channels
@@ -219,62 +307,71 @@ class CarryChannel:
             else self.strip_dtype
 
     # ------------------------------------------------------------ hooks
-    def init_carry(self, strip_ref, *, lane, rblk, w, compute_dtype):
+    def init_carry(self, strip_ref, *, p, entered, w, compute_dtype,
+                   lanes_per_chain=LANES):
         """(prev_row registers, left column, prev-left) at t = 0."""
         dt = self.reg_dtype(compute_dtype)
         edge = jnp.asarray(self.edge_init, dt)
-        prev0 = tuple(jnp.full(lane.shape, self.prev_init, dt)
+        prev0 = tuple(jnp.full(p.shape, self.prev_init, dt)
                       for _ in range(w))
-        # t=0: only lane 0 is active (row 0); its left column is the
-        # previous block's strip (block > 0) or the edge sentinel
-        strip0 = self.read_strip(strip_ref, 0, compute_dtype=compute_dtype)
-        left0 = jnp.where(lane == 0,
-                          jnp.where(rblk > 0, strip0, edge), edge)
-        prev_left0 = jnp.full(lane.shape, self.edge_init, dt)
+        # t=0: only each chain's lane 0 is active (row 0); its left
+        # column is the previous sub-block's strip (``entered``: one
+        # ran before in this batch step) or the edge sentinel
+        strip0 = self.read_strip(strip_ref, 0, compute_dtype=compute_dtype,
+                                 lanes_per_chain=lanes_per_chain)
+        left0 = jnp.where(p == 0,
+                          jnp.where(entered, strip0, edge), edge)
+        prev_left0 = jnp.full(p.shape, self.edge_init, dt)
         return (prev0, left0, prev_left0)
 
-    def roll_carry(self, last, *, lane, strip_val, use_strip,
+    def roll_carry(self, last, *, p, strip_val, use_strip,
                    compute_dtype):
         """``__shfl_up`` analogue: the neighbour lane's last cell
-        becomes my left value; lane 0 reads the previous block's
-        boundary strip (or the edge sentinel past the query)."""
+        becomes my left value; each chain's lane 0 reads the previous
+        sub-block's boundary strip (or the edge sentinel past the
+        query) in place of the lane below it, which is another
+        chain's."""
         dt = self.reg_dtype(compute_dtype)
         rolled = pltpu.roll(last, 1, 1)
         lane0 = jnp.where(use_strip, strip_val,
                           jnp.asarray(self.edge_init, dt))
-        return jnp.where(lane == 0, lane0, rolled)
+        return jnp.where(p == 0, lane0, rolled)
 
-    def read_strip(self, strip_ref, t, *, compute_dtype):
-        """Strip row ``t`` for every query, in lane 0 (the only lane
-        that reads it): the aligned lane tile holding column t, rotated
-        so that column lands in lane 0."""
-        base, off = _aligned(t)
+    def read_strip(self, strip_ref, t, *, compute_dtype,
+                   lanes_per_chain=LANES):
+        """Strip row ``t`` of every chain's queries, in each chain's
+        lane 0 (the only lane that reads it): the aligned lane tile
+        holding row t of every chain, rotated so that each chain's
+        row lands in its lane 0."""
+        base, _, shift, _ = _window_at(t, lanes_per_chain)
         tile = strip_ref[:, pl.ds(base, LANES)]
-        return pltpu.roll(tile, lax.rem(LANES - off, LANES), 1) \
+        return pltpu.roll(tile, shift, 1) \
             .astype(self.reg_dtype(compute_dtype))
 
-    def write_strip(self, strip_ref, i, last, *, lane):
-        """Publish the channel's right column (lane LANES-1) as strip
-        row ``i`` for the next reference block: a read-modify-write of
-        the aligned lane tile holding column i."""
-        base, off = _aligned(i)
-        col = pltpu.roll(last, lax.rem(off + 1, LANES), 1)  # lane off
+    def write_strip(self, strip_ref, i, last, *, p, lanes_per_chain=LANES):
+        """Publish the channel's right column (each chain's lane L-1)
+        as strip row ``i`` for the next sub-block: a read-modify-write
+        of the aligned lane tile holding row i of every chain."""
+        L = lanes_per_chain
+        base, off, _, _ = _window_at(i, L)
+        # lane c L + L - 1 -> lane c L + off
+        col = pltpu.roll(last, lax.rem(off + (1 + LANES - L), LANES), 1)
         tile = strip_ref[:, pl.ds(base, LANES)]
         strip_ref[:, pl.ds(base, LANES)] = jnp.where(
-            lane == off, col.astype(self.strip_dtype), tile)
+            p == off, col.astype(self.strip_dtype), tile)
 
-    def strip_shape(self, m: int, rows: int):
-        return pltpu.VMEM((rows, strip_len(m)), self.strip_dtype)
+    def strip_shape(self, m: int, rows: int, lanes_per_chain: int = LANES):
+        return pltpu.VMEM((rows, strip_len(m, lanes_per_chain)),
+                          self.strip_dtype)
 
 
 # ---------------------------------------------------------------- folds
-def _write(out_ref, col):
-    """Store a per-query (S, 1) result as the output block's lane-dense
-    (S, LANES) tile (TPU blocks are whole (8, 128) tiles)."""
-    out_ref[0] = jnp.broadcast_to(col, out_ref.shape[1:]).astype(
-        out_ref.dtype)
-
-
+#
+# A fold keeps per-lane accumulators in VMEM (``accumulators()`` gives
+# their dtypes; the executor allocates them), folds each step's cells
+# into them (``update``, on the refs) and, once a batch step's last
+# reference block has run, reduces them across lanes (``finalize``, on
+# their (S, LANES) values) to one (S, 1) column per output.
 def _fill(ref, value):
     ref[...] = jnp.full(ref.shape, value, ref.dtype)
 
@@ -286,12 +383,8 @@ class MinArgminFold:
 
     with_window: bool = False
 
-    def scratch_shapes(self, rows):
-        shapes = [pltpu.VMEM((rows, LANES), jnp.float32),   # min
-                  pltpu.VMEM((rows, LANES), jnp.int32)]     # argmin
-        if self.with_window:
-            shapes.append(pltpu.VMEM((rows, LANES), jnp.int32))
-        return shapes
+    def accumulators(self):
+        return (jnp.float32, jnp.int32) + (jnp.int32,) * self.with_window
 
     def init(self, scr):
         _fill(scr[0], KERNEL_BIG)
@@ -324,24 +417,22 @@ class MinArgminFold:
         if self.with_window:
             scr[2][...] = jnp.where(take, best_s, scr[2][...])
 
-    def _cross_lane(self, scr):
+    def _cross_lane(self, accs):
         """(min, its earliest column, and the lane-hit mask) per query,
         each (S, 1): a where/min reduce across lanes — every column
         belongs to exactly one lane, so the hit lane is unique."""
-        mv = scr[0][...]                                  # (S, L) f32
+        mv, cols = accs[0], accs[1]                       # (S, L)
         best = jnp.min(mv, axis=1, keepdims=True)
-        cols = scr[1][...]
         end = jnp.min(jnp.where(mv == best, cols, _J_MAX), axis=1,
                       keepdims=True)
         return best, end, (mv == best) & (cols == end)
 
-    def finalize(self, scr, outs, plan):
-        best, end, hit = self._cross_lane(scr)
-        _write(outs[0], best)
-        _write(outs[1], end)
-        if self.with_window:
-            _write(outs[2], jnp.min(jnp.where(hit, scr[2][...], _J_MAX),
-                                    axis=1, keepdims=True))
+    def finalize(self, accs, plan):
+        best, end, hit = self._cross_lane(accs)
+        if not self.with_window:
+            return [best, end]
+        return [best, end, jnp.min(jnp.where(hit, accs[2], _J_MAX),
+                                   axis=1, keepdims=True)]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -358,10 +449,10 @@ class SoftMinFold:
     detection (all bottom cells masked -> +inf, engine parity).
     """
 
-    def scratch_shapes(self, rows):
-        return MinArgminFold().scratch_shapes(rows) + [
-            pltpu.VMEM((rows, LANES), jnp.float32),   # running max m
-            pltpu.VMEM((rows, LANES), jnp.float32)]   # scaled sum s
+    def accumulators(self):
+        # hard twin (min, argmin), then the running max m and the
+        # scaled sum s
+        return MinArgminFold().accumulators() + (jnp.float32, jnp.float32)
 
     def init(self, scr):
         MinArgminFold().init(scr[:2])
@@ -389,9 +480,9 @@ class SoftMinFold:
         scr[2][...] = jnp.where(at_bottom, m_safe, m_run)
         scr[3][...] = jnp.where(at_bottom, s_new, s_run)
 
-    def finalize(self, scr, outs, plan):
-        best, idx, _ = MinArgminFold()._cross_lane(scr[:2])
-        m_l, s_l = scr[2][...], scr[3][...]               # (S, L)
+    def finalize(self, accs, plan):
+        best, idx, _ = MinArgminFold()._cross_lane(accs[:2])
+        m_l, s_l = accs[2], accs[3]                       # (S, L)
         m_g = jnp.max(m_l, axis=1, keepdims=True)         # (S, 1)
         s_g = jnp.sum(s_l * soft_exp(m_l - m_g), axis=1, keepdims=True)
         cost = -plan.spec.gamma * (m_g + soft_log(s_g))
@@ -401,9 +492,8 @@ class SoftMinFold:
         # finite ~1e12 << SOFT_BIG/2: the kernel's long-standing
         # blocked-band-with-reachable-padding semantics, see ops.py.)
         blocked = best >= jnp.asarray(SOFT_BIG / 2, jnp.float32)
-        _write(outs[0], jnp.where(blocked,
-                                  jnp.asarray(jnp.inf, jnp.float32), cost))
-        _write(outs[1], idx)
+        return [jnp.where(blocked, jnp.asarray(jnp.inf, jnp.float32), cost),
+                idx]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -420,8 +510,8 @@ class CornerFold:
     flows strictly left-to-right, so cell (m-1, n-1) never reads them.
     """
 
-    def scratch_shapes(self, rows):
-        return [pltpu.VMEM((rows, LANES), jnp.float32)]
+    def accumulators(self):
+        return (jnp.float32,)
 
     def init(self, scr):
         _fill(scr[0], KERNEL_BIG)
@@ -433,14 +523,14 @@ class CornerFold:
             acc = jnp.where(hit, rows["cost"][k].astype(jnp.float32), acc)
         scr[0][...] = acc
 
-    def finalize(self, scr, outs, plan):
+    def finalize(self, accs, plan):
         # exactly one lane ever wrote the corner; min() selects it
-        corner = jnp.min(scr[0][...], axis=1, keepdims=True)  # (S, 1)
+        corner = jnp.min(accs[0], axis=1, keepdims=True)  # (S, 1)
         blocked = corner >= jnp.asarray(plan.big / 2, jnp.float32)
-        _write(outs[0], jnp.where(
-            blocked, jnp.asarray(jnp.inf, jnp.float32), corner))
-        _write(outs[1], jnp.where(blocked, jnp.asarray(0, jnp.int32),
-                                  jnp.asarray(plan.n - 1, jnp.int32)))
+        return [jnp.where(blocked, jnp.asarray(jnp.inf, jnp.float32),
+                          corner),
+                jnp.where(blocked, jnp.asarray(0, jnp.int32),
+                          jnp.asarray(plan.n - 1, jnp.int32))]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -458,9 +548,8 @@ class LocalCellsFold:
     ``v < big/2`` guard.
     """
 
-    def scratch_shapes(self, rows):
-        return [pltpu.VMEM((rows, LANES), jnp.float32),   # lex value
-                pltpu.VMEM((rows, LANES), jnp.int32)]     # lex column
+    def accumulators(self):
+        return (jnp.float32, jnp.int32)                   # lex value, column
 
     def init(self, scr):
         _fill(scr[0], KERNEL_BIG)
@@ -479,16 +568,14 @@ class LocalCellsFold:
         scr[0][...] = bv
         scr[1][...] = bj
 
-    def _cross_lane(self, scr):
-        mv = scr[0][...]                                      # (S, L)
+    def _cross_lane(self, accs):
+        mv = accs[0]                                          # (S, L)
         best = jnp.min(mv, axis=1, keepdims=True)             # (S, 1)
-        js = jnp.where(mv == best, scr[1][...], _J_MAX)
+        js = jnp.where(mv == best, accs[1], _J_MAX)
         return best, jnp.min(js, axis=1, keepdims=True)
 
-    def finalize(self, scr, outs, plan):
-        best, end = self._cross_lane(scr)
-        _write(outs[0], best)
-        _write(outs[1], end)
+    def finalize(self, accs, plan):
+        return list(self._cross_lane(accs))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -507,10 +594,9 @@ class SoftCellsFold:
     engine's masked diagonals.
     """
 
-    def scratch_shapes(self, rows):
-        return LocalCellsFold().scratch_shapes(rows) + [
-            pltpu.VMEM((rows, LANES), jnp.float32),   # running max m
-            pltpu.VMEM((rows, LANES), jnp.float32)]   # scaled sum s
+    def accumulators(self):
+        # lex twin, then the running max m and the scaled sum s
+        return LocalCellsFold().accumulators() + (jnp.float32, jnp.float32)
 
     def init(self, scr):
         LocalCellsFold().init(scr[:2])
@@ -539,13 +625,12 @@ class SoftCellsFold:
         scr[2][...] = m_safe
         scr[3][...] = s_run * soft_exp(m_run - m_safe) + add
 
-    def finalize(self, scr, outs, plan):
-        _, end = LocalCellsFold()._cross_lane(scr[:2])
-        m_l, s_l = scr[2][...], scr[3][...]                   # (S, L)
+    def finalize(self, accs, plan):
+        _, end = LocalCellsFold()._cross_lane(accs[:2])
+        m_l, s_l = accs[2], accs[3]                           # (S, L)
         m_g = jnp.max(m_l, axis=1, keepdims=True)             # (S, 1)
         s_g = jnp.sum(s_l * soft_exp(m_l - m_g), axis=1, keepdims=True)
-        _write(outs[0], -plan.spec.gamma * (m_g + soft_log(s_g)))
-        _write(outs[1], end)
+        return [-plan.spec.gamma * (m_g + soft_log(s_g)), end]
 
 
 # ----------------------------------------------------------------- plan
@@ -596,10 +681,26 @@ class KernelPlan:
     #                              Every plan kind gains from two: its
     #                              loop body at 16 rows is under twice
     #                              its body at 8 (PERF.md section 5)
+    lanes_per_chain: int = LANES  # L: lanes one dependency chain spans.
+    #                              k = LANES / L chains a step, each
+    #                              with rows_per_step queries of its
+    #                              own; a reference block runs as k
+    #                              sub-blocks of m + L - 1 steps, so a
+    #                              short query fills L - 1 steps, not
+    #                              LANES - 1 (ops.plan_rows)
 
     def __post_init__(self):
         if self.features < 1:
             raise ValueError(f"features must be >= 1, got {self.features}")
+        if self.lanes_per_chain not in CHAIN_LANES:
+            raise ValueError(
+                f"lanes_per_chain is one of {CHAIN_LANES}, got "
+                f"{self.lanes_per_chain}")
+        if self.chains > 1 and (self.reverse or self.checkpoint):
+            raise ValueError(
+                "reverse and checkpoint sweeps run one chain: the fused "
+                "soft backward's residual is one strip per reference "
+                f"block (got lanes_per_chain={self.lanes_per_chain})")
         if self.features > 1 and (self.spec.family != "sdtw"
                                   or self.reverse or self.checkpoint):
             raise ValueError(
@@ -661,6 +762,16 @@ class KernelPlan:
     @property
     def compute_dtype(self):
         return jnp.dtype(self.compute_dtype_name)
+
+    @property
+    def chains(self) -> int:
+        """Independent lane chains a serial step carries."""
+        return LANES // self.lanes_per_chain
+
+    @property
+    def queries_per_step(self) -> int:
+        """Queries one grid step carries: rows x chains."""
+        return self.rows_per_step * self.chains
 
     @property
     def big(self) -> float:
@@ -776,14 +887,17 @@ class KernelPlan:
         """What one dispatch of this plan does for ``batch`` queries
         against a reference of true length ``n``, as plain integers:
 
-          * ``rows_per_step``: the plan's queries per serial step;
-          * ``grid_steps``: steps along the batch (query groups, two
-            at a time at 2 * SUBLANES rows, an odd count rounded up) x
+          * ``rows_per_step``: the plan's queries per serial step in
+            each chain, and ``lanes_per_chain`` (L): the lanes one
+            chain spans, LANES / L chains a step;
+          * ``grid_steps``: steps along the batch (``rows_per_step`` x
+            chains queries each, the last one's rest padded) x
             executed reference blocks;
-          * ``loop_steps``: serial wavefront steps, ``m + LANES - 1``
-            per grid step (the pipeline fills and drains every block);
+          * ``loop_steps``: serial wavefront steps, chains x
+            ``m + L - 1`` per grid step (the pipeline fills and drains
+            every sub-block of L lanes' columns);
           * ``lane_cells``: cells those steps update, ``rows_per_step``
-            x LANES x ``segment_width`` each, padding, pad group and
+            x LANES x ``segment_width`` each, padding, pad queries and
             pipeline fill included;
           * ``cells_real``: the real query x real column cells inside
             the executed blocks, never more than ``lane_cells``;
@@ -797,18 +911,40 @@ class KernelPlan:
             raise ValueError(
                 f"reference length n={n} does not fit the plan's "
                 f"{self.num_ref_blocks} blocks of {block_cols} columns")
-        rows = self.rows_per_step
-        grid_steps = _ceil_to(batch, rows) // rows * self.grid_blocks
-        loop_steps = grid_steps * (self.m + LANES - 1)
+        rows, per = self.rows_per_step, self.queries_per_step
+        grid_steps = _ceil_to(batch, per) // per * self.grid_blocks
+        loop_steps = grid_steps * self.chains * (
+            self.m + self.lanes_per_chain - 1)
         cells_real = batch * self.m * min(n, self.grid_blocks * block_cols)
         return {
             "rows_per_step": rows,
+            "lanes_per_chain": self.lanes_per_chain,
             "grid_steps": grid_steps,
             "loop_steps": loop_steps,
             "lane_cells": loop_steps * rows * block_cols,
             "cells_real": cells_real,
             "feature_cells": cells_real * self.features,
         }
+
+    def vmem_bytes(self) -> int:
+        """VMEM one grid step of this plan holds, counted at 4 bytes an
+        element: the query-laid operand blocks (:func:`step_pack_len`
+        lanes a row) and the reference-laid ones, each double-buffered,
+        the output blocks likewise, then the scratch: one boundary strip
+        per channel, the fold's accumulators for every chain and, in a
+        chained plan, each sub-block's reference-laid tiles.  A chained
+        plan's query block and strip are about ``chains`` times the
+        one-chain plan's."""
+        rows, L, D = self.rows_per_step, self.lanes_per_chain, self.features
+        kinds = [_EXTRA_KIND[name] for name in self.extra_inputs]
+        q_laid = (1 + kinds.count("q")) * rows * step_pack_len(self.m, D, L)
+        r_laid = (D + kinds.count("r")) * self.segment_width * LANES
+        out = (self.num_outputs - self.checkpoint) * rows * LANES \
+            + self.checkpoint * rows * strip_len(self.m)
+        scratch = len(self.channels) * rows * strip_len(self.m, L) \
+            + len(self.fold.accumulators()) * self.chains * rows * LANES \
+            + (self.chains > 1) * r_laid
+        return 4 * (2 * (q_laid + r_laid + out) + scratch)
 
     # ------------------------------------------------------------ cell
     def cell(self, qv, rv, *, is_row0, i_l, j_col, vals3, extras=None,
@@ -907,11 +1043,12 @@ def build_plan(spec: DPSpec, *, m: int, segment_width: int,
 
 # ------------------------------------------------------------- executor
 def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
-    """One (batch-group, reference-block) grid cell, assembled from the
+    """One (batch step, reference-block) grid cell, assembled from the
     plan's channels and fold.
 
     q_ref:  (1, rows, Mp)      reversed+padded queries (see ops.py),
-                               ``plan.rows_per_step`` of them; a
+                               ``plan.rows_per_step`` of each chain,
+                               packed as :func:`step_pack_len` says; a
                                multivariate plan's row holds one pack
                                per feature
     r_ref:  (1, w, LANES)      reference block,
@@ -919,23 +1056,34 @@ def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
                                (1, D, w, LANES) for D features
     refs:   ``plan.extra_inputs`` family operand refs (laid out like
             q_ref or r_ref per ``_EXTRA_KIND``), then plan.num_outputs
-            output refs, one boundary strip per channel, then the
-            fold's scratch accumulators.
+            output refs, one boundary strip per channel, the fold's
+            accumulators (one set per sub-block) and, in a chained
+            plan, one sub-block tile per reference-laid
+            operand.
+
+    Chain c spans lanes [c L, (c + 1) L).  Sub-block u of the block
+    gives lane c L + p the reference lanes u L + p: the same columns
+    for every chain, each against its own queries.
     """
     channels = plan.channels
     fold = plan.fold
     n_out, n_ch = plan.num_outputs, len(channels)
-    n_ex = len(plan.extra_inputs)
-    ex_refs = dict(zip(plan.extra_inputs, refs[:n_ex]))
-    refs = refs[n_ex:]
+    n_acc = len(fold.accumulators())
+    ex_names = plan.extra_inputs
+    ex_refs = dict(zip(ex_names, refs[:len(ex_names)]))
+    refs = refs[len(ex_names):]
     out_refs = refs[:n_out]
     strip_refs = refs[n_out:n_out + n_ch]
-    scr = refs[n_out + n_ch:]
+    scr = refs[n_out + n_ch:n_out + n_ch + n_acc]
+    tile_refs = refs[n_out + n_ch + n_acc:]
 
     rblk = pl.program_id(1)
     m, w = plan.m, plan.segment_width
+    L, chains = plan.lanes_per_chain, plan.chains
     cdt = plan.compute_dtype
     lane = lax.broadcasted_iota(jnp.int32, (plan.rows_per_step, LANES), 1)
+    # each lane's place in its chain: lane c L + p is on row t - p
+    p = jnp.bitwise_and(lane, L - 1)
 
     @pl.when(rblk == 0)
     def _init():
@@ -956,111 +1104,170 @@ def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
     def ref_row(ref, k):                  # (1, LANES): reference slot k
         return ref[0, k:k + 1, :].astype(cdt)
 
-    # global ref index of lane's k=0 cell; a reverse band-skip grid
-    # starts block_offset layout blocks in (leading flipped columns are
-    # out of band for every row), forward grids start at 0
-    j_base = ((rblk + plan.block_offset) * LANES + lane) * w
+    def sweep(u, r_src, ex_src, acc):
+        """Sub-block ``u``: m + L - 1 serial steps of every chain over
+        ``r_src`` (the block, or its sub-block tile) and ``ex_src``
+        (the family operands, the same way), folding into ``acc``."""
+        # global ref index of lane's k=0 cell; a reverse band-skip grid
+        # starts block_offset layout blocks in (leading flipped columns
+        # are out of band for every row), forward grids start at 0
+        j_base = ((rblk + plan.block_offset) * LANES + u * L + p) * w
+        entered = (rblk > 0) | (u > 0)
 
-    def step(t, carry):
-        # lane l is computing query row i = t - l this step
-        i_l = t - lane                                    # (S, L) int32
-        is_row0 = (i_l == 0)
+        def step(t, carry):
+            # lane c L + p is computing query row i = t - p this step
+            i_l = t - p                                   # (S, L) int32
+            is_row0 = (i_l == 0)
 
-        # q value for (query s, lane l) = q[s, t - l]; q_ref stores the
-        # REVERSED query so this is an ascending slice (no lane flip).
-        # A multivariate plan reads its features' windows into the w
-        # cell costs instead.
-        costs = [None] * w
-        if plan.features > 1:
-            costs = _feature_costs(plan, q_ref, r_ref, t, lane)
-            qv = None
-        else:
-            qv = _lane_window(q_ref, m - 1 + LANES - 1 - t,
-                              lane).astype(cdt)
+            # q value for (query s, lane p) = q[s, t - p]; q_ref stores
+            # the REVERSED query so this is an ascending slice (no lane
+            # flip).  A multivariate plan reads its features' windows
+            # into the w cell costs instead.
+            costs = [None] * w
+            if plan.features > 1:
+                costs = _feature_costs(plan, q_ref, r_src, t, p)
+                qv = None
+            else:
+                qv = _lane_window(q_ref, m - 1 + L - 1 - t, p,
+                                  L).astype(cdt)
 
-        # per-step family operand values, laid out exactly like qv /
-        # r_blk.  q_prev = q[i_l - 1] is the t-1 slice of the same
-        # reversed pack (start clamped so t = 0 never reads past the
-        # pad; lane 0's masked convention value 0 is injected instead).
-        ex_step = {}
-        if plan.family == "twed":
-            qp = _lane_window(q_ref, m - 1 + LANES - 1
-                              - jnp.maximum(t - 1, 0), lane).astype(cdt)
-            ex_step["q_prev"] = jnp.where(is_row0, jnp.zeros_like(qp), qp)
-        elif plan.family == "erp":
-            ex_step["bl"] = _lane_window(ex_refs["bl"], m - 1 + LANES - 1 - t,
-                                         lane).astype(cdt)
-
-        rows = {ch.name: [] for ch in channels}
-        lefts = {ch.name: c[1] for ch, c in zip(channels, carry)}
-        for k in range(w):
-            vals3 = {}
-            for ch, (prev_row, _, prev_left) in zip(channels, carry):
-                up = prev_row[k]
-                upleft = prev_left if k == 0 else prev_row[k - 1]
-                vals3[ch.name] = (lefts[ch.name], up, upleft)
-            ex_k = None
+            # per-step family operand values, laid out exactly like qv
+            # / r_blk.  q_prev = q[i_l - 1] is the t-1 slice of the same
+            # reversed pack (start clamped so t = 0 never reads past
+            # the pad; lane 0's masked convention value 0 is injected
+            # instead).
+            ex_step = {}
             if plan.family == "twed":
-                ex_k = dict(ex_step, r_prev=ref_row(ex_refs["r_prev"], k))
+                qp = _lane_window(q_ref, m - 1 + L - 1
+                                  - jnp.maximum(t - 1, 0), p,
+                                  L).astype(cdt)
+                ex_step["q_prev"] = jnp.where(is_row0, jnp.zeros_like(qp),
+                                              qp)
             elif plan.family == "erp":
-                ex_k = dict(ex_step, bt=ref_row(ex_refs["bt"], k))
-            rv = None if plan.features > 1 else ref_row(r_ref, k)
-            new = plan.cell(qv, rv, is_row0=is_row0,
-                            i_l=i_l, j_col=j_base + k, vals3=vals3,
-                            extras=ex_k, cost=costs[k])
-            for ch in channels:
-                rows[ch.name].append(new[ch.name])
-                lefts[ch.name] = new[ch.name]
+                ex_step["bl"] = _lane_window(
+                    ex_src["bl"], m - 1 + L - 1 - t, p, L).astype(cdt)
 
-        # streaming fold when a lane finishes its bottom row (the
-        # family folds additionally see the in-grid mask: the local
-        # valid-cell fold is not a bottom-row fold)
-        fold.update(scr, at_bottom=(i_l == m - 1), rows=rows,
-                    j_base=j_base, plan=plan,
-                    in_grid=(i_l >= 0) & (i_l < m))
+            rows = {ch.name: [] for ch in channels}
+            lefts = {ch.name: c[1] for ch, c in zip(channels, carry)}
+            for k in range(w):
+                vals3 = {}
+                for ch, (prev_row, _, prev_left) in zip(channels, carry):
+                    up = prev_row[k]
+                    upleft = prev_left if k == 0 else prev_row[k - 1]
+                    vals3[ch.name] = (lefts[ch.name], up, upleft)
+                ex_k = None
+                if plan.family == "twed":
+                    ex_k = dict(ex_step,
+                                r_prev=ref_row(ex_src["r_prev"], k))
+                elif plan.family == "erp":
+                    ex_k = dict(ex_step, bt=ref_row(ex_src["bt"], k))
+                rv = None if plan.features > 1 else ref_row(r_src, k)
+                new = plan.cell(qv, rv, is_row0=is_row0,
+                                i_l=i_l, j_col=j_base + k, vals3=vals3,
+                                extras=ex_k, cost=costs[k])
+                for ch in channels:
+                    rows[ch.name].append(new[ch.name])
+                    lefts[ch.name] = new[ch.name]
 
-        # lane roll + boundary-strip read, mechanically per channel
-        t_next = jnp.minimum(t + 1, m - 1)
-        use_strip = (rblk > 0) & ((t + 1) < m)
-        new_carry = []
-        for ch, strip_ref, (_, left_in, _) in zip(channels, strip_refs,
-                                                  carry):
-            last = rows[ch.name][w - 1]                   # (S, L)
-            strip_val = ch.read_strip(strip_ref, t_next,
-                                      compute_dtype=cdt)
-            next_left = ch.roll_carry(last, lane=lane,
-                                      strip_val=strip_val,
-                                      use_strip=use_strip,
-                                      compute_dtype=cdt)
-            new_carry.append((tuple(rows[ch.name]), next_left, left_in))
+            # streaming fold when a lane finishes its bottom row (the
+            # family folds additionally see the in-grid mask: the local
+            # valid-cell fold is not a bottom-row fold)
+            fold.update(acc, at_bottom=(i_l == m - 1), rows=rows,
+                        j_base=j_base, plan=plan,
+                        in_grid=(i_l >= 0) & (i_l < m))
 
-        # publish right columns for the next block (lane LANES-1's row)
-        i127 = t - (LANES - 1)
+            # lane roll + boundary-strip read, mechanically per channel
+            t_next = jnp.minimum(t + 1, m - 1)
+            use_strip = entered & ((t + 1) < m)
+            new_carry = []
+            for ch, strip_ref, (_, left_in, _) in zip(channels, strip_refs,
+                                                      carry):
+                last = rows[ch.name][w - 1]               # (S, L)
+                strip_val = ch.read_strip(strip_ref, t_next,
+                                          compute_dtype=cdt,
+                                          lanes_per_chain=L)
+                next_left = ch.roll_carry(last, p=p,
+                                          strip_val=strip_val,
+                                          use_strip=use_strip,
+                                          compute_dtype=cdt)
+                new_carry.append((tuple(rows[ch.name]), next_left, left_in))
 
-        @pl.when((i127 >= 0) & (i127 < m))
-        def _store():
-            for ch, strip_ref in zip(channels, strip_refs):
-                ch.write_strip(strip_ref, i127, rows[ch.name][w - 1],
-                               lane=lane)
+            # publish right columns for the next sub-block (each
+            # chain's lane L-1 is on row t - (L - 1))
+            i_last = t - (L - 1)
 
-        return tuple(new_carry)
+            @pl.when((i_last >= 0) & (i_last < m))
+            def _store():
+                for ch, strip_ref in zip(channels, strip_refs):
+                    ch.write_strip(strip_ref, i_last, rows[ch.name][w - 1],
+                                   p=p, lanes_per_chain=L)
 
-    carry0 = tuple(ch.init_carry(strip_ref, lane=lane, rblk=rblk, w=w,
-                                 compute_dtype=cdt)
-                   for ch, strip_ref in zip(channels, strip_refs))
-    lax.fori_loop(0, m + LANES - 1, step, carry0)
+            return tuple(new_carry)
+
+        carry0 = tuple(ch.init_carry(strip_ref, p=p, entered=entered, w=w,
+                                     compute_dtype=cdt, lanes_per_chain=L)
+                       for ch, strip_ref in zip(channels, strip_refs))
+        lax.fori_loop(0, m + L - 1, step, carry0)
+
+    if chains == 1:             # the block is the one sub-block's tile
+        sweep(0, r_ref, ex_refs, [a.at[0] for a in scr])
+    else:
+        # the reference block and the family operands laid out like
+        # it, as each sub-block's tiles; the query-laid ones as they are
+        r_names = [name for name in ex_names if _EXTRA_KIND[name] == "r"]
+        ex_src = dict(ex_refs, **dict(zip(r_names, tile_refs[1:])))
+
+        def sub_block(u, carry):
+            for src, dst in zip([r_ref] + [ex_refs[n] for n in r_names],
+                                tile_refs):
+                dst[...] = _tile_chains(src[...], u, L)
+            sweep(u, tile_refs[0], ex_src, [a.at[u] for a in scr])
+            return carry
+
+        lax.fori_loop(0, chains, sub_block, 0)
 
     @pl.when(rblk == plan.grid_blocks - 1)
     def _finalize():
-        fold.finalize(scr, out_refs, plan)
+        # each chain's per-query results, in its own L lanes of the
+        # lane-dense output tile
+        tiles = None
+        for c in range(chains):
+            cols = fold.finalize(
+                [_one_chain_lanes(a, c, L, lane) for a in scr], plan)
+            if tiles is None:
+                tiles = [jnp.broadcast_to(col, lane.shape) for col in cols]
+            else:
+                mine = (lane >= c * L) & (lane < (c + 1) * L)
+                tiles = [jnp.where(mine, col, tile)
+                         for col, tile in zip(cols, tiles)]
+        for out_ref, tile in zip(out_refs, tiles):
+            out_ref[0] = tile.astype(out_ref.dtype)
 
 
-def _stack_groups(x, rows: int):
-    """(G, SUBLANES, Mp) -> (ceil(G / k), rows, Mp), k = rows / SUBLANES
-    consecutive query groups per block, the last padded with zeros."""
-    k = rows // SUBLANES
-    x = jnp.pad(x, ((0, -x.shape[0] % k), (0, 0), (0, 0)))
-    return x.reshape(-1, rows, x.shape[2])
+def _step_queries(x, plan: KernelPlan):
+    """Prepared query packs (G, SUBLANES, Mp) -> the (steps, rows, Mp')
+    rows grid steps read: ``plan.queries_per_step`` consecutive queries
+    a step (the last step's rest zero queries), query c * rows + s of a
+    step in sublane s of chain c.  At one chain that is consecutive
+    groups stacked, which moves no data under the TPU's (8, 128)
+    tiling.  At k chains of L lanes each feature's pack, from position
+    LANES - L on (its last L - 1 leading zeros), is cut into L-lane
+    pieces, and lane tile tau of the step row holds piece tau of every
+    chain (:func:`step_pack_len`)."""
+    G, S, Mp = x.shape
+    rows, per, L = plan.rows_per_step, plan.queries_per_step, \
+        plan.lanes_per_chain
+    x = x.reshape(G * S, Mp)
+    x = jnp.pad(x, ((0, -(G * S) % per), (0, 0)))
+    if plan.chains == 1:
+        return x.reshape(-1, rows, Mp)
+    D = plan.features
+    stride = Mp // D                      # lanes of one feature's pack
+    tiles = feature_stride(plan.m, L) // LANES
+    x = x.reshape(-1, D, stride)[:, :, LANES - L:LANES - L + tiles * L]
+    x = x.reshape(-1, plan.chains, rows, D, tiles, L)
+    return x.transpose(0, 2, 3, 4, 1, 5).reshape(
+        -1, rows, step_pack_len(plan.m, D, L))
 
 
 def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
@@ -1089,10 +1296,11 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
                G' = ceil(G * SUBLANES / rows) — every channel rides the
                SAME pallas_call, never a second sweep.
 
-    A plan of 2 * SUBLANES rows takes two consecutive query groups per
-    grid step, as one (2 * SUBLANES, Mp) block: the reshape moves no
-    data under the TPU's (8, 128) tiling.  An odd group count gains one
-    group of zero queries, trimmed from the outputs like batch padding.
+    A grid step takes ``plan.queries_per_step`` consecutive queries
+    (:func:`_step_queries`): ``rows_per_step`` (one or two packed
+    groups) in each of the plan's chains.  A batch that does not fill
+    its last step gains zero queries, trimmed from the outputs like
+    batch padding.
     """
     G, S, Mp = q_rev_pad.shape
     D = plan.features
@@ -1100,16 +1308,16 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
         raise ValueError(
             f"reference layout {tuple(r_layout.shape)} does not match the "
             f"plan's {D} feature(s)")
-    R, w, L = r_layout.shape[0], *r_layout.shape[-2:]
+    R, w, lanes = r_layout.shape[0], *r_layout.shape[-2:]
     if len(extras) != len(plan.extra_inputs):
         raise ValueError(
             f"family {plan.family!r} plans take extra operands "
             f"{plan.extra_inputs} (got {len(extras)}): build them with "
             "ops.family_extras(spec, queries, reference, ...)")
-    if S != SUBLANES or L != LANES:
+    if S != SUBLANES or lanes != LANES:
         raise ValueError(
             f"operand layout mismatch: queries packed {S} per group "
-            f"(want {SUBLANES}), reference {L} lanes (want {LANES})")
+            f"(want {SUBLANES}), reference {lanes} lanes (want {LANES})")
     if w != plan.segment_width or R != plan.num_ref_blocks:
         raise ValueError(
             f"reference layout {tuple(r_layout.shape)} does not match "
@@ -1124,10 +1332,11 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
         from repro.kernels.ops import default_interpret  # imports us
         interpret = default_interpret()
     kernel = functools.partial(_generic_kernel, plan=plan)
-    rows = plan.rows_per_step
-    grid = (_ceil_to(G * SUBLANES, rows) // rows, plan.grid_blocks)
+    rows, L = plan.rows_per_step, plan.lanes_per_chain
+    grid = (_ceil_to(G * SUBLANES, plan.queries_per_step)
+            // plan.queries_per_step, plan.grid_blocks)
     # per-query results leave as lane-dense (rows, LANES) tiles, every
-    # lane holding the same value (see _write)
+    # lane of a chain holding its query's value
     dtypes = [jnp.float32, jnp.int32] + ([jnp.int32] * plan.with_window)
     out_shape = [jax.ShapeDtypeStruct((grid[0], rows, LANES), dt)
                  for dt in dtypes]
@@ -1143,13 +1352,14 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
         out_specs.append(pl.BlockSpec((1, 1, rows, ms),
                                       lambda b, r: (b, r, 0, 0)))
     off = plan.block_offset
+    r_block = (1, w, LANES) if D == 1 else (1, D, w, LANES)
+    q_block = (1, rows, step_pack_len(plan.m, D, L))
     in_specs = [
-        pl.BlockSpec((1, rows, Mp), lambda b, r: (b, 0, 0)),
+        pl.BlockSpec(q_block, lambda b, r: (b, 0, 0)),
         # grid step r reads layout block r + offset (reverse band-skip
         # grids start past the leading out-of-band flipped blocks)
-        pl.BlockSpec((1, w, LANES), lambda b, r: (r + off, 0, 0))
-        if D == 1 else
-        pl.BlockSpec((1, D, w, LANES), lambda b, r: (r + off, 0, 0, 0)),
+        pl.BlockSpec(r_block,
+                     lambda b, r: (r + off,) + (0,) * (len(r_block) - 1)),
     ]
     for name, arr in zip(plan.extra_inputs, extras):
         if _EXTRA_KIND[name] == "r":
@@ -1166,15 +1376,19 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
                     f"family operand {name!r} {tuple(arr.shape)} must "
                     f"be packed like the prepared queries "
                     f"{tuple(q_rev_pad.shape)}")
-            in_specs.append(
-                pl.BlockSpec((1, rows, Mp), lambda b, r: (b, 0, 0)))
-    scratch = [ch.strip_shape(plan.m, rows) for ch in plan.channels]
-    scratch += plan.fold.scratch_shapes(rows)
-    if rows != SUBLANES:
-        q_rev_pad = _stack_groups(q_rev_pad, rows)
-        extras = tuple(_stack_groups(x, rows) if _EXTRA_KIND[name] == "q"
-                       else x for name, x in zip(plan.extra_inputs,
-                                                 extras))
+            in_specs.append(pl.BlockSpec(q_block, lambda b, r: (b, 0, 0)))
+    scratch = [ch.strip_shape(plan.m, rows, L) for ch in plan.channels]
+    scratch += [pltpu.VMEM((plan.chains, rows, LANES), dt)
+                for dt in plan.fold.accumulators()]
+    if plan.chains > 1:       # each sub-block's tile of the reference
+        #                       and of the operands laid out like it
+        scratch += [pltpu.VMEM(r_block, r_layout.dtype)] + [
+            pltpu.VMEM((1, w, LANES), x.dtype)
+            for name, x in zip(plan.extra_inputs, extras)
+            if _EXTRA_KIND[name] == "r"]
+    q_rev_pad = _step_queries(q_rev_pad, plan)
+    extras = tuple(_step_queries(x, plan) if _EXTRA_KIND[name] == "q"
+                   else x for name, x in zip(plan.extra_inputs, extras))
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
@@ -1187,10 +1401,10 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
         out_shape=tuple(out_shape), scratch_shapes=scratch,
         interpret=interpret, name=name, **kwargs,
     )(q_rev_pad, r_layout, *extras)
-    per_query = out[:len(dtypes)]
-    if rows != SUBLANES:
-        per_query = [x.reshape(-1, SUBLANES, LANES)[:G] for x in per_query]
-    out = [x[:, :, 0] for x in per_query] + \
+    # query c * rows + s of a grid step sits in sublane s, lanes of
+    # chain c: back to the prepared (G, SUBLANES) order
+    out = [x[:, :, ::L].transpose(0, 2, 1).reshape(-1, SUBLANES)[:G]
+           for x in out[:len(dtypes)]] + \
         [x[..., :plan.m] for x in out[len(dtypes):]]
     if plan.with_window:
         costs, ends, starts = out

@@ -19,7 +19,8 @@ import pytest
 
 from repro.core.spec import DPSpec
 from repro.kernels import backward, ops
-from repro.kernels.wavefront import LANES, SUBLANES, query_pack_len
+from repro.kernels.wavefront import (LANES, SUBLANES, query_pack_len,
+                                     step_pack_len)
 
 B, M, N = 512, 2_000, 100_000      # configs/paper_sdtw.PAPER
 W = 8                              # default segment width
@@ -119,6 +120,51 @@ def test_fused_soft_backward_compiles(one_chip, tpu_math):
     # one group of 8 queries: both sweeps keep one group a step
     assert _rows_per_step(text, "sdtw_wavefront") == ["8"]
     assert _rows_per_step(text, "sdtw_wavefront_reverse") == ["8"]
+
+
+def _compile_the_rules_plan(one_chip, batch, m, n, features, lanes):
+    """Compile a dispatch of these shapes as the rule plans it, which
+    must be 16 rows in chains of ``lanes``: the kernel reads query rows
+    packed for that many lanes a chain, ``rows x chains`` queries a
+    grid step."""
+    plan = ops.kernel_plan(m=m, n=n, segment_width=W, batch=batch,
+                           features=features)
+    assert (plan.rows_per_step, plan.lanes_per_chain) == (16, lanes)
+    blocks = plan.num_ref_blocks
+    q = _sds((batch // SUBLANES, SUBLANES, query_pack_len(m, features)),
+             one_chip)
+    r = _sds((blocks, W, LANES) if features == 1 else
+             (blocks, features, W, LANES), one_chip)
+
+    def sweep(q, r):
+        return ops.sdtw_wavefront_prepped(q, r, batch=batch, m=m, n=n,
+                                          segment_width=W, interpret=False)
+    text = _assert_kernel(jax.jit(sweep).lower(q, r).compile(),
+                          "sdtw_wavefront")
+    steps = batch // plan.queries_per_step
+    assert f"f32[{steps},16,{step_pack_len(m, features, lanes)}]" in text
+
+
+@pytest.mark.parametrize("batch, m, n, features, lanes", [
+    (B, M, N, 1, 16),                   # paper_batch: 8 chains of 16
+    (32, 100, 7_200_000, 39, 64),       # sws2013_qbe: 2 chains of 64
+])
+def test_benchmark_shapes_compile_chained(one_chip, batch, m, n, features,
+                                          lanes):
+    """Both benchmark shapes compile as the chained plans the rule
+    picks."""
+    _compile_the_rules_plan(one_chip, batch, m, n, features, lanes)
+
+
+@pytest.mark.parametrize("batch, m, features, lanes", [
+    (B, 12_000, 1, LANES),      # long queries keep one chain
+    (256, 500, 39, 32),         # 8 chains would outgrow the VMEM budget
+])
+def test_long_and_wide_batches_compile(one_chip, batch, m, features,
+                                       lanes):
+    """A long univariate batch and a wide multivariate one compile as
+    the rule plans them."""
+    _compile_the_rules_plan(one_chip, batch, m, N, features, lanes)
 
 
 @pytest.mark.parametrize("shape", [(B, M), (1, N)])

@@ -11,7 +11,7 @@ import pytest
 
 from repro.core.spec import DPSpec
 from repro.kernels import backward, ops
-from repro.kernels.wavefront import (LANES, SUBLANES, query_pack_len,
+from repro.kernels.wavefront import (LANES, SUBLANES, step_pack_len,
                                      wavefront_call)
 
 KINDS = {
@@ -91,15 +91,16 @@ def _pallas_grids(jaxpr):
     return found
 
 
-@pytest.mark.parametrize("batch, rows, grid_batch", [
-    (1, 8, 1), (8, 8, 1),                  # one group: the 8-row program
-    (9, 16, 1), (24, 16, 2), (64, 16, 4),  # two or more: two a step
+@pytest.mark.parametrize("batch, rows, grid_batch, lanes", [
+    (1, 8, 1, 128), (8, 8, 1, 128),        # one group: the 8-row program
+    (9, 16, 1, 128),                       # two or more: two a step,
+    (24, 16, 1, 64), (64, 16, 1, 32),      # in as many chains as fill
 ])
 def test_the_batch_picks_the_rows(data, one_group, batch, rows,
-                                  grid_batch):
+                                  grid_batch, lanes):
     q, r = data
-    assert ops.kernel_plan(m=M, n=N, segment_width=W,
-                           batch=batch).rows_per_step == rows
+    plan = ops.kernel_plan(m=M, n=N, segment_width=W, batch=batch)
+    assert (plan.rows_per_step, plan.lanes_per_chain) == (rows, lanes)
     qp = ops.prepare_queries(q[:batch])
     rl = ops.swizzle_reference(r, W)
 
@@ -107,7 +108,8 @@ def test_the_batch_picks_the_rows(data, one_group, batch, rows,
         return ops.sdtw_wavefront_prepped(qp, rl, batch=batch, m=M, n=N,
                                           segment_width=W, interpret=True)
     grids = _pallas_grids(jax.make_jaxpr(sweep)(qp, rl).jaxpr)
-    assert grids == [((grid_batch, BLOCKS), (1, rows, query_pack_len(M)))]
+    assert grids == [((grid_batch, BLOCKS),
+                      (1, rows, step_pack_len(M, 1, lanes)))]
     # the public path answers as the one-group sweep does
     costs, ends = sweep(qp, rl)
     want_c, want_e = (np.asarray(x).reshape(-1)[:batch]
