@@ -35,7 +35,7 @@ def main(argv=None):
         ap.error("--ci and --full are mutually exclusive")
 
     from benchmarks import table1_throughput, fig3_segment_width
-    from benchmarks import train_step_bench, sdtw_scaling
+    from benchmarks import sdtw_scaling
     from benchmarks import search_throughput, backend_matrix
     from benchmarks import align_throughput, band_skip, aligner_session
     from benchmarks import serve_stream, soft_backward
@@ -50,7 +50,6 @@ def main(argv=None):
             ("table1", lambda rows: table1_throughput.run(
                 full=full, kernel=args.kernel, csv=rows)),
             ("sdtw_scaling", lambda rows: sdtw_scaling.run(csv=rows)),
-            ("train_step", lambda rows: train_step_bench.run(csv=rows)),
         ]
     benches += [
         # fig3 runs in --ci too: the tiny-budget tuner smoke asserts a

@@ -73,7 +73,7 @@ def pipeline_mesh(row_blocks=(40, 100, 200, 500)):
 
 
 def kernel_analytic():
-    """Pallas wavefront kernel, VMEM-resident model (DESIGN.md §8.5):
+    """Pallas wavefront kernel, VMEM-resident model:
     HBM traffic = q + r + outputs; compute = VPU elementwise (f32)."""
     hbm = (B * M + N + 2 * B) * 4.0
     vpu = 4e12      # ~VPU f32 elementwise roofline per chip

@@ -1,7 +1,9 @@
-"""repro — multi-pod JAX framework around subsequence-DTW (sDTW).
+"""repro — subsequence dynamic time warping (sDTW) on JAX and TPU.
 
-Reproduction + scale-out of "Optimizing sDTW for AMD GPUs" (CS.DC 2024),
-adapted to TPU per DESIGN.md.
+A reproduction of "Optimizing sDTW for AMD GPUs" (cs.DC 2024): batched
+sDTW of many query series against a long reference, run by an
+anti-diagonal wavefront kernel in Pallas, with an XLA engine, a scan
+reference and a float64 oracle to check it against.
 
 One front door::
 

@@ -1,82 +1,2 @@
-"""Architecture registry: the 10 assigned configs (+ the paper's own
-sDTW workload config).
-
-Each ``<id>.py`` exposes ``CONFIG`` (the exact published config) and
-``smoke()`` (a reduced same-family config for CPU smoke tests). Select
-with ``--arch <id>`` in the launchers.
-"""
-
-from __future__ import annotations
-
-import dataclasses
-import importlib
-
-from repro.models.config import ModelConfig
-
-ARCH_IDS = (
-    "seamless_m4t_large_v2",
-    "pixtral_12b",
-    "llama4_scout_17b_16e",
-    "qwen2_moe_a2_7b",
-    "gemma3_27b",
-    "qwen2_72b",
-    "qwen3_32b",
-    "stablelm_12b",
-    "mamba2_130m",
-    "recurrentgemma_9b",
-)
-
-# canonical dashed ids (as assigned) -> module names
-ALIASES = {i.replace("_", "-"): i for i in ARCH_IDS}
-
-
-def _module(arch: str):
-    arch = ALIASES.get(arch, arch).replace("-", "_")
-    return importlib.import_module(f"repro.configs.{arch}")
-
-
-def get_config(arch: str) -> ModelConfig:
-    return _module(arch).CONFIG
-
-
-def get_smoke(arch: str) -> ModelConfig:
-    return _module(arch).smoke()
-
-
-def all_configs() -> dict[str, ModelConfig]:
-    return {a: get_config(a) for a in ARCH_IDS}
-
-
-# -------------------------------------------------- assigned input shapes
-
-@dataclasses.dataclass(frozen=True)
-class ShapeSpec:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str                 # "train" | "prefill" | "decode"
-
-
-SHAPES = {
-    "train_4k":    ShapeSpec("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeSpec("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k":  ShapeSpec("decode_32k", 32_768, 128, "decode"),
-    "long_500k":   ShapeSpec("long_500k", 524_288, 1, "decode"),
-}
-
-# long_500k needs sub-quadratic attention: only the SSM and hybrid
-# (RG-LRU + local-window) archs run it (DESIGN.md §4).
-LONG_CONTEXT_ARCHS = ("mamba2_130m", "recurrentgemma_9b")
-
-
-def shape_applicable(arch: str, shape: str) -> bool:
-    arch = ALIASES.get(arch, arch)
-    if shape == "long_500k":
-        return arch in LONG_CONTEXT_ARCHS
-    return True
-
-
-def cells():
-    """Every applicable (arch, shape) cell."""
-    return [(a, s) for a in ARCH_IDS for s in SHAPES
-            if shape_applicable(a, s)]
+"""Workload configurations: ``paper_sdtw`` holds the paper's own batch
+(Table 1 / Fig. 3) and the reduced shape the CPU benches use."""

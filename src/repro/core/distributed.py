@@ -1,6 +1,6 @@
 """Distributed sDTW — the paper's wavefront structure lifted to a mesh.
 
-Two composable levels (DESIGN.md §2, §5):
+Two composable levels:
 
 1. **Query-batch data parallelism** over the ``("pod", "data")`` axes —
    the paper's block-per-query batching: sDTW is embarrassingly parallel

@@ -1,5 +1,3 @@
 from repro.data.cbf import make_cylinder_bell_funnel, make_sdtw_dataset
-from repro.data.pipeline import TokenStream, ShardedLoader, sdtw_dedup
 
-__all__ = ["make_cylinder_bell_funnel", "make_sdtw_dataset",
-           "TokenStream", "ShardedLoader", "sdtw_dedup"]
+__all__ = ["make_cylinder_bell_funnel", "make_sdtw_dataset"]

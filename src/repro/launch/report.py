@@ -1,7 +1,5 @@
-"""Reporting driver, three modes:
+"""Reporting driver over ``BENCH_*.json`` documents, three modes:
 
-  * default — aggregate experiments/dryrun/*.json into the
-    EXPERIMENTS.md roofline table (markdown to stdout);
   * ``--compare A/ B/`` — diff two directories of schema-versioned
     ``BENCH_*.json`` files (written by ``benchmarks/run.py``; schema in
     ``repro.obs.bench``) and flag regressions beyond ``--threshold``
@@ -35,8 +33,6 @@
 from __future__ import annotations
 
 import argparse
-import glob
-import json
 import logging
 import os
 import re
@@ -308,30 +304,8 @@ def write_plots(root: str, out_dir: str, *, last: int = 20,
     return written
 
 
-def dryrun_table(args) -> int:
-    rows = []
-    for p in sorted(glob.glob(os.path.join(args.dir, "*.json"))):
-        d = json.load(open(p))
-        if d["mesh"] != args.mesh:
-            continue
-        rows.append(d)
-    rows.sort(key=lambda d: (d["arch"], d["shape"]))
-
-    print(f"| arch | shape | t_comp (s) | t_mem (s) | t_coll (s) | "
-          f"bound | MODEL/HLO | roofline frac |")
-    print("|---|---|---|---|---|---|---|---|")
-    for d in rows:
-        print(f"| {d['arch']} | {d['shape']} | {fmt(d['t_compute'])} | "
-              f"{fmt(d['t_memory'])} | {fmt(d['t_collective'])} | "
-              f"{d['bottleneck']} | {fmt(d['flops_ratio'])} | "
-              f"{fmt(d['roofline_fraction'])} |")
-    return 0
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--dir", default="experiments/dryrun")
-    ap.add_argument("--mesh", default="pod16x16")
     ap.add_argument("--compare", nargs=2, metavar=("BASE", "NEW"),
                     help="diff two directories of BENCH_*.json files")
     ap.add_argument("--history", metavar="DIR",
@@ -351,9 +325,8 @@ def main(argv=None):
                          "regression (default 0.10 = 10%%)")
     args = ap.parse_args(argv)
     obs.configure_logging()
-    if sum(map(bool, (args.compare, args.history, args.plot))) > 1:
-        ap.error("--compare, --history and --plot are mutually "
-                 "exclusive")
+    if sum(map(bool, (args.compare, args.history, args.plot))) != 1:
+        ap.error("give exactly one of --compare, --history and --plot")
 
     if args.plot:
         if args.last is not None and args.last < 1:
@@ -384,7 +357,6 @@ def main(argv=None):
             log.error("%s", e)
             return 2
         return 1 if n else 0
-    return dryrun_table(args)
 
 
 if __name__ == "__main__":

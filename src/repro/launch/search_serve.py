@@ -20,9 +20,9 @@ CPU-scale usage (reduced workload):
       # instead of pre-formed chunks; --max-wait-ms / --max-batch /
       # --workers / --deadline-ms expose the formation policy knobs
 
-The driver mirrors launch/serve.py: build the index once (normalized +
-cached layouts), then drive the SearchService over arriving chunks the
-way a serving frontend would.  Hits come back with their matched
+The driver builds the index once (normalized + cached layouts), then
+drives the SearchService over arriving chunks the way a serving
+frontend would.  Hits come back with their matched
 reference window — ``track3[412..540]`` — not just a distance, unless
 ``--no-windows`` (or a soft-min spec) turns the start lanes off.
 

@@ -4,10 +4,10 @@ into the paper's fixed kernel shapes.
 The wavefront kernel (and the jit cache in front of every backend)
 wants static shapes: a (B, M) block with B a SUBLANES multiple and one
 compiled executable per distinct shape. Real search traffic is neither:
-queries arrive one at a time with arbitrary lengths. Mirroring the slot
-discipline of ``serve/batcher.py``, the packer keeps one open bucket
-per query length; a bucket emits a full batch the moment all
-``max_slots`` slots fill, and ``flush()`` drains stragglers. Emitted
+queries arrive one at a time with arbitrary lengths. The packer keeps
+one open bucket per query length; a bucket emits a full batch the
+moment all ``max_slots`` slots fill, and ``flush()`` drains
+stragglers. Emitted
 batches are zero-padded up to a small shape grid (SUBLANES x powers of
 two, capped at ``max_slots``) so a long-running service compiles each
 backend for only O(log(max_slots / SUBLANES)) batch shapes per length.

@@ -21,11 +21,6 @@ profile is benchmarked closed-loop under seeded Poisson arrivals
     with StreamServer(index, config=StreamConfig(max_wait_ms=5)) as srv:
         fut = srv.submit(query, k=3, deadline_ms=100)
         resp = fut.result()          # ServeResponse(status="ok", hits=...)
-
-The seed-era LM generation stubs (``serve.engine`` prefill/decode,
-``serve.batcher`` token-slot continuous batching) remain importable
-from their submodules for the legacy model stack; this package's public
-surface is the search service.
 """
 
 from repro.serve.faults import FaultPolicy, TransientSweepError

@@ -1,161 +1,6 @@
-"""Roofline-term derivation from a compiled XLA artifact (DESIGN.md §6).
-
-Per the assignment, the three terms for a (arch, mesh) cell are::
-
-    compute    = HLO_FLOPs / (chips * PEAK_FLOPS)
-    memory     = HLO_bytes / (chips * HBM_BW)
-    collective = collective_bytes / (chips * LINK_BW)
-
-HLO_FLOPs / HLO_bytes come from ``compiled.cost_analysis()``.
-collective_bytes is parsed out of the post-SPMD HLO text
-(``compiled.as_text()``): we sum the operand bytes of every all-gather /
-all-reduce / reduce-scatter / all-to-all / collective-permute op
-(all-reduce counted twice — reduce + broadcast phases of a ring).
-
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
-"""
-
-from __future__ import annotations
-
-import dataclasses
-import json
-import re
-from typing import Optional
+"""TPU v5e per-chip hardware constants for roofline terms:
+197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s per ICI link."""
 
 PEAK_FLOPS = 197e12          # bf16 per chip
 HBM_BW = 819e9               # bytes/s per chip
 LINK_BW = 50e9               # bytes/s per ICI link
-
-_DTYPE_BYTES = {
-    "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
-    "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
-    "s32": 4, "u32": 4, "f32": 4,
-    "s64": 8, "u64": 8, "f64": 8, "c64": 8, "c128": 16,
-}
-
-_COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter",
-                "all-to-all", "collective-permute")
-
-# a typed tensor literal in HLO text: bf16[128,4096]{1,0}
-_SHAPE_RE = re.compile(r"\b([a-z]+\d*)\[([\d,]*)\]")
-
-
-def _shape_bytes(dtype: str, dims: str) -> int:
-    n = 1
-    for d in dims.split(","):
-        if d:
-            n *= int(d)
-    return n * _DTYPE_BYTES.get(dtype, 4)
-
-
-def collective_bytes(hlo_text: str) -> dict[str, int]:
-    """Sum operand bytes per collective kind from post-SPMD HLO text."""
-    out = {k: 0 for k in _COLLECTIVES}
-    for line in hlo_text.splitlines():
-        line = line.strip()
-        m = re.match(r"%?[\w.\-]+\s*=\s*[\w\[\]{},\s]*?\s*"
-                     r"(all-gather|all-reduce|reduce-scatter|all-to-all|"
-                     r"collective-permute)(-start|-done)?\(", line)
-        if not m:
-            continue
-        kind, phase = m.group(1), m.group(2)
-        if phase == "-done":        # counted at -start
-            continue
-        # operand types: everything inside the call parens
-        call = line[m.end() - 1:]
-        shapes = _SHAPE_RE.findall(call)
-        if not shapes:              # fall back to the output type
-            shapes = _SHAPE_RE.findall(line[: m.start(1)])
-        nbytes = sum(_shape_bytes(dt, dims) for dt, dims in shapes)
-        if kind == "all-reduce":
-            nbytes *= 2             # ring: reduce-scatter + all-gather
-        out[kind] += nbytes
-    out["total"] = sum(out[k] for k in _COLLECTIVES)
-    return out
-
-
-@dataclasses.dataclass
-class Roofline:
-    arch: str
-    shape: str
-    mesh: str
-    chips: int
-    hlo_flops: float
-    hlo_bytes: float
-    coll_bytes: float
-    coll_breakdown: dict
-    model_flops: float = 0.0       # 6*N*D analytic
-    bytes_per_device: float = 0.0  # from memory_analysis
-
-    @property
-    def t_compute(self) -> float:
-        return self.hlo_flops / (self.chips * PEAK_FLOPS)
-
-    @property
-    def t_memory(self) -> float:
-        return self.hlo_bytes / (self.chips * HBM_BW)
-
-    @property
-    def t_collective(self) -> float:
-        return self.coll_bytes / (self.chips * LINK_BW)
-
-    @property
-    def bottleneck(self) -> str:
-        t = {"compute": self.t_compute, "memory": self.t_memory,
-             "collective": self.t_collective}
-        return max(t, key=t.get)
-
-    @property
-    def roofline_fraction(self) -> float:
-        """useful-compute time / modeled step time (max of terms):
-        how close the cell sits to the hardware roofline."""
-        step = max(self.t_compute, self.t_memory, self.t_collective)
-        if step <= 0:
-            return 0.0
-        useful = self.model_flops / (self.chips * PEAK_FLOPS)
-        return useful / step
-
-    @property
-    def flops_ratio(self) -> float:
-        """MODEL_FLOPS / HLO_FLOPs — how much compiled compute is useful
-        (catches remat / redundancy waste). HLO counts a MAC as 2 FLOPs,
-        same convention as 6*N*D."""
-        return self.model_flops / self.hlo_flops if self.hlo_flops else 0.0
-
-    def to_dict(self) -> dict:
-        d = dataclasses.asdict(self)
-        d.update(t_compute=self.t_compute, t_memory=self.t_memory,
-                 t_collective=self.t_collective, bottleneck=self.bottleneck,
-                 roofline_fraction=self.roofline_fraction,
-                 flops_ratio=self.flops_ratio)
-        return d
-
-
-def from_compiled(compiled, *, arch: str, shape: str, mesh_desc: str,
-                  chips: int, model_flops: float = 0.0) -> Roofline:
-    from repro.utils import hlo_cost
-
-    # loop-aware HLO walk (XLA's own cost_analysis counts while bodies
-    # once — useless for scanned layer stacks; see utils/hlo_cost.py).
-    # The compiled module is the PER-DEVICE SPMD program: scale by chips
-    # so hlo_* are global, matching the roofline formulas (terms then
-    # reduce to per-device work / per-device bandwidth).
-    c = hlo_cost.analyze(compiled.as_text())
-    coll = {k: v * chips for k, v in c.coll.items()}
-    coll["total"] = c.coll_bytes * chips
-    mem = compiled.memory_analysis()
-    per_dev = 0.0
-    if mem is not None:
-        per_dev = (getattr(mem, "argument_size_in_bytes", 0)
-                   + getattr(mem, "output_size_in_bytes", 0)
-                   + getattr(mem, "temp_size_in_bytes", 0))
-    return Roofline(arch=arch, shape=shape, mesh=mesh_desc, chips=chips,
-                    hlo_flops=c.flops * chips, hlo_bytes=c.bytes * chips,
-                    coll_bytes=c.coll_bytes * chips, coll_breakdown=coll,
-                    model_flops=model_flops, bytes_per_device=per_dev)
-
-
-def save(r: Roofline, path: str):
-    with open(path, "w") as f:
-        json.dump(r.to_dict(), f, indent=1)

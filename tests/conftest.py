@@ -2,8 +2,8 @@
 
 NOTE: deliberately does NOT set ``--xla_force_host_platform_device_count``:
 smoke tests and benchmarks must see the real single CPU device.  The
-distributed / dry-run tests that need fake devices spawn subprocesses with
-their own XLA_FLAGS (see tests/test_distributed.py, tests/test_dryrun_small.py).
+tests that need fake devices spawn subprocesses with their own XLA_FLAGS
+(see tests/test_distributed.py, tests/test_hlo_cost.py).
 """
 import numpy as np
 import pytest

@@ -12,7 +12,7 @@ import pytest
 
 import repro
 from repro.core.api import sdtw
-from repro.core.normalize import normalize_batch
+from repro.core.normalize import normalize_batch, normalize_reference
 from repro.core.spec import DPSpec
 from repro.data.cbf import make_cylinder_bell_funnel
 
@@ -87,12 +87,39 @@ def test_outputs_key_is_order_insensitive(data):
 
 
 # ------------------------------------------------------------- parity
-@pytest.mark.parametrize("backend", ["ref", "engine", "kernel"])
-def test_session_equals_front_door_bit_for_bit(data, backend):
+def _batch(batch, features, seed=3):
+    """``batch`` queries and one reference; feature inputs are (B, M, D)
+    and (N, D) random walks."""
+    rng = np.random.default_rng(seed)
+    if features == 1:
+        return (jnp.asarray(make_cylinder_bell_funnel(rng, batch, M)),
+                jnp.asarray(make_cylinder_bell_funnel(rng, 1, N)[0]))
+    walk = lambda shape: jnp.asarray(np.cumsum(
+        rng.normal(size=shape), axis=-2).astype(np.float32))
+    return walk((batch, M, features)), walk((N, features))
+
+
+@pytest.mark.parametrize("backend, batch, features", [
+    pytest.param("ref", B, 1, id="ref"),
+    pytest.param("engine", B, 1, id="engine"),
+    pytest.param("kernel", B, 1, id="kernel"),
+    # the batches where ops.plan_rows changes its rows or chains:
+    # 8 rows; 16 rows; 2, 2 and 4 chains of 16 rows
+    *[pytest.param("kernel", b, 1, id=f"kernel-batch{b}")
+      for b in (2, 16, 24, 32, 64)],
+    *[pytest.param(be, B, d, id=f"{be}-features{d}")
+      for be in ("ref", "kernel") for d in (2, 39)],
+    pytest.param("kernel", 24, 39, id="kernel-batch24-features39"),
+])
+def test_session_equals_front_door_bit_for_bit(data, backend, batch,
+                                               features):
     """A normalize=False session contains exactly the sweep, so its
-    numbers equal the eager dispatch path bit for bit."""
-    q, r = data
-    qn, rn = normalize_batch(q), normalize_batch(r)
+    numbers equal the eager dispatch path bit for bit — the session's
+    own kernel program and the registry's kernel route alike, at every
+    rows-and-chains plan and on feature inputs."""
+    q, r = data if (batch, features) == (B, 1) else _batch(batch, features)
+    qn = normalize_batch(q)
+    rn = normalize_reference(r)
     a = repro.Aligner(rn, backend=backend, normalize=False,
                       segment_width=2)
     res = a(qn, outputs=("cost", "start", "end"))

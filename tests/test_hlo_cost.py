@@ -1,8 +1,8 @@
-"""Dry-run machinery on a small fake-device mesh (subprocess so the
-XLA device-count flag never leaks into other tests), plus hlo_cost
-unit checks that run in-process."""
+"""hlo_cost: loop-aware FLOP and collective-byte counts from compiled
+HLO text.  The collective check needs two fake devices, so it runs in
+a subprocess and the XLA device-count flag never leaks into other
+tests."""
 
-import json
 import os
 import subprocess
 import sys
@@ -56,45 +56,4 @@ print("OK", got.coll_bytes)
     r = subprocess.run([sys.executable, "-c", code], env=env,
                        capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr
-    assert "OK" in r.stdout
-
-
-def test_dryrun_cell_small_mesh():
-    """End-to-end dry-run of one smoke-config cell on a 2x2 fake mesh."""
-    code = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import jax, json
-import dataclasses
-from repro import configs
-from repro.launch import specs as S
-from repro.utils import roofline as R
-
-mesh = jax.make_mesh((2, 2), ("data", "model"))
-
-# monkeypatch the registry to the smoke config so this compiles fast
-import repro.configs as C
-smoke = C.get_smoke("gemma3_27b")
-C._module("gemma3_27b").CONFIG = smoke
-
-# shrink the shape too
-C.SHAPES = dict(C.SHAPES)
-C.SHAPES["train_4k"] = dataclasses.replace(
-    C.SHAPES["train_4k"], seq_len=64, global_batch=4)
-
-cell = S.build_cell("gemma3_27b", "train_4k", mesh)
-fn = jax.jit(cell.fn, in_shardings=cell.in_shardings,
-             out_shardings=cell.out_shardings)
-compiled = fn.lower(*cell.args).compile()
-r = R.from_compiled(compiled, arch="gemma3_27b", shape="train_4k",
-                    mesh_desc="2x2", chips=4, model_flops=cell.model_flops)
-assert r.hlo_flops > 0 and r.hlo_bytes > 0
-assert r.bottleneck in ("compute", "memory", "collective")
-print("OK", json.dumps({"flops": r.hlo_flops, "bn": r.bottleneck}))
-"""
-    env = dict(os.environ, PYTHONPATH=SRC)
-    env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", code], env=env,
-                       capture_output=True, text=True, timeout=600)
-    assert r.returncode == 0, r.stderr[-3000:]
     assert "OK" in r.stdout

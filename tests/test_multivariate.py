@@ -38,12 +38,24 @@ def _cmvn(x):
 
 
 # ------------------------------------------------------------- parity
+# lanes a chain runs at each batch (ops.plan_rows): one chain of 128 up
+# to 16 queries, then 2, 4 and 8 chains as the batch fills them
+CHAIN_LANES_AT = {8: 128, 16: 128, 24: 64, 32: 64, 64: 32, 128: 16}
+
+
 @pytest.mark.parametrize("b, m, n, d", [
     (8, 13, 700, 2),        # one group; 700 columns pad to 3 blocks
     (16, 9, 300, 13),       # two groups, one step of 16 rows
     (24, 7, 260, 39),       # three groups: a pad group of zeros
+    # D = 2, 5, 39 x chains of 128, 64, 32 and 16 lanes
+    (32, 7, 260, 2), (64, 7, 260, 2), (128, 7, 260, 2),
+    (16, 7, 260, 5), (24, 7, 260, 5), (64, 7, 260, 5), (128, 7, 260, 5),
+    (8, 7, 260, 39), (64, 7, 260, 39), (128, 7, 260, 39),
 ])
 def test_kernel_ref_and_oracle_agree(b, m, n, d):
+    plan = ops.kernel_plan(DPSpec(), m=m, n=n, segment_width=2, batch=b,
+                           features=d, with_window=True)
+    assert plan.lanes_per_chain == CHAIN_LANES_AT[b]
     q, r = _data(b, m, n, d, seed=d)
     qn, rn = normalize_batch(jnp.asarray(q)), normalize_reference(
         jnp.asarray(r))

@@ -442,6 +442,16 @@ def test_report_compare_flags_injected_regression(tmp_path, capsys):
     assert report.main(["--compare", str(a), str(tmp_path / "nope")]) == 2
 
 
+@pytest.mark.parametrize("argv", [[], ["--compare", "a", "b",
+                                       "--history", "h"]])
+def test_report_takes_exactly_one_mode(argv, capsys):
+    from repro.launch import report
+    with pytest.raises(SystemExit) as exit_:
+        report.main(argv)
+    assert exit_.value.code == 2
+    assert "exactly one of" in capsys.readouterr().err
+
+
 # ------------------------------------------------- instrumented hot paths
 
 def test_registry_select_records_choice(monkeypatch):

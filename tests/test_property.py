@@ -3,7 +3,7 @@ import numpy as np
 import pytest
 
 hypothesis = pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 import hypothesis.extra.numpy as hnp
 
 from repro.core.engine import sdtw_engine
@@ -80,7 +80,11 @@ def test_softdtw_lower_bounds_hard(q, r):
 
 @settings(max_examples=15, deadline=None)
 @given(q=series(2, 8), r=series(4, 16))
+@example(q=np.zeros(6, np.float32), r=np.zeros(9, np.float32))
 def test_softdtw_gamma_to_zero_recovers_hard(q, r):
+    """Soft-sDTW sits below hard sDTW by at most gamma x log(number of
+    optimal alignments): ties in every cell (all zeros) give about
+    26,000 at these lengths, so gamma is 1e-4, where that gap is 1e-3."""
     hard = sdtw_numpy(q, r)[0]
-    soft = float(np.asarray(sdtw_soft(q[None], r, gamma=1e-3))[0])
+    soft = float(np.asarray(sdtw_soft(q[None], r, gamma=1e-4))[0])
     np.testing.assert_allclose(soft, hard, rtol=1e-2, atol=1e-2)

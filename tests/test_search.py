@@ -7,8 +7,10 @@ import pytest
 
 from repro.core.normalize import normalize_batch
 from repro.core.ref import sdtw_ref
-from repro.core.spec import DPSpec
+from repro import obs
+from repro.core.spec import DEFAULT_SPEC, DPSpec
 from repro.data.cbf import make_search_dataset
+from repro.kernels.ops import swizzle_reference
 from repro.kernels.sdtw_wavefront import SUBLANES
 from repro.search import (QueryBatcher, ReferenceIndex, SearchConfig,
                           SearchService, brute_force_topk, grid_size,
@@ -163,7 +165,73 @@ def test_reference_index_normalizes_once(rng):
     np.testing.assert_array_equal(np.asarray(raw.series), r)
 
 
+def test_reference_index_registration_order(rng):
+    idx = ReferenceIndex(normalize=False)
+    series = {n: rng.normal(size=(40 + 10 * i,)).astype(np.float32)
+              for i, n in enumerate(("b", "a", "c"))}
+    assert idx.add_many(series.items()) is idx
+    assert len(idx) == 3 and "a" in idx and "z" not in idx
+    assert idx.names() == ["b", "a", "c"]          # registration order
+    entries = idx.references()
+    assert [e.name for e in entries] == ["b", "a", "c"]
+    assert [e.order for e in entries] == [0, 1, 2]
+    assert [e.length for e in entries] == [40, 50, 60]
+    assert idx.spec == DEFAULT_SPEC
+
+
+def test_reference_index_rejects_empty_reference():
+    idx = ReferenceIndex()
+    with pytest.raises(ValueError, match="empty"):
+        idx.add("a", np.zeros((0,), np.float32))
+    assert len(idx) == 0
+
+
+def test_reference_index_layout_per_dtype_and_envelopes_per_chunk(rng):
+    idx = ReferenceIndex()
+    idx.add("a", rng.normal(size=(300,)).astype(np.float32))
+    series = idx.get("a").series
+    f32 = idx.layout("a", 4)
+    np.testing.assert_array_equal(np.asarray(f32), np.asarray(
+        swizzle_reference(series, 4)))
+    bf16 = idx.layout("a", 4, jnp.bfloat16)
+    assert bf16.dtype == jnp.bfloat16 and f32.dtype == jnp.float32
+    assert idx.layout("a", 4, jnp.bfloat16) is bf16
+    assert set(idx.get("a").layouts) == {(4, "float32"), (4, "bfloat16")}
+    e4, e8 = idx.envelopes("a", 4), idx.envelopes("a", 8)
+    assert (e4[0].shape[-1], e8[0].shape[-1]) == (75, 38)   # ceil(300/c)
+    assert set(idx.get("a").envelopes) == {4, 8}
+
+
 # -------------------------------------------------------------- batcher
+@pytest.mark.parametrize("n, max_slots, want", [
+    (1, 64, SUBLANES), (8, 64, 8), (9, 64, 16), (16, 64, 16),
+    (17, 64, 32), (33, 64, 64), (33, 48, 48),
+])
+def test_grid_size(n, max_slots, want):
+    """The smallest SUBLANES x 2^k that holds the batch, capped."""
+    assert grid_size(n, max_slots) == want
+
+
+def test_grid_size_rejects_more_than_max_slots():
+    with pytest.raises(ValueError, match="exceeds max_slots"):
+        grid_size(65, 64)
+
+
+def test_batcher_records_fill_metrics(rng):
+    metrics = obs.MetricsRegistry()
+    b = QueryBatcher(max_slots=16, metrics=metrics)
+    batches = b.pack([rng.normal(size=(20,)) for _ in range(19)],
+                     ids=[f"q{i}" for i in range(19)])
+    assert [x.n_real for x in batches] == [16, 3]
+    assert batches[1].ids == ("q16", "q17", "q18")
+    assert metrics.value("batcher.batches") == 2
+    assert metrics.value("batcher.rows_real") == 19
+    assert metrics.value("batcher.rows_padded") == 8 - 3
+    fill = metrics.get("batcher.fill")
+    assert fill.count == 2
+    assert sorted(fill.summary()[k] for k in ("min", "max")) == [3 / 8, 1.0]
+
+
 def test_batcher_buckets_and_grid(rng):
     b = QueryBatcher(max_slots=16)
     out = []

@@ -114,20 +114,43 @@ def test_statically_blocked_band_zero_grads(data):
     assert E.shape == (2, 20, 8) and (np.asarray(E) == 0).all()
 
 
-def test_train_loss_grad_equivalence(data):
-    """make_sdtw_loss differentiates identically through the fused
-    kernel backward and the engine — normalization chain included."""
+@pytest.mark.parametrize("band", [None, 8])
+@pytest.mark.parametrize("reduce", ["mean", "sum", "none"])
+@pytest.mark.parametrize("backend", ["ref", "engine", "kernel"])
+def test_train_loss_grad_equivalence(data, backend, reduce, band):
+    """make_sdtw_loss promotes its spec to soft-min, reduces as asked,
+    and differentiates identically on every backend — through the fused
+    kernel backward on the kernel — against the engine's soft-min cost
+    through the front door, normalization chain included."""
+    import repro
     from repro.train import make_sdtw_loss
     q, r = data
-    lk = make_sdtw_loss(r, gamma=0.5, backend="kernel",
-                        segment_width=SEG, interpret=True)
-    le = make_sdtw_loss(r, gamma=0.5, backend="engine")
-    np.testing.assert_allclose(float(lk(q)), float(le(q)),
+    kw = {"segment_width": SEG, "interpret": True} \
+        if backend == "kernel" else {}
+    loss = make_sdtw_loss(r, gamma=0.5, band=band, backend=backend,
+                          reduce=reduce, **kw)
+    reducer = {"mean": jnp.mean, "sum": jnp.sum,
+               "none": lambda c: c}[reduce]
+
+    def want(x):
+        return reducer(repro.sdtw(x, r, spec=_spec(0.5, band=band),
+                                  backend="engine",
+                                  outputs=("cost",)).cost)
+
+    got = loss(q)
+    assert got.shape == (() if reduce != "none" else (B,))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want(q)),
                                rtol=1e-5, atol=1e-5)
-    gk = jax.grad(lk)(q)
-    ge = jax.grad(le)(q)
+    gk = jax.grad(lambda x: loss(x).sum())(q)
+    ge = jax.grad(lambda x: want(x).sum())(q)
     np.testing.assert_allclose(np.asarray(gk), np.asarray(ge),
                                rtol=1e-4, atol=1e-4)
+
+
+def test_train_loss_rejects_unknown_reduce(data):
+    from repro.train import make_sdtw_loss
+    with pytest.raises(ValueError, match="reduce must be"):
+        make_sdtw_loss(data[1], reduce="max")
 
 
 # --------------------------------------------------- memory guarantee

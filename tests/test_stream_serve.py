@@ -322,6 +322,33 @@ def test_due_flushes_policy():
     assert due == [] and wake is None
 
 
+@pytest.mark.parametrize("bad", [
+    {"max_retries": -1},
+    {"default_deadline_ms": 0.0},
+    {"retry_after_ms": -5.0},
+])
+def test_stream_config_rejects(bad):
+    with pytest.raises(ValueError, match=next(iter(bad))):
+        StreamConfig(**bad)
+
+
+def test_stream_config_seconds():
+    """retry-after defaults to one batch-formation period."""
+    cfg = StreamConfig(max_wait_ms=25.0)
+    assert (cfg.max_wait_s, cfg.retry_after_s) == (0.025, 0.025)
+    assert StreamConfig(max_wait_ms=25.0,
+                        retry_after_ms=400.0).retry_after_s == 0.4
+
+
+def test_due_flushes_at_the_deadline():
+    """A bucket whose oldest request has waited exactly max_wait is
+    due; with max_wait 0 every bucket is due at once."""
+    due, wake = due_flushes({10: 0.0, 20: 1.0}, now=2.0, max_wait_s=2.0)
+    assert due == [10] and wake == 3.0
+    due, wake = due_flushes({30: 5.0, 10: 5.0}, now=5.0, max_wait_s=0.0)
+    assert due == [10, 30] and wake is None
+
+
 # ------------------------------------------------------------ session pool
 def test_session_pool_exactly_once_callbacks(workload):
     index, queries, _ = workload
@@ -351,6 +378,43 @@ def test_fault_policy_counts_attempts():
         policy.on_dispatch()
     policy.on_dispatch()                  # third attempt passes
     assert policy.attempts == 3
+
+
+@pytest.mark.parametrize("bad", [{"fail_first": -1},
+                                 {"latency_s": -0.1}])
+def test_fault_policy_validation(bad):
+    with pytest.raises(ValueError, match=next(iter(bad))):
+        FaultPolicy(**bad)
+
+
+def test_fault_policy_fail_when_schedule():
+    """fail_when sees every attempt index, retries included."""
+    policy = FaultPolicy(fail_when=lambda i: i % 2 == 1)
+    outcomes = []
+    for _ in range(5):
+        try:
+            policy.on_dispatch()
+            outcomes.append("ok")
+        except TransientSweepError:
+            outcomes.append("fail")
+    assert outcomes == ["ok", "fail", "ok", "fail", "ok"]
+    assert policy.attempts == 5
+
+
+def test_fault_policy_fatal_is_not_transient():
+    policy = FaultPolicy(fail_first=1, fatal=True)
+    with pytest.raises(RuntimeError) as err:
+        policy.on_dispatch()
+    assert not isinstance(err.value, TransientSweepError)
+    policy.on_dispatch()
+
+
+def test_fault_policy_stalls_every_attempt():
+    policy = FaultPolicy(latency_s=0.05)
+    t0 = time.perf_counter()
+    policy.on_dispatch()
+    policy.on_dispatch()
+    assert time.perf_counter() - t0 >= 0.1
 
 
 # ----------------------------------------- batcher streaming-admission hooks
