@@ -219,6 +219,15 @@ CHAIN_STEP_GROWTH = 0.04
 # v5e's 128 MiB.  A chain more grows the query block and the strip
 # about as many times; a one-chain plan keeps what it needs.
 CHAIN_VMEM_BUDGET = 16 * 2 ** 20
+# VMEM a multivariate plan may hold with its cost tile
+# (KernelPlan.vmem_bytes): half of a v5e's 128 MiB.  The tile grows
+# with the query length; a plan whose tile does not fit computes its
+# costs on the VPU in each step.
+COST_TILE_VMEM_BUDGET = 64 * 2 ** 20
+# Features from which the cost tile pays: below it the VPU's per-step
+# cost is fewer bundles than the tile's build and reads (a v5e's
+# compiled loops, PERF.md section 5).
+COST_TILE_MIN_FEATURES = 5
 
 
 def plan_rows(plan: KernelPlan, batch: int) -> KernelPlan:
@@ -241,10 +250,17 @@ def plan_rows(plan: KernelPlan, batch: int) -> KernelPlan:
         ``CHAIN_VMEM_BUDGET``;
 
     else one.  Reverse and checkpoint sweeps keep one.  Every query's
-    arithmetic is the same at any rows and chains."""
+    arithmetic is the same at any rows and chains.
+
+    Then a squared-Euclidean plan of at least
+    ``COST_TILE_MIN_FEATURES`` features takes the MXU's cost tile
+    (``KernelPlan.cost_tile``) where its :meth:`KernelPlan.vmem_bytes`
+    is within ``COST_TILE_VMEM_BUDGET``; the tile changes only where
+    each cell's cost comes from, and the order of its float32
+    additions."""
     rows = 2 * SUBLANES if batch > SUBLANES else SUBLANES
     plan = dataclasses.replace(plan, rows_per_step=rows,
-                               lanes_per_chain=LANES)
+                               lanes_per_chain=LANES, cost_tile=False)
     if plan.reverse or plan.checkpoint:
         return plan
     for lanes in CHAIN_LANES[:-1]:                  # the most chains first
@@ -253,7 +269,13 @@ def plan_rows(plan: KernelPlan, batch: int) -> KernelPlan:
                 and plan.m + LANES - 1 >= (1 + CHAIN_STEP_GROWTH)
                 * (plan.m + lanes - 1)
                 and chained.vmem_bytes() <= CHAIN_VMEM_BUDGET):
-            return chained
+            plan = chained
+            break
+    if (plan.features >= COST_TILE_MIN_FEATURES
+            and plan.spec.distance == "sqeuclidean"):
+        tiled = dataclasses.replace(plan, cost_tile=True)
+        if tiled.vmem_bytes() <= COST_TILE_VMEM_BUDGET:
+            return tiled
     return plan
 
 
@@ -326,9 +348,9 @@ def count_wavefront(work: dict) -> None:
     ``.cells_real`` / ``.feature_cells`` (equal to ``.cells_real`` for
     univariate dispatches) of :func:`repro.obs.default_registry`, and to
     ``.wide_dispatches`` where the plan carried two query groups a
-    step, and to ``.chained_dispatches`` where it carried more than one
-    lane chain (``wide_dispatches / dispatches`` and
-    ``chained_dispatches / dispatches``: how often each engages).
+    step, to ``.chained_dispatches`` where it carried more than one
+    lane chain, and to ``.cost_tile_dispatches`` where the MXU built
+    its cell costs (each over ``.dispatches``: how often each engages).
 
     Process-wide because the chip and its kernels belong to the
     process, not to a session.  Call it on the host once per dispatch
@@ -340,6 +362,8 @@ def count_wavefront(work: dict) -> None:
         reg.inc("kernel.wavefront.wide_dispatches")
     if work["lanes_per_chain"] < LANES:
         reg.inc("kernel.wavefront.chained_dispatches")
+    if work["cost_tile"]:
+        reg.inc("kernel.wavefront.cost_tile_dispatches")
     for key, name in _WORK_COUNTERS:
         reg.inc(name, work[key])
 

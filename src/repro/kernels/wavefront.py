@@ -68,10 +68,14 @@ DESIGN — mapping channels back to the paper's AMD/HIP mechanisms:
     and the fold keeps one accumulator set per sub-block, so every
     lane folds exactly the cells, in the order, of a one-chain plan and
     the outputs agree with it bit for bit.
-  * multivariate series -> ``plan.features`` > 1: each query row packs
-    one reversed copy per feature (:func:`feature_stride` lanes apart)
-    and each reference block holds a (D, w, LANES) tile; every step
-    sums the D per-feature costs of its w cells on the VPU
+  * multivariate series -> ``plan.features`` > 1: each reference block
+    holds a (D, w, LANES) tile.  Where ``plan.cost_tile`` (the tile
+    fits, ``ops.plan_rows``), the MXU builds each sub-block's cell
+    costs before its steps, as ``|q|^2 + |r|^2 - 2 q.r`` over the
+    features (:func:`_build_cost_tile`), and a step reads its w costs
+    with strided loads.  Otherwise each query row packs one reversed
+    copy per feature (:func:`feature_stride` lanes apart) and every
+    step sums the D per-feature costs of its w cells on the VPU
     (:func:`_feature_costs`) before the recurrence reads them.
 
 Band-skip: with a Sakoe–Chiba band every cell (i, j) with
@@ -114,6 +118,11 @@ CHAIN_LANES = (16, 32, 64, LANES)   # lanes a chain may span: no fewer
 #                                    than 16, since each chain more adds
 #                                    a sub-block's set-up (its reference
 #                                    tile, its carry) to every block
+
+TILE_VMEM_HEADROOM = 16 * 2 ** 20   # VMEM a cost-tile plan asks for
+#                                    beyond KernelPlan.vmem_bytes: the
+#                                    compiler keeps the tile build's
+#                                    matmul results in buffers of its own
 
 _J_MAX = 2 ** 31 - 1   # lexicographic-min column sentinel (int32 max):
 #                        any real column index beats it, so it doubles
@@ -239,6 +248,96 @@ def _feature_costs(plan, q_ref, r_ref, t, p):
             term = spec.cell_cost(qd, r_ref[0, d, k:k + 1, :].astype(cdt))
             costs[k] = term if d == 0 else costs[k] + term
     return costs
+
+
+def _split3(x):
+    """float32 ``x`` as three bfloat16-exact float32 parts, hi + mid +
+    lo = x to float32's 24 bits: each part the top 16 bits of what the
+    ones before it leave.  Six of the nine products of two split
+    operands, all but mid.lo, lo.mid and lo.lo, are how the TPU
+    multiplies float32 at ``Precision.HIGHEST``."""
+    def top(v):
+        bits = lax.bitcast_convert_type(v, jnp.uint32)
+        return lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                        jnp.float32)
+    hi = top(x)
+    mid = top(x - hi)
+    return hi, mid, top(x - hi - mid)
+
+
+# the six products of two float32 operands split by _split3, as the
+# TPU multiplies float32 at Precision.HIGHEST: (reference part, query
+# part) of each contraction block of a cost-tile plan
+_HIGHEST_PRODUCTS = ((0, 0), (0, 1), (0, 2), (1, 0), (1, 1), (2, 0))
+
+
+def _cost_lhs(plan, r_ref, aug_ref, lhs_ref):
+    """The reference side of a block's cost tiles, once a block: its
+    rows (features, a row of ones, ``|r|^2``) split by :func:`_split3`
+    into the ``_HIGHEST_PRODUCTS`` blocks of the contraction, row kappa
+    of slot k at ``aug_ref`` row kappa w + k, then transposed into
+    ``lhs_ref`` as bfloat16: row (u w + k) L + p holds reference lane
+    u L + p, slot k, of sub-block u."""
+    D, w, L = plan.features, plan.segment_width, plan.lanes_per_chain
+    depth, n_rows = plan.tile_depth, plan.features + 2
+    x = r_ref[0].astype(jnp.float32)                     # (D, w, LANES)
+    parts = _split3(jnp.concatenate(
+        [x, jnp.ones((1, w, LANES), jnp.float32),
+         jnp.sum(x * x, axis=0, keepdims=True)], axis=0))
+    for b, (ri, _) in enumerate(_HIGHEST_PRODUCTS):
+        for d in range(n_rows):
+            aug_ref[pl.ds((b * n_rows + d) * w, w), :] = parts[ri][d]
+    pad = depth - len(_HIGHEST_PRODUCTS) * n_rows
+    if pad:
+        aug_ref[pl.ds((depth - pad) * w, pad * w), :] = jnp.zeros(
+            (pad * w, LANES), jnp.float32)
+    for k in range(w):
+        lhs = aug_ref[pl.ds(k, depth, stride=w), :].T.astype(jnp.bfloat16)
+        for u in range(plan.chains):
+            lhs_ref[pl.ds((u * w + k) * L, L), :] = lhs[u * L:(u + 1) * L]
+
+
+def _build_cost_tile(plan, q_ref, u, lhs_ref, prev_ref, cost_ref):
+    """Sub-block ``u``'s cell costs, built on the MXU before its steps:
+    ``cost_ref[k, s * T + t]`` holds, in lane c L + p, the cost of row
+    t - p of chain c's query s against reference lane u L + p, slot k
+    (T = ``plan.tile_steps``), so step t reads its w costs as w
+    sublane-strided loads.
+
+    For each piece of LANES frames and each chain, one bfloat16 matmul
+    of the sub-block's rows of ``lhs_ref`` (:func:`_cost_lhs`) against
+    the chain's query operand (:func:`_tile_queries`), accumulated in
+    float32, gives ``|q|^2 + |r|^2 - 2 q.r`` of every slot, reference
+    lanes as rows and frames along lanes.  Rolling lane p's row by p
+    skews it, a transpose turns it back, and its first p frames come
+    from the previous piece, whose first L frames ``prev_ref`` keeps."""
+    w, L, chains = plan.segment_width, plan.lanes_per_chain, plan.chains
+    depth = plan.tile_depth
+    base = pl.multiple_of(u * w * L, w * L)
+    own = lax.broadcasted_iota(jnp.int32, (L, LANES), 0) >= \
+        jnp.bitwise_and(lax.broadcasted_iota(jnp.int32, (L, LANES), 1),
+                        L - 1)
+    for k in range(w):          # frames before a query: any finite cost
+        prev_ref[k] = jnp.zeros((L, LANES), jnp.float32)
+
+    def piece(j, carry):
+        at = pl.multiple_of(j * LANES, LANES)
+        costs_t = [jnp.dot(lhs_ref[pl.ds(base, w * L), :],
+                           q_ref[0, pl.ds(c * depth, depth), pl.ds(at, LANES)],
+                           preferred_element_type=jnp.float32)
+                   for c in range(chains)]              # (w L, LANES) each
+        for k in range(w):
+            skewed = jnp.concatenate(
+                [pltpu.roll(x[k * L:(k + 1) * L], 0, 1, stride=1,
+                            stride_axis=0) for x in costs_t], axis=0).T
+            cost_ref[k, pl.ds(at, L), :] = jnp.where(own, skewed[:L],
+                                                     prev_ref[k])
+            if L < LANES:
+                cost_ref[k, pl.ds(at + L, LANES - L), :] = skewed[L:]
+            prev_ref[k] = skewed[:L]
+        return carry
+
+    lax.fori_loop(0, plan.rows_per_step * plan.tile_steps // LANES, piece, 0)
 
 
 def _tile_chains(x, u, lanes_per_chain):
@@ -688,6 +787,11 @@ class KernelPlan:
     #                              sub-blocks of m + L - 1 steps, so a
     #                              short query fills L - 1 steps, not
     #                              LANES - 1 (ops.plan_rows)
+    cost_tile: bool = False      # a multivariate plan's cell costs
+    #                              come from a per-sub-block tile the
+    #                              MXU builds (_build_cost_tile), not
+    #                              from the VPU inside each step; set
+    #                              by ops.plan_rows where the tile fits
 
     def __post_init__(self):
         if self.features < 1:
@@ -707,6 +811,12 @@ class KernelPlan:
                 "multivariate plans run the sdtw forward sweep only: "
                 f"got family {self.spec.family!r}, reverse={self.reverse}, "
                 f"checkpoint={self.checkpoint}")
+        if self.cost_tile and (self.features == 1
+                               or self.spec.distance != "sqeuclidean"):
+            raise ValueError(
+                "the cost tile expands the squared Euclidean cost of "
+                "multivariate plans; got features="
+                f"{self.features}, distance {self.spec.distance!r}")
         if self.rows_per_step not in (SUBLANES, 2 * SUBLANES):
             raise ValueError(
                 f"rows_per_step is {SUBLANES} or {2 * SUBLANES} (one or "
@@ -772,6 +882,23 @@ class KernelPlan:
     def queries_per_step(self) -> int:
         """Queries one grid step carries: rows x chains."""
         return self.rows_per_step * self.chains
+
+    @property
+    def tile_steps(self) -> int:
+        """T: the cost tile's steps a query, m + L - 1 rounded up so
+        that a query operand row of ``rows_per_step`` x T frames fills
+        whole lane tiles."""
+        return _ceil_to(self.m + self.lanes_per_chain - 1,
+                        LANES // self.rows_per_step)
+
+    @property
+    def tile_depth(self) -> int:
+        """Rows of one chain's contraction in the cost tile's matmul:
+        the ``_HIGHEST_PRODUCTS`` blocks of the features, a row for
+        ``|q|^2`` and one for ``|r|^2``, rounded up to whole bfloat16
+        sublane tiles."""
+        return _ceil_to(len(_HIGHEST_PRODUCTS) * (self.features + 2),
+                        2 * SUBLANES)
 
     @property
     def big(self) -> float:
@@ -903,6 +1030,8 @@ class KernelPlan:
             the executed blocks, never more than ``lane_cells``;
           * ``feature_cells``: ``cells_real`` x the plan's features,
             the per-feature cost terms the real cells add.
+          * ``cost_tile``: 1 where the plan's cell costs come from the
+            MXU's cost tile, else 0.
 
         Forward and reverse sweeps execute the same number of blocks
         and hold the same real columns, so both read the same work."""
@@ -924,6 +1053,7 @@ class KernelPlan:
             "lane_cells": loop_steps * rows * block_cols,
             "cells_real": cells_real,
             "feature_cells": cells_real * self.features,
+            "cost_tile": int(self.cost_tile),
         }
 
     def vmem_bytes(self) -> int:
@@ -934,17 +1064,37 @@ class KernelPlan:
         per channel, the fold's accumulators for every chain and, in a
         chained plan, each sub-block's reference-laid tiles.  A chained
         plan's query block and strip are about ``chains`` times the
-        one-chain plan's."""
+        one-chain plan's.  A cost-tile plan's query block is the
+        matmul's operand (:func:`_tile_queries`), and its scratch holds
+        the sub-block's augmented reference and the w x rows x T cost
+        tile in place of the reference tile."""
         rows, L, D = self.rows_per_step, self.lanes_per_chain, self.features
+        w = self.segment_width
         kinds = [_EXTRA_KIND[name] for name in self.extra_inputs]
         q_laid = (1 + kinds.count("q")) * rows * step_pack_len(self.m, D, L)
-        r_laid = (D + kinds.count("r")) * self.segment_width * LANES
+        r_laid = (D + kinds.count("r")) * w * LANES
         out = (self.num_outputs - self.checkpoint) * rows * LANES \
             + self.checkpoint * rows * strip_len(self.m)
         scratch = len(self.channels) * rows * strip_len(self.m, L) \
-            + len(self.fold.accumulators()) * self.chains * rows * LANES \
-            + (self.chains > 1) * r_laid
-        return 4 * (2 * (q_laid + r_laid + out) + scratch)
+            + len(self.fold.accumulators()) * self.chains * rows * LANES
+        if not self.cost_tile:
+            scratch += (self.chains > 1) * r_laid
+            return 4 * (2 * (q_laid + r_laid + out) + scratch)
+        # bfloat16 query operand and reference side, float32 the rest
+        q_laid = self.chains * self.tile_depth * rows * self.tile_steps
+        scratch += self.tile_depth * w * LANES \
+            + w * L * LANES + w * rows * self.tile_steps * LANES
+        return 4 * (2 * (r_laid + out) + scratch) \
+            + 2 * (2 * q_laid + w * LANES * self.tile_depth)
+
+    def vmem_limit_bytes(self) -> int | None:
+        """VMEM the ``pallas_call`` asks the compiler for: a cost-tile
+        plan's :meth:`vmem_bytes` and ``TILE_VMEM_HEADROOM`` for the
+        compiler's own buffers (the tile build's matmul results); None,
+        the compiler's default, for every other plan."""
+        if not self.cost_tile:
+            return None
+        return self.vmem_bytes() + TILE_VMEM_HEADROOM
 
     # ------------------------------------------------------------ cell
     def cell(self, qv, rv, *, is_row0, i_l, j_col, vals3, extras=None,
@@ -1059,7 +1209,9 @@ def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
             output refs, one boundary strip per channel, the fold's
             accumulators (one set per sub-block) and, in a chained
             plan, one sub-block tile per reference-laid
-            operand.
+            operand; a cost-tile plan holds the augmented reference
+            and the cost tile there instead (:func:`_build_cost_tile`),
+            and its q_ref is (1, K, rows T), the matmul's query operand.
 
     Chain c spans lanes [c L, (c + 1) L).  Sub-block u of the block
     gives lane c L + p the reference lanes u L + p: the same columns
@@ -1121,12 +1273,17 @@ def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
 
             # q value for (query s, lane p) = q[s, t - p]; q_ref stores
             # the REVERSED query so this is an ascending slice (no lane
-            # flip).  A multivariate plan reads its features' windows
-            # into the w cell costs instead.
+            # flip).  A multivariate plan reads its w cell costs from
+            # the sub-block's cost tile, or sums its features' windows
+            # into them.
             costs = [None] * w
-            if plan.features > 1:
+            qv = None
+            if plan.cost_tile:
+                costs = [tile_refs[-1][k, pl.ds(t, plan.rows_per_step,
+                                               stride=plan.tile_steps), :]
+                         for k in range(w)]
+            elif plan.features > 1:
                 costs = _feature_costs(plan, q_ref, r_src, t, p)
-                qv = None
             else:
                 qv = _lane_window(q_ref, m - 1 + L - 1 - t, p,
                                   L).astype(cdt)
@@ -1209,7 +1366,17 @@ def _generic_kernel(q_ref, r_ref, *refs, plan: KernelPlan):
                        for ch, strip_ref in zip(channels, strip_refs))
         lax.fori_loop(0, m + L - 1, step, carry0)
 
-    if chains == 1:             # the block is the one sub-block's tile
+    if plan.cost_tile:
+        aug_ref, lhs_ref, prev_ref, cost_ref = tile_refs
+        _cost_lhs(plan, r_ref, aug_ref, lhs_ref)
+
+        def sub_block(u, carry):
+            _build_cost_tile(plan, q_ref, u, lhs_ref, prev_ref, cost_ref)
+            sweep(u, None, ex_refs, [a.at[u] for a in scr])
+            return carry
+
+        lax.fori_loop(0, chains, sub_block, 0)
+    elif chains == 1:           # the block is the one sub-block's tile
         sweep(0, r_ref, ex_refs, [a.at[0] for a in scr])
     else:
         # the reference block and the family operands laid out like
@@ -1268,6 +1435,30 @@ def _step_queries(x, plan: KernelPlan):
     x = x.reshape(-1, plan.chains, rows, D, tiles, L)
     return x.transpose(0, 2, 3, 4, 1, 5).reshape(
         -1, rows, step_pack_len(plan.m, D, L))
+
+
+def _tile_queries(x, plan: KernelPlan):
+    """Prepared multivariate query packs (G, SUBLANES, D * stride) -> a
+    cost-tile plan's (steps, chains x ``tile_depth``, rows * T) bfloat16
+    query operands.  Chain c's rows, lane s * T + i: ``-2 q[i]``'s D
+    features, ``|q[i]|^2`` and 1 of chain c's query s (as
+    :func:`_step_queries` places the queries), split by
+    :func:`_split3` into the query parts of ``_HIGHEST_PRODUCTS``;
+    frames i >= m are zero queries."""
+    G, S, Mp = x.shape
+    rows, per, D = plan.rows_per_step, plan.queries_per_step, plan.features
+    m, T, depth = plan.m, plan.tile_steps, plan.tile_depth
+    q = x.reshape(G * S, D, Mp // D)[:, :, LANES - 1:LANES - 1 + m]
+    q = jnp.flip(q, axis=2).astype(jnp.float32)       # (B, D, m) in order
+    q = jnp.pad(q, ((0, -(G * S) % per), (0, 0), (0, T - m)))
+    parts = _split3(jnp.concatenate(
+        [-2.0 * q, jnp.sum(q * q, axis=1, keepdims=True),
+         jnp.ones((q.shape[0], 1, T), jnp.float32)], axis=1))
+    q = jnp.concatenate([parts[qi] for _, qi in _HIGHEST_PRODUCTS], axis=1)
+    q = jnp.pad(q, ((0, 0), (0, depth - q.shape[1]), (0, 0)))
+    q = q.reshape(-1, plan.chains, rows, depth, T)
+    return q.transpose(0, 1, 3, 2, 4).reshape(
+        -1, plan.chains * depth, rows * T).astype(jnp.bfloat16)
 
 
 def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
@@ -1353,7 +1544,8 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
                                       lambda b, r: (b, r, 0, 0)))
     off = plan.block_offset
     r_block = (1, w, LANES) if D == 1 else (1, D, w, LANES)
-    q_block = (1, rows, step_pack_len(plan.m, D, L))
+    q_block = ((1, plan.chains * plan.tile_depth, rows * plan.tile_steps)
+               if plan.cost_tile else (1, rows, step_pack_len(plan.m, D, L)))
     in_specs = [
         pl.BlockSpec(q_block, lambda b, r: (b, 0, 0)),
         # grid step r reads layout block r + offset (reverse band-skip
@@ -1380,19 +1572,27 @@ def wavefront_call(plan: KernelPlan, q_rev_pad: jnp.ndarray,
     scratch = [ch.strip_shape(plan.m, rows, L) for ch in plan.channels]
     scratch += [pltpu.VMEM((plan.chains, rows, LANES), dt)
                 for dt in plan.fold.accumulators()]
-    if plan.chains > 1:       # each sub-block's tile of the reference
+    if plan.cost_tile:        # the augmented reference, the cost tile
+        scratch += [
+            pltpu.VMEM((plan.tile_depth * w, LANES), jnp.float32),
+            pltpu.VMEM((w * LANES, plan.tile_depth), jnp.bfloat16),
+            pltpu.VMEM((w, L, LANES), jnp.float32),
+            pltpu.VMEM((w, rows * plan.tile_steps, LANES), jnp.float32)]
+    elif plan.chains > 1:     # each sub-block's tile of the reference
         #                       and of the operands laid out like it
         scratch += [pltpu.VMEM(r_block, r_layout.dtype)] + [
             pltpu.VMEM((1, w, LANES), x.dtype)
             for name, x in zip(plan.extra_inputs, extras)
             if _EXTRA_KIND[name] == "r"]
-    q_rev_pad = _step_queries(q_rev_pad, plan)
+    q_rev_pad = (_tile_queries if plan.cost_tile else _step_queries)(
+        q_rev_pad, plan)
     extras = tuple(_step_queries(x, plan) if _EXTRA_KIND[name] == "q"
                    else x for name, x in zip(plan.extra_inputs, extras))
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"))
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=plan.vmem_limit_bytes())
     # a stable name for the profiler's trace: the HLO op is named
     # after it, whatever jitted function dispatches the kernel
     name = "sdtw_wavefront_reverse" if plan.reverse else "sdtw_wavefront"

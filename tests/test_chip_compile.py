@@ -122,14 +122,17 @@ def test_fused_soft_backward_compiles(one_chip, tpu_math):
     assert _rows_per_step(text, "sdtw_wavefront_reverse") == ["8"]
 
 
-def _compile_the_rules_plan(one_chip, batch, m, n, features, lanes):
+def _compile_the_rules_plan(one_chip, batch, m, n, features, lanes,
+                            cost_tile=False):
     """Compile a dispatch of these shapes as the rule plans it, which
     must be 16 rows in chains of ``lanes``: the kernel reads query rows
     packed for that many lanes a chain, ``rows x chains`` queries a
-    grid step."""
+    grid step.  A cost-tile plan reads the MXU's bfloat16 query
+    operand instead and asks the compiler for the VMEM it holds."""
     plan = ops.kernel_plan(m=m, n=n, segment_width=W, batch=batch,
                            features=features)
-    assert (plan.rows_per_step, plan.lanes_per_chain) == (16, lanes)
+    assert (plan.rows_per_step, plan.lanes_per_chain, plan.cost_tile) == \
+        (16, lanes, cost_tile)
     blocks = plan.num_ref_blocks
     q = _sds((batch // SUBLANES, SUBLANES, query_pack_len(m, features)),
              one_chip)
@@ -142,7 +145,16 @@ def _compile_the_rules_plan(one_chip, batch, m, n, features, lanes):
     text = _assert_kernel(jax.jit(sweep).lower(q, r).compile(),
                           "sdtw_wavefront")
     steps = batch // plan.queries_per_step
-    assert f"f32[{steps},16,{step_pack_len(m, features, lanes)}]" in text
+    if not cost_tile:
+        assert f"f32[{steps},16,{step_pack_len(m, features, lanes)}]" in text
+        return
+    depth, rows_t = plan.chains * plan.tile_depth, 16 * plan.tile_steps
+    assert f"bf16[{steps},{depth},{rows_t}]" in text
+    limit = plan.vmem_limit_bytes()
+    assert plan.vmem_bytes() <= limit
+    kernel = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+              and "sdtw_wavefront/pallas_call" in ln]
+    assert len(kernel) == 1 and f'"size":"{limit}"' in kernel[0]
 
 
 @pytest.mark.parametrize("batch, m, n, features, lanes", [
@@ -152,13 +164,15 @@ def _compile_the_rules_plan(one_chip, batch, m, n, features, lanes):
 def test_benchmark_shapes_compile_chained(one_chip, batch, m, n, features,
                                           lanes):
     """Both benchmark shapes compile as the chained plans the rule
-    picks."""
-    _compile_the_rules_plan(one_chip, batch, m, n, features, lanes)
+    picks; sws2013_qbe's 39 features take the MXU's cost tile."""
+    _compile_the_rules_plan(one_chip, batch, m, n, features, lanes,
+                            cost_tile=features > 1)
 
 
 @pytest.mark.parametrize("batch, m, features, lanes", [
     (B, 12_000, 1, LANES),      # long queries keep one chain
-    (256, 500, 39, 32),         # 8 chains would outgrow the VMEM budget
+    (256, 500, 39, 32),         # 8 chains, or the cost tile, would outgrow
+    #                             their VMEM budgets
 ])
 def test_long_and_wide_batches_compile(one_chip, batch, m, features,
                                        lanes):

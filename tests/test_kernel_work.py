@@ -13,8 +13,8 @@ from repro.kernels import ops
 from repro.kernels.wavefront import KernelPlan
 
 KEYS = ("dispatches", "wide_dispatches", "chained_dispatches",
-        "grid_steps", "loop_steps", "lane_cells", "cells_real",
-        "feature_cells")
+        "cost_tile_dispatches", "grid_steps", "loop_steps", "lane_cells",
+        "cells_real", "feature_cells")
 
 
 def counters() -> dict:
@@ -28,8 +28,9 @@ def plus(before: dict, *works) -> dict:
         out["dispatches"] += 1
         out["wide_dispatches"] += w["rows_per_step"] == 16
         out["chained_dispatches"] += w["lanes_per_chain"] < 128
+        out["cost_tile_dispatches"] += w["cost_tile"]
         for k, v in w.items():
-            if k not in ("rows_per_step", "lanes_per_chain"):
+            if k not in ("rows_per_step", "lanes_per_chain", "cost_tile"):
                 out[k] += v
     return out
 
@@ -39,20 +40,20 @@ def plus(before: dict, *works) -> dict:
     (DPSpec(), 512, 2_000, 100_000, 8,
      {"rows_per_step": 8, "lanes_per_chain": 128, "grid_steps": 6_272, "loop_steps": 13_340_544,
       "lane_cells": 109_285_736_448, "cells_real": 102_400_000_000,
-      "feature_cells": 102_400_000_000}),
+      "feature_cells": 102_400_000_000, "cost_tile": 0}),
     # 13 queries fill 2 groups; 1,000 columns pad to 2 blocks of 512
     (DPSpec(), 13, 20, 1_000, 4,
      {"rows_per_step": 8, "lanes_per_chain": 128, "grid_steps": 4,
       "loop_steps": 4 * 147,
       "lane_cells": 4 * 147 * 8 * 512, "cells_real": 13 * 20 * 1_000,
-      "feature_cells": 13 * 20 * 1_000}),
+      "feature_cells": 13 * 20 * 1_000, "cost_tile": 0}),
     # band 100 at m = 200 keeps columns up to 298: 2 of 20 blocks of
     # 256 run, and only their 512 columns hold real cells
     (DPSpec(band=100), 8, 200, 5_000, 2,
      {"rows_per_step": 8, "lanes_per_chain": 128, "grid_steps": 2,
       "loop_steps": 2 * 327,
       "lane_cells": 2 * 327 * 8 * 256, "cells_real": 8 * 200 * 512,
-      "feature_cells": 8 * 200 * 512}),
+      "feature_cells": 8 * 200 * 512, "cost_tile": 0}),
 ])
 def test_plan_work_by_hand(spec, batch, m, n, w, want):
     plan = ops.kernel_plan(spec, m=m, n=n, segment_width=w)
@@ -69,41 +70,48 @@ def test_plan_work_by_hand(spec, batch, m, n, w, want):
     (DPSpec(), 512, 2_000, 100_000, 8,
      {"rows_per_step": 16, "lanes_per_chain": 16, "grid_steps": 392,
       "loop_steps": 6_319_040, "lane_cells": 103_531_151_360,
-      "cells_real": 102_400_000_000, "feature_cells": 102_400_000_000}),
+      "cells_real": 102_400_000_000, "feature_cells": 102_400_000_000,
+      "cost_tile": 0}),
     # 24 queries round up to 32: 2 chains of 16 rows, one step along
     # the batch, its 8 pad queries' lane-cells counted
     (DPSpec(), 24, 20, 1_000, 4,
      {"rows_per_step": 16, "lanes_per_chain": 64, "grid_steps": 2,
       "loop_steps": 2 * 2 * 83, "lane_cells": 2 * 2 * 83 * 16 * 512,
-      "cells_real": 24 * 20 * 1_000, "feature_cells": 24 * 20 * 1_000}),
+      "cells_real": 24 * 20 * 1_000, "feature_cells": 24 * 20 * 1_000,
+      "cost_tile": 0}),
     # 9 queries fill 2 groups, 7 rows of the second padding
     (DPSpec(band=100), 9, 200, 5_000, 2,
      {"rows_per_step": 16, "lanes_per_chain": 128, "grid_steps": 2,
       "loop_steps": 2 * 327, "lane_cells": 2 * 327 * 16 * 256,
-      "cells_real": 9 * 200 * 512, "feature_cells": 9 * 200 * 512}),
+      "cells_real": 9 * 200 * 512, "feature_cells": 9 * 200 * 512,
+      "cost_tile": 0}),
     # a search round's 2 real rows: one group in one chain
     (DPSpec(), 2, 20, 1_000, 4,
      {"rows_per_step": 8, "lanes_per_chain": 128, "grid_steps": 2,
       "loop_steps": 2 * 147, "lane_cells": 2 * 147 * 8 * 512,
-      "cells_real": 2 * 20 * 1_000, "feature_cells": 2 * 20 * 1_000}),
+      "cells_real": 2 * 20 * 1_000, "feature_cells": 2 * 20 * 1_000,
+      "cost_tile": 0}),
     # one group never takes the wide step
     (DPSpec(), 8, 20, 1_000, 4,
      {"rows_per_step": 8, "lanes_per_chain": 128, "grid_steps": 2,
       "loop_steps": 2 * 147, "lane_cells": 2 * 147 * 8 * 512,
-      "cells_real": 8 * 20 * 1_000, "feature_cells": 8 * 20 * 1_000}),
+      "cells_real": 8 * 20 * 1_000, "feature_cells": 8 * 20 * 1_000,
+      "cost_tile": 0}),
     # 12,000-sample queries: chains would cut the steps under 1%, so
     # one chain, 32 steps along the batch x 98 blocks of 12,127 steps
     (DPSpec(), 512, 12_000, 100_000, 8,
      {"rows_per_step": 16, "lanes_per_chain": 128, "grid_steps": 3_136,
       "loop_steps": 3_136 * 12_127,
       "lane_cells": 3_136 * 12_127 * 16 * 1_024,
-      "cells_real": 614_400_000_000, "feature_cells": 614_400_000_000}),
+      "cells_real": 614_400_000_000, "feature_cells": 614_400_000_000,
+      "cost_tile": 0}),
     # sws2013_qbe's terms: 32 queries of 100 frames x 39 features, 2
     # chains of 64 lanes, 7,032 blocks of 2 sub-blocks of 163 steps
     (DPSpec(), 32, 100, 7_200_000, 8,
      {"rows_per_step": 16, "lanes_per_chain": 64, "grid_steps": 7_032,
       "loop_steps": 2_292_432, "lane_cells": 37_559_205_888,
-      "cells_real": 23_040_000_000, "feature_cells": 898_560_000_000}),
+      "cells_real": 23_040_000_000, "feature_cells": 898_560_000_000,
+      "cost_tile": 1}),
 ])
 def test_batch_plan_work_by_hand(spec, batch, m, n, w, want):
     features = want["feature_cells"] // want["cells_real"]

@@ -43,6 +43,14 @@ def _cmvn(x):
 CHAIN_LANES_AT = {8: 128, 16: 128, 24: 64, 32: 64, 64: 32, 128: 16}
 
 
+def _cost_source(m, d):
+    """Where each case's cell costs come from: the MXU's cost tile from
+    5 features on (ops.COST_TILE_MIN_FEATURES), unless the tile outgrows
+    ops.COST_TILE_VMEM_BUDGET, as 4,000-frame queries make it; else the
+    VPU inside each step."""
+    return "tile" if d >= 5 and m < 4_000 else "vpu"
+
+
 @pytest.mark.parametrize("b, m, n, d", [
     (8, 13, 700, 2),        # one group; 700 columns pad to 3 blocks
     (16, 9, 300, 13),       # two groups, one step of 16 rows
@@ -51,11 +59,13 @@ CHAIN_LANES_AT = {8: 128, 16: 128, 24: 64, 32: 64, 64: 32, 128: 16}
     (32, 7, 260, 2), (64, 7, 260, 2), (128, 7, 260, 2),
     (16, 7, 260, 5), (24, 7, 260, 5), (64, 7, 260, 5), (128, 7, 260, 5),
     (8, 7, 260, 39), (64, 7, 260, 39), (128, 7, 260, 39),
+    (8, 4_000, 200, 39),    # the tile outgrows its budget: the VPU
 ])
 def test_kernel_ref_and_oracle_agree(b, m, n, d):
     plan = ops.kernel_plan(DPSpec(), m=m, n=n, segment_width=2, batch=b,
                            features=d, with_window=True)
     assert plan.lanes_per_chain == CHAIN_LANES_AT[b]
+    assert ("tile" if plan.cost_tile else "vpu") == _cost_source(m, d)
     q, r = _data(b, m, n, d, seed=d)
     qn, rn = normalize_batch(jnp.asarray(q)), normalize_reference(
         jnp.asarray(r))
@@ -264,3 +274,28 @@ def test_feature_cells_count_d_times_the_cells():
     assert work["feature_cells"] == 13 * work["cells_real"]
     layout = [e for e in tracer.events if e["name"] == "aligner.layout"]
     assert [e["args"]["step"] for e in layout] == ["normalize", "swizzle"]
+
+
+def test_cost_tile_dispatches_count_the_tiled_plans():
+    """``kernel.wavefront.cost_tile_dispatches`` counts the dispatches
+    whose cell costs the MXU built: every one of a multivariate plan
+    that takes the tile, none of a univariate one or of one that keeps
+    the VPU's cost."""
+    reg = obs.default_registry()
+
+    def dispatched(q, r):
+        obs.reset()
+        repro.Aligner(r, backend="kernel", segment_width=2,
+                      metrics=obs.MetricsRegistry())(q)
+        return (reg.value("kernel.wavefront.dispatches"),
+                reg.value("kernel.wavefront.cost_tile_dispatches"))
+
+    q, r = _data(9, 8, 300, 13, seed=4)
+    assert dispatched(q, r) == (1, 1)                    # the tile
+    assert dispatched(q[..., 0], r[:, 0]) == (1, 0)      # univariate
+    assert dispatched(q[..., :2], r[:, :2]) == (1, 0)    # 2 features
+    q, r = _data(8, 4_000, 200, 39, seed=6)
+    assert dispatched(q, r) == (1, 0)                    # over budget
+    # sws2013_qbe's plan takes the tile on every dispatch
+    assert ops.wavefront_work(batch=32, m=100, n=7_200_000, segment_width=8,
+                              features=39)["cost_tile"] == 1

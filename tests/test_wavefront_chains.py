@@ -116,8 +116,11 @@ def test_the_batch_picks_the_chains(batch, m, features, rows, lanes):
                            features=features)
     assert (plan.rows_per_step, plan.lanes_per_chain) == (rows, lanes)
     assert plan.chains * plan.lanes_per_chain == LANES
-    if plan.chains > 1:
-        assert plan.vmem_bytes() <= ops.CHAIN_VMEM_BUDGET
+    if plan.chains > 1:     # chains are picked before the cost tile
+        vpu = dataclasses.replace(plan, cost_tile=False)
+        assert vpu.vmem_bytes() <= ops.CHAIN_VMEM_BUDGET
+    if plan.cost_tile:
+        assert plan.vmem_bytes() <= ops.COST_TILE_VMEM_BUDGET
 
 
 @pytest.mark.parametrize("m, features, lanes, want", [
